@@ -1,15 +1,9 @@
-"""Shared example bootstrap: repo path + platform forcing.
-
-Some TPU plugins ignore the JAX_PLATFORMS env var; jax.config.update
-before any backend initializes is the reliable override (same recipe as
-__graft_entry__._force_virtual_cpu_mesh), so `JAX_PLATFORMS=cpu python
-examples/...` really runs on CPU."""
+"""Shared example bootstrap: repo path + the compile cache."""
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    import jax
+from mxnet_tpu.runtime import enable_compile_cache  # noqa: E402
 
-    jax.config.update("jax_platforms", "cpu")
+enable_compile_cache()

@@ -22,7 +22,7 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-import _common  # noqa: F401,E402  (repo path + platform forcing)
+import _common  # noqa: F401,E402  (repo path + compile cache)
 
 import numpy as np
 

@@ -75,18 +75,7 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None):
 
     # NOTE: jax.process_count()/devices() must NOT be called before
     # jax.distributed.initialize — they would initialize the backend.
-    # jax.distributed.is_initialized() only exists from jax 0.5; on
-    # older versions the service handle lives in the private global
-    # state object, so probe both.
-    if hasattr(jax.distributed, "is_initialized"):
-        initialized = jax.distributed.is_initialized()
-    else:
-        try:
-            from jax._src.distributed import global_state
-            initialized = global_state.client is not None
-        except Exception:
-            initialized = False
-    if initialized:
+    if jax.distributed.is_initialized():
         return jax.process_index(), jax.process_count()
     if coordinator is None:
         uri = os.environ.get("DMLC_PS_ROOT_URI")
@@ -100,15 +89,9 @@ def init_distributed(coordinator=None, num_processes=None, process_id=None):
     if coordinator is None or num_processes <= 1:
         return 0, 1
     # multi-process CPU backends need a cross-process collectives impl
-    # (the TPU backend has ICI/DCN built in); must be set pre-init. The
-    # env var alone is not enough when jax was pre-imported with another
-    # platform pinned — jax.config.update overrides the stale value.
+    # (the TPU backend has ICI/DCN built in); must be set pre-init
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        try:
-            jax.config.update("jax_platforms", "cpu")
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)

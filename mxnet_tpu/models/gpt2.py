@@ -257,51 +257,38 @@ class GPT2Attention(HybridBlock):
         v = self._split(self._lora(self.value(x), 2, layer_idx, x))
         t = q.shape[2]
         if getattr(cache, "ragged", False):
-            # ragged serving decode: each slot appends at its OWN length
-            # and attends only its live pages through the ragged paged-
-            # attention kernel — no dense (B, T_max) gather at all.
-            # t == 1 is plain decode; t > 1 is a multi-query dispatch
-            # (speculative verify, or the unified chunked-prefill
-            # serving step) where query position j attends
-            # < length + j + 1 through the span kernel's per-position
-            # causal offsets. When the cache carries per-slot `spans`
-            # (the unified fixed-shape dispatch), rows past a slot's
-            # span neither attend nor write — the kernel emits exact
-            # zeros for them.
-            from ..ops.pallas_attention import (ragged_decode_attention,
-                                                ragged_span_attention)
+            # ragged serving path: each slot appends at its OWN length
+            # and attends only its live pages through the span kernel —
+            # no dense (B, T_max) gather at all. t == 1 is plain decode;
+            # t > 1 is a multi-query dispatch (speculative verify, or
+            # the unified chunked-prefill serving step) where query
+            # position j attends < length + j + 1 through the kernel's
+            # per-position causal offsets. When the cache carries
+            # per-slot `spans` (the unified fixed-shape dispatch), rows
+            # past a slot's span neither attend nor write — the kernel
+            # emits exact zeros for them.
+            from ..ops.pallas_attention import ragged_span_attention
             cache = cache.write_decode(layer_idx, k._data, v._data)
             impl = cache.attn_impl
             interp = impl == "pallas_interpret"
             impl = "pallas" if interp else impl
             quant = getattr(cache, "quantized", False)
-            if t == 1 and not quant:
-                out = ragged_decode_attention(
-                    q._data[:, :, 0, :].astype(cache.k_pages.dtype),
-                    cache.k_pages[layer_idx], cache.v_pages[layer_idx],
-                    cache.page_table, cache.length + 1,
-                    impl=impl, interpret=interp)
-                b, h, d = out.shape
-                out = out.astype(q._data.dtype).reshape(b, 1, h * d)
-            else:
-                # int8 pages keep q in its own compute dtype (casting q
-                # to the pool dtype would destroy it) and thread the
-                # per-(page, head) scales into the fused dequant; t == 1
-                # quantized decode rides the span kernel too so the
-                # dequant epilogue is a single code path
-                qd = q._data.transpose(0, 2, 1, 3)
-                if not quant:
-                    qd = qd.astype(cache.k_pages.dtype)
-                out = ragged_span_attention(
-                    qd,
-                    cache.k_pages[layer_idx], cache.v_pages[layer_idx],
-                    cache.page_table, cache.length + 1,
-                    q_counts=getattr(cache, "spans", None),
-                    impl=impl, interpret=interp,
-                    k_scale=cache.k_scale[layer_idx] if quant else None,
-                    v_scale=cache.v_scale[layer_idx] if quant else None)
-                b, tq, h, d = out.shape
-                out = out.astype(q._data.dtype).reshape(b, tq, h * d)
+            # int8 pages keep q in its own compute dtype (casting q to
+            # the pool dtype would destroy it) and thread the per-(page,
+            # head) scales into the fused dequant
+            qd = q._data.transpose(0, 2, 1, 3)
+            if not quant:
+                qd = qd.astype(cache.k_pages.dtype)
+            out = ragged_span_attention(
+                qd,
+                cache.k_pages[layer_idx], cache.v_pages[layer_idx],
+                cache.page_table, cache.length + 1,
+                q_counts=getattr(cache, "spans", None),
+                impl=impl, interpret=interp,
+                k_scale=cache.k_scale[layer_idx] if quant else None,
+                v_scale=cache.v_scale[layer_idx] if quant else None)
+            b, tq, h, d = out.shape
+            out = out.astype(q._data.dtype).reshape(b, tq, h * d)
             out = NDArray(out)
             return self._proj_out(out, layer_idx), cache
         if t > 1:

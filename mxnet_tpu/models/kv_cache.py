@@ -22,7 +22,7 @@ donation):
     length. Ragged caches take decode writes through `write_decode`
     (per-slot scatter at each slot's own offset, NO dense gather) and
     attention reads the pools directly via the ragged paged-attention
-    kernel (ops/pallas_attention.ragged_decode_attention), so per-token
+    kernel (ops/pallas_attention.ragged_span_attention), so per-token
     HBM traffic scales with live length instead of max_length. The
     static `attn_impl` knob ('auto'|'pallas'|'pallas_interpret'|'xla')
     rides in the pytree aux so it is part of the jit signature.

@@ -557,12 +557,7 @@ def array(source_array, ctx=None, dtype=None) -> NDArray:
 
 
 def waitall():
-    """Parity: mx.nd.waitall — block until all async work completes."""
-    try:
-        jax.effects_barrier()
-    except Exception:
-        pass
-    # block on all live backends' activity via a trivial sync per device
-    for d in jax.devices():
-        jnp.zeros((), jnp.float32).block_until_ready()
-        break
+    """Parity: mx.nd.waitall — block until all async work completes:
+    every ordered effect has run and every live array holds its value."""
+    jax.effects_barrier()
+    jax.block_until_ready(jax.live_arrays())

@@ -8,9 +8,10 @@ provides the TPU-native upgrades (SURVEY.md §5.7):
   * flash_attention_data — blockwise online-softmax attention, O(T) memory,
     implemented as a lax.scan over KV blocks so XLA fuses each block's
     QK^T·softmax·V into MXU work without materializing the (T,T) matrix.
-    On TPU, jax.experimental.pallas.ops.tpu.flash_attention is used when
-    importable (hand-tiled VMEM pipeline); the scan path is the portable
-    fallback with identical semantics (used on CPU tests).
+    On TPU, unmasked self-attention whose length the kernel tiles divide
+    runs jax.experimental.pallas.ops.tpu.flash_attention (hand-tiled VMEM
+    pipeline; see pallas_flash_eligible); every other call — and every
+    CPU test — takes the scan path, which has identical semantics.
   * ring_attention_data — sequence-parallel attention: Q stays put, KV
     blocks rotate around the mesh's "sp" axis via lax.ppermute, combining
     partial softmax statistics exactly as flash does across local blocks.
@@ -33,16 +34,19 @@ def flash_eligible(q, k, v, mask, dropout_p):
                                             jnp.float16)
 
 
-def _pallas_flash(q, k, v, causal, scale):
-    """Try the TPU Pallas flash kernel; return None if unavailable."""
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention)
-        if jax.devices()[0].platform != "tpu":
-            return None
-        return flash_attention(q, k, v, causal=causal, sm_scale=scale)
-    except Exception:
-        return None
+# tile edge of jax's Pallas TPU flash kernel at its default BlockSizes
+_PALLAS_FLASH_BLOCK = 128
+
+
+def pallas_flash_eligible(q, k, mask):
+    """Will flash_attention_data run jax's Pallas TPU flash kernel for
+    this call? A decision from the platform and the shapes alone (unmasked
+    self-attention whose length the kernel's tiles divide) — so a caller
+    can tell which path produced a number, and a failure of the chosen
+    kernel is an error, never a quiet switch to the scan."""
+    return (jax.default_backend() == "tpu" and mask is None
+            and q.shape[-2] == k.shape[-2]
+            and q.shape[-2] % _PALLAS_FLASH_BLOCK == 0)
 
 
 def flash_attention_data(q, k, v, mask=None, scale=None, causal=False,
@@ -52,10 +56,10 @@ def flash_attention_data(q, k, v, mask=None, scale=None, causal=False,
     mask: broadcastable to (B, H, Tq, Tk), True = attend."""
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
-    if mask is None and q.shape[-2] == k.shape[-2]:
-        out = _pallas_flash(q, k, v, causal, s)
-        if out is not None:
-            return out
+    if pallas_flash_eligible(q, k, mask):
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention)
+        return flash_attention(q, k, v, causal=causal, sm_scale=s)
     B, H, Tq, D = q.shape
     Tk = k.shape[-2]
     block_k = min(block_k, Tk)

@@ -740,6 +740,60 @@ def _target_platform(x):
             pass
     return jax.default_backend()
 
+def _fused_attention(q, k, v, mask, scale, causal, dropout_p, key, layout):
+    """The fused Pallas kernel — partitioned by hand under a multi-device
+    mesh. The SPMD partitioner cannot split a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned"), so with a mesh active
+    the call runs in a shard_map: batch over "dp", heads over "tp" — the
+    activation layout megatron_dense_rules produces. Attention is
+    independent per batch row and per head, so the map needs no
+    collective; an axis that does not divide its dim is left replicated.
+    Each shard folds its mesh position into the dropout key (the kernel
+    seeds from LOCAL grid ids, which repeat across shards)."""
+    from . import pallas_attention as _pa
+    from ..parallel.mesh import (AXIS_DP, AXIS_TP, PartitionSpec,
+                                 current_mesh, shard_map_compat)
+
+    def call(qb, kb, vb, mb, kk):
+        return _pa.fused_attention(qb, kb, vb, mask=mb, scale=scale,
+                                   causal=causal, dropout_p=dropout_p,
+                                   key=kk, layout=layout)
+
+    mesh = current_mesh()
+    h_dim = 2 if layout == "BTHD" else 1
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    ba, ha = (
+        ax if (mesh is not None and ax in mesh.axis_names
+               and mesh.shape[ax] > 1 and ax not in manual
+               and q.shape[dim] % mesh.shape[ax] == 0) else None
+        for ax, dim in ((AXIS_DP, 0), (AXIS_TP, h_dim)))
+    if ba is None and ha is None:
+        return call(q, k, v, mask, key)
+    qspec = [ba, None, None, None]
+    qspec[h_dim] = ha
+    qspec = PartitionSpec(*qspec)
+    args, in_specs = [q, k, v], [qspec, qspec, qspec]
+    if mask is not None:
+        args.append(jnp.broadcast_to(mask, (q.shape[0],) + mask.shape[1:]))
+        in_specs.append(PartitionSpec(ba, None, None, None))
+    if key is not None:
+        args.append(key)
+        in_specs.append(PartitionSpec())
+
+    def local(qb, kb, vb, *rest):
+        rest = list(rest)
+        mb = rest.pop(0) if mask is not None else None
+        kk = rest.pop(0) if key is not None else None
+        if kk is not None:
+            for ax in (ba, ha):
+                if ax is not None:
+                    kk = jax.random.fold_in(kk, lax.axis_index(ax))
+        return call(qb, kb, vb, mb, kk)
+
+    return shard_map_compat(local, mesh=mesh, in_specs=tuple(in_specs),
+                            out_specs=qspec, check_rep=False)(*args)
+
+
 def _sp_auto_impl(q, k, mask, train_drop):
     """The sequence-parallel route impl='auto' should take, or None.
 
@@ -809,10 +863,9 @@ def dot_product_attention(q, k, v, mask=None, scale=None, causal=False,
                     and (impl == "fused" or _sp_auto_impl(
                         bhtd(q), bhtd(k), mask, train_drop) is None)):
                 key = _rng.next_key() if train_drop else None
-                return _pa.fused_attention(
-                    q, k, v, mask=mask, scale=scale, causal=causal,
-                    dropout_p=dropout_p if train_drop else 0.0, key=key,
-                    layout="BTHD")
+                return _fused_attention(
+                    q, k, v, mask, scale, causal,
+                    dropout_p if train_drop else 0.0, key, "BTHD")
         if impl == "xla":
             d = q.shape[-1]
             s = scale if scale is not None else 1.0 / _pymath.sqrt(d)
@@ -866,9 +919,9 @@ def dot_product_attention(q, k, v, mask=None, scale=None, causal=False,
         ok = on_tpu and _pa.supported(q, k, mask)
         if ok:
             key = _rng.next_key() if train_drop else None
-            return _pa.fused_attention(
-                q, k, v, mask=mask, scale=scale, causal=causal,
-                dropout_p=dropout_p if train_drop else 0.0, key=key)
+            return _fused_attention(
+                q, k, v, mask, scale, causal,
+                dropout_p if train_drop else 0.0, key, "BHTD")
         if impl == "fused":
             # An explicit request must not silently measure a different
             # kernel; only impl='auto' may fall back quietly.

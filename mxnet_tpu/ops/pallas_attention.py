@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 
 import numpy as _np
 import jax
@@ -41,6 +42,17 @@ NEG_INF = -1e30
 # compiles and runs for both f32 and bf16 (Mosaic reuses the (T, T)
 # scratch tiles); beyond it the blockwise scan path takes over.
 MAX_FUSED_T = 1024
+# Mosaic's default scoped-VMEM limit is below what a whole-row (T, T) f32
+# score tile plus its temporaries needs; every kernel here asks for the
+# same raised limit (a v5e core has 128 MiB of VMEM).
+_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+
+def _compiler_params(interpret, dimension_semantics=None):
+    if interpret:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+                                dimension_semantics=dimension_semantics)
 
 
 def _scores(q_ref, k_ref, bias_ref, scale, causal, tq, tk):
@@ -291,8 +303,7 @@ def _fused_packed_fwd(q, k, v, bias, seed, scale, p_drop, causal, H,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+        compiler_params=_compiler_params(interpret),
     )(seed, bias, q, k, v)
     return out, (q, k, v, bias, seed)
 
@@ -315,8 +326,7 @@ def _fused_packed_bwd(scale, p_drop, causal, H, interpret, res, g):
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+        compiler_params=_compiler_params(interpret),
     )(seed, bias, q, k, v, g)
     return dq, dk, dv, jnp.zeros_like(bias), \
         _np.zeros(seed.shape, jax.dtypes.float0)
@@ -346,6 +356,7 @@ def _fused_fwd(q, k, v, bias, seed, scale, p_drop, causal, interpret):
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        compiler_params=_compiler_params(interpret),
     )(seed, bias, q, k, v)
     return out, (q, k, v, bias, seed)
 
@@ -368,6 +379,7 @@ def _fused_bwd(scale, p_drop, causal, interpret, res, g):
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         interpret=interpret,
+        compiler_params=_compiler_params(interpret),
     )(seed, bias, q, k, v, g)
     return dq, dk, dv, jnp.zeros_like(bias), \
         _np.zeros(seed.shape, jax.dtypes.float0)
@@ -377,201 +389,82 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 # ---------------------------------------------------------------------------
-# ragged paged-attention decode kernel (the serving hot path).
-#
-# One query token per decode slot attends over that slot's live KV pages
-# only. The dense alternative (PagedKVCache._gather) re-materializes the
-# FULL (B, max_length, H, D) cache view from HBM every decoded token — at
-# GPT-2 774M serving shapes that is max_length/live_length times more HBM
-# traffic than the tokens actually alive. This kernel follows the ragged
-# paged attention design (arxiv 2604.15464): grid (slots, pages-per-slot),
-# the page table and per-slot lengths ride in scalar-prefetch SMEM so the
-# BlockSpec index_map DMAs exactly the pages the slot owns, and pages past
-# the live length re-map to the slot's last live page — Pallas elides the
-# DMA when consecutive grid steps ask for the same block, so per-token HBM
-# traffic scales with the LIVE length, not max_length.
-#
-# Layout: pages enter packed as (num_pages, S, H*D) (a free minor-dim
-# reshape of the pool's (num_pages, S, H, D)); heads are static 64-aligned
-# column slices exactly like the packed training kernels above, so the
-# (8, 128) Mosaic rule holds for every transformer width. The online-
-# softmax accumulators live in VMEM scratch and persist across the
-# sequential minor page-grid dimension.
-# ---------------------------------------------------------------------------
-
-def _ragged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                          m_ref, l_ref, acc_ref, *, scale, S, H, D):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    length = len_ref[b]
-    n_live = (length + S - 1) // S
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(p < n_live)
-    def _accumulate():
-        # token positions covered by this page, masked to the live length
-        pos = p * S + lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        valid = pos < length
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            q = q_ref[0, :, c0:c1]                     # (1, D)
-            k = k_ref[0, :, c0:c1]                     # (S, D)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)           # (1, S)
-            m_prev = m_ref[h, 0]
-            l_prev = l_ref[h, 0]
-            m_new = jnp.maximum(m_prev, jnp.max(s))
-            # fully-masked page rows contribute zeros, not exp(0)
-            e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-            alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
-                              jnp.exp(m_prev - m_new))
-            v = v_ref[0, :, c0:c1]                     # (S, D)
-            pv = lax.dot_general(e.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            acc_ref[h:h + 1, :] = acc_ref[h:h + 1, :] * alpha + pv
-            l_ref[h, 0] = l_prev * alpha + jnp.sum(e)
-            m_ref[h, 0] = m_new
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _emit():
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            # empty slots (length 0) keep acc == 0 → emit zeros
-            o_ref[0, :, c0:c1] = (
-                acc_ref[h:h + 1, :]
-                / jnp.maximum(l_ref[h, 0], 1e-30)).astype(o_ref.dtype)
-
-
-def ragged_supported(q, k_pages):
-    """Can the ragged Pallas decode kernel take this call on real TPU
-    hardware? (Interpret mode runs any shape.)"""
-    H, D = q.shape[1], q.shape[2]
-    S = k_pages.shape[1]
-    if (H * D) % 128 or D % 64:
-        return False   # packed head slices must be 64-aligned lane blocks
-    if S % 8:
-        return False   # sublane rule for the (S, H*D) page blocks
-    if k_pages.dtype == jnp.int8 and S % 32:
-        return False   # int8 page blocks need the (32, 128) min tile
-    if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    return True
-
-
-def _ragged_reference(q, k_pages, v_pages, page_table, lengths, scale):
-    """Dense XLA fallback/oracle: gather the full per-slot views and mask
-    by length — the exact math the kernel computes, O(max_length) HBM."""
-    B = q.shape[0]
-    g = jnp.take(k_pages, page_table, axis=0)          # (B, P, S, H, D)
-    P, S = g.shape[1], g.shape[2]
-    k = g.reshape(B, P * S, *g.shape[3:])              # (B, T, H, D)
-    v = jnp.take(v_pages, page_table, axis=0).reshape(B, P * S,
-                                                      *g.shape[3:])
-    s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
-    mask = (jnp.arange(P * S)[None, :] < lengths[:, None])[:, None, :]
-    s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(s - m))
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    w = e / jnp.maximum(l, 1e-30)
-    return jnp.einsum("bht,bthd->bhd", w,
-                      v.astype(jnp.float32)).astype(q.dtype)
-
-
-def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                            scale=None, impl="auto", interpret=False):
-    """Ragged paged-attention for one decode step.
-
-    q:              (B, H, D) — the current token's query per slot.
-    k_pages/v_pages:(num_pages, S, H, D) — ONE layer's page pools.
-    page_table:     (B, P) int32 — physical pages per slot.
-    lengths:        (B,) int32 — LIVE tokens per slot, including the
-                    token just written (a slot with length 0 yields 0s).
-    impl: 'auto' (kernel on TPU when shapes allow, dense XLA otherwise),
-    'pallas' (force the kernel; interpret=True runs it on CPU), 'xla'.
-    Returns (B, H, D) in q's dtype.
-    """
-    B, H, D = q.shape
-    N, S = k_pages.shape[0], k_pages.shape[1]
-    P = page_table.shape[1]
-    s = float(scale) if scale is not None else 1.0 / math.sqrt(D)
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu" and not interpret
-        impl = "pallas" if (on_tpu and ragged_supported(q, k_pages)) \
-            else ("pallas" if interpret else "xla")
-    if impl == "xla":
-        return _ragged_reference(q, k_pages, v_pages, page_table,
-                                 lengths, s)
-    if impl != "pallas":
-        raise ValueError(f"unknown ragged attention impl {impl!r}")
-    qp = q.reshape(B, 1, H * D)
-    kp = k_pages.reshape(N, S, H * D)
-    vp = v_pages.reshape(N, S, H * D)
-    lengths = lengths.astype(jnp.int32)
-    table = page_table.astype(jnp.int32)
-
-    def page_index(b, p, tbl, lens):
-        # pages past the live length re-map to the last live page: the
-        # block index repeats, so the pipeline skips the DMA (ragged
-        # traffic). Empty slots (length 0) pin to the slot's first page.
-        last_live = jnp.maximum((lens[b] + S - 1) // S - 1, 0)
-        return (tbl[b, jnp.minimum(p, last_live)], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, H * D), lambda b, p, tbl, lens: (b, 0, 0)),
-            pl.BlockSpec((1, S, H * D), page_index),
-            pl.BlockSpec((1, S, H * D), page_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, H * D),
-                               lambda b, p, tbl, lens: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),   # running max (lane 0)
-            pltpu.VMEM((H, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((H, D), jnp.float32),     # running numerator
-        ],
-    )
-    kernel = functools.partial(_ragged_decode_kernel, scale=s, S=S, H=H,
-                               D=D)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, H * D), q.dtype),
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-            dimension_semantics=("parallel", "arbitrary")),
-    )(table, lengths, qp, kp, vp)
-    return out.reshape(B, H, D)
-
-
-# ---------------------------------------------------------------------------
-# span (per-slot query count) ragged paged-attention — ONE kernel for
+# ragged paged attention (the serving hot path) — ONE span kernel for
 # prefill chunks, plain decode, and speculative verification.
+#
+# Each slot attends over its own live KV pages only. The dense alternative
+# (PagedKVCache._gather) re-materializes the FULL (B, max_length, H, D)
+# cache view from HBM every decoded token — at GPT-2 774M serving shapes
+# that is max_length/live_length times more HBM traffic than the tokens
+# actually alive. The kernel follows the ragged paged attention design
+# (arxiv 2604.15464): grid (slots, pages-per-slot), the page table and
+# per-slot lengths ride in scalar-prefetch SMEM so the BlockSpec index_map
+# DMAs exactly the pages the slot owns, and pages past the live extent
+# re-map to the slot's last live page — Pallas elides the DMA when
+# consecutive grid steps ask for the same block, so HBM traffic scales
+# with the LIVE length, not max_length.
 #
 # Each of the B slots in a (B, Sq, H, D) dispatch consumes q_counts[b]
 # query tokens: a decode slot 1, a speculative verify S, a prefill chunk
 # C, an idle slot 0. Query row j of slot b sits at absolute position
 # lengths[b]-1+j, so it may attend key positions < lengths[b]+j — the
 # per-position CAUSAL OFFSET — and rows >= q_counts[b] are dead: they
-# accumulate nothing and emit exact zeros. The scalar-prefetch grid skips
-# dead rows AND dead pages (a slot's page extent stretches only to
-# lengths[b] + q_counts[b] - 1; an idle slot visits no page at all), so
-# HBM traffic per dispatch scales with the live work, not B*Sq. Same grid
-# and DMA-eliding page remap as the single-query kernel above; the
-# (Sq, S) score tile replaces the (1, S) one and the online-softmax
-# accumulators carry one row per query position.
+# accumulate nothing and emit exact zeros. The grid skips dead rows AND
+# dead pages (a slot's page extent stretches only to
+# lengths[b] + q_counts[b] - 1; an idle slot visits no page at all).
+#
+# Layout: pages enter packed as (num_pages, S, H*D) (a free minor-dim
+# reshape of the pool's (num_pages, S, H, D)); heads are static 64-aligned
+# column slices exactly like the packed training kernels above, so the
+# (8, 128) Mosaic rule holds for every transformer width. The online-
+# softmax accumulators live in VMEM scratch, one row per query position,
+# and persist across the sequential minor page-grid dimension.
 # ---------------------------------------------------------------------------
+
+def _ragged_unsupported_reason(q, k_pages):
+    """Why the ragged Pallas kernel cannot take this call on real TPU
+    hardware (None when it can; interpret mode runs any shape).
+    q: (B, H, D) or (B, Sq, H, D)."""
+    H, D = q.shape[-2], q.shape[-1]
+    S = k_pages.shape[1]
+    if (H * D) % 128 or D % 64:
+        return (f"heads*head_dim={H}*{D} is not a multiple of 128 lanes "
+                "with 64-aligned head slices")
+    if S % 8:
+        return f"page size {S} breaks the sublane rule (multiple of 8)"
+    if k_pages.dtype == jnp.int8 and S % 32:
+        return f"int8 pages need page size {S} to be a multiple of 32"
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
+        return f"query dtype {q.dtype} is neither float32 nor bfloat16"
+    return None
+
+
+def ragged_supported(q, k_pages):
+    """Can the ragged Pallas kernel take this call on real TPU
+    hardware? (Interpret mode runs any shape.)"""
+    return _ragged_unsupported_reason(q, k_pages) is None
+
+
+def _resolve_ragged_impl(impl, interpret, q, k_pages):
+    """'auto' -> 'pallas' | 'xla'. On a TPU the only way 'auto' reaches
+    the dense reference is an unsupported shape, and that is said out
+    loud: the dense path re-reads max_length of cache per token, so a
+    benchmark must never land on it unnoticed."""
+    if impl != "auto":
+        return impl
+    if interpret:
+        return "pallas"
+    if jax.default_backend() != "tpu":
+        return "xla"
+    why = _ragged_unsupported_reason(q, k_pages)
+    if why is None:
+        return "pallas"
+    warnings.warn(
+        "ragged paged attention: impl='auto' on TPU is using the dense "
+        f"XLA reference instead of the Pallas kernel because {why}",
+        stacklevel=3)
+    return "xla"
+
 
 def _ragged_span_kernel(table_ref, len_ref, qc_ref, q_ref, k_ref, v_ref,
                         o_ref, m_ref, l_ref, acc_ref, *, scale, S,
@@ -760,8 +653,9 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     for int8 page pools; both set or both None. The
                     Pallas path fuses the dequant into the page DMA
                     epilogue; the XLA path dequants the gathered view.
-    impl/interpret: same contract as ragged_decode_attention. Sq=1 with
-    q_counts=None matches the single-query kernel exactly.
+    impl: 'auto' (kernel on TPU; dense XLA elsewhere, or on TPU with a
+    warning when ragged_supported says no), 'pallas' (force the kernel;
+    interpret=True runs it on CPU), 'xla'.
     Returns (B, Sq, H, D) in q's dtype.
     """
     B, Sq, H, D = q.shape
@@ -771,10 +665,7 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     quant = k_scale is not None
     if q_counts is None:
         q_counts = jnp.full((B,), Sq, jnp.int32)
-    if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu" and not interpret
-        impl = "pallas" if (on_tpu and ragged_supported(q[:, 0], k_pages)) \
-            else ("pallas" if interpret else "xla")
+    impl = _resolve_ragged_impl(impl, interpret, q, k_pages)
     if impl == "xla":
         return _ragged_span_reference(q, k_pages, v_pages, page_table,
                                       lengths, q_counts, s,
@@ -792,9 +683,10 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     n_scalar = 5 if quant else 3
 
     def page_index(b, p, tbl, lens, qcs, *_scales):
-        # same DMA-eliding remap as the single-query kernel, with the
-        # live extent stretched to cover the slot's furthest live query;
-        # idle slots (q_count 0) pin every step to their first page and
+        # pages past the live extent (stretched to cover the slot's
+        # furthest live query) re-map to the last live page: the block
+        # index repeats, so the pipeline skips the DMA (ragged traffic).
+        # Idle slots (q_count 0) pin every step to their first page and
         # the kernel body skips all of them
         last_live = jnp.maximum((lens[b] + qcs[b] - 1 + S - 1) // S - 1, 0)
         return (tbl[b, jnp.minimum(p, last_live)], 0, 0)
@@ -832,11 +724,30 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
         interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024,
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            interpret, dimension_semantics=("parallel", "arbitrary")),
     )(*operands)
     return out.reshape(B, Sq, H, D)
+
+
+def _ragged_reference(q, k_pages, v_pages, page_table, lengths, scale):
+    """Dense oracle for ragged_decode_attention: the Sq=1 row of the
+    multi-query reference."""
+    return _ragged_mq_reference(q[:, None], k_pages, v_pages, page_table,
+                                lengths, scale)[:, 0]
+
+
+def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths,
+                            scale=None, impl="auto", interpret=False):
+    """Ragged paged-attention for one decode step: the Sq=1 call of
+    ragged_span_attention with every row live.
+
+    q: (B, H, D) — the current token's query per slot; lengths: (B,)
+    LIVE tokens per slot, including the token just written (a slot with
+    length 0 yields 0s). Returns (B, H, D) in q's dtype."""
+    return ragged_span_attention(q[:, None], k_pages, v_pages, page_table,
+                                 lengths, q_counts=None, scale=scale,
+                                 impl=impl, interpret=interpret)[:, 0]
 
 
 def ragged_mq_decode_attention(q, k_pages, v_pages, page_table, lengths,

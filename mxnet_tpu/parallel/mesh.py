@@ -34,18 +34,11 @@ __all__ = ["Mesh", "PartitionSpec", "NamedSharding", "make_mesh",
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check_rep=False):
-    """jax.shard_map across jax versions: 0.8+ renamed check_rep →
-    check_vma (and moved the function out of jax.experimental)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_rep)
-    except TypeError:  # pragma: no cover - pre-0.8 signature
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
+    """jax.shard_map with the framework's default: no replication check
+    (psum-assembled outputs defeat jax's conservative inference)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
+
 
 AXIS_DP, AXIS_FSDP, AXIS_TP = "dp", "fsdp", "tp"
 AXIS_SP, AXIS_PP, AXIS_EP = "sp", "pp", "ep"

@@ -7,10 +7,33 @@ of the JAX/XLA stack, probed once on first access.
 """
 from __future__ import annotations
 
+import os
+
 from .base import MXNetError
 
 __all__ = ["Feature", "Features", "feature_list", "jit_cache_stats",
-           "reset_jit_cache_stats"]
+           "reset_jit_cache_stats", "enable_compile_cache"]
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compile_cache():
+    """Give JAX's persistent compilation cache a home, unless the
+    environment already placed it; returns the directory in use.
+
+    With JAX_COMPILATION_CACHE_DIR set JAX honours it by itself and
+    nothing is set here. Otherwise the cache is <checkout>/.jax_cache —
+    a fixed path, because a later process only finds the entries of an
+    earlier one at the same path. The ONE place the cache is configured:
+    every entry point (bench.py, chip_smoke.py, the fleet worker,
+    examples/) calls it before its first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def jit_cache_stats():

@@ -1827,15 +1827,18 @@ class ServingEngine:
         (prefill chunks + decode + verify in the same fixed-shape
         program), free finished slots.
 
-        Dispatch exceptions do NOT propagate. The supervisor catches
-        them, runs the page-pool invariant audit, latches a
+        Runtime dispatch faults do NOT propagate. The supervisor
+        catches them, runs the page-pool invariant audit, latches a
         flight-recorder dump, rolls the implicated slots back (leases
         released, device state parked), re-queues the requests with
         backoff — and quarantines a request whose dispatches failed
         `max_retries` times (terminal reason="error"). Rolled-back
         requests restart by re-prefilling prompt+emitted with their RNG
         counter resumed, so recovered outputs are bit-identical to an
-        uninterrupted run.
+        uninterrupted run. The one exception that DOES propagate is
+        telemetry.cost.ProgramCompileError: a program that does not
+        compile fails identically on every retry, for every request, so
+        it is the operator's error to see, not a request's to absorb.
 
         Returns every request that reached a TERMINAL state this round:
         finished, deadline-shed/-cancelled, or quarantined."""
@@ -1877,6 +1880,8 @@ class ServingEngine:
         if self.scheduler.num_active:
             try:
                 finished.extend(self._dispatch())
+            except _cost.ProgramCompileError:
+                raise
             except Exception as e:          # noqa: BLE001 — supervisor
                 finished.extend(self._on_decode_fault(e))
             self._set_load_gauges()

@@ -26,6 +26,14 @@ __all__ = ["WorkerProc", "spawn_worker", "spawn_fleet", "FleetProcs"]
 _REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", ".."))
 
+# Every worker is told its platform explicitly, never left to inherit
+# the spawner's: a chip belongs to one process, so a parent that holds
+# one (or N workers sharing one host) would leave a child that asks for
+# it hanging. This launcher has no way to hand each child a chip of its
+# own, so the only platform it gives out is the CPU; TPU replicas run as
+# engines inside the one process that owns the chips.
+_WORKER_PLATFORM = "cpu"
+
 
 class WorkerProc:
     """One spawned worker subprocess + its READY announcement."""
@@ -95,6 +103,14 @@ def spawn_worker(spec, role="mixed", host="127.0.0.1", port=0,
     """Launch one worker process and block until it is READY (or dead).
     Returns a WorkerProc. The spec travels via a temp file, so big
     engine configs never hit argv limits."""
+    asked = (env or {}).get("JAX_PLATFORMS", _WORKER_PLATFORM)
+    if asked != _WORKER_PLATFORM:
+        raise MXNetError(
+            f"fleet worker asked for JAX_PLATFORMS={asked!r}: this "
+            "launcher cannot give each worker process a chip of its own "
+            "(one process holds a chip at a time), so workers run on "
+            f"{_WORKER_PLATFORM!r} only — serve TPU replicas from one "
+            "process instead")
     fd, spec_path = tempfile.mkstemp(prefix="mx_fleet_spec_",
                                      suffix=".json")
     with os.fdopen(fd, "w", encoding="utf-8") as f:
@@ -109,8 +125,8 @@ def spawn_worker(spec, role="mixed", host="127.0.0.1", port=0,
     child_env = dict(os.environ)
     child_env["PYTHONPATH"] = _REPO_ROOT + os.pathsep \
         + child_env.get("PYTHONPATH", "")
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     child_env.update(env or {})
+    child_env["JAX_PLATFORMS"] = _WORKER_PLATFORM
     proc = subprocess.Popen(
         cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True, cwd=_REPO_ROOT, env=child_env)

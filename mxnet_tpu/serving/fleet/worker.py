@@ -45,6 +45,7 @@ import time
 from urllib.parse import parse_qs, urlparse
 
 from ...base import MXNetError
+from ...runtime import enable_compile_cache
 from ... import telemetry
 from ..frontend import (ServingFrontend, TokenStream, _FrontendServer,
                         _Handler, _drain_rejection, _invalid_body,
@@ -627,6 +628,7 @@ def main(argv=None):
         with open(raw, "r", encoding="utf-8") as f:
             raw = f.read()
     spec = json.loads(raw)
+    enable_compile_cache()
     _net, cfg, eng = build_engine(spec)
     if not args.no_warmup:
         warm_engine(eng, cfg, spec)

@@ -58,6 +58,7 @@ from urllib.parse import parse_qs, urlparse
 from ..base import MXNetError
 from ..analysis import assertions_enabled, claim_ownership, thread_safe
 from .. import telemetry
+from ..telemetry import cost as _cost
 from ..telemetry import server as _tserver
 from .scheduler import (Request, RejectedError, QueueFullError,
                         TERMINAL_STATUSES)
@@ -503,6 +504,7 @@ class ServingFrontend:
         self._stop_evt = threading.Event()
         self._draining = False
         self._closed = False
+        self._fatal = None          # the compile error that stopped the loop
         self._probe_name = f"frontend{self._fid}"
         _tserver.register_ready_probe(self._probe_name,
                                       self._ready_probe)
@@ -571,17 +573,20 @@ class ServingFrontend:
         self._stop_evt.set()
         self._wake.set()
         self._loop_thread.join(timeout=10)
-        with self._lock:
-            live = list(self._live.values())
-        for _req, st in live:
-            try:
-                st.close("aborted")
-            except Exception:       # noqa: BLE001 — teardown
-                pass
+        self._abort_streams("aborted")
         self._server.close()
         _tserver.unregister_ready_probe(self._probe_name)
         telemetry.unregister_status_provider(self._probe_name)
         self._metrics["active_streams"].set(0)
+
+    def _abort_streams(self, status):
+        with self._lock:
+            live = list(self._live.values())
+        for _req, st in live:
+            try:
+                st.close(status)
+            except Exception:       # noqa: BLE001 — teardown
+                pass
 
     def __enter__(self):
         return self
@@ -607,6 +612,13 @@ class ServingFrontend:
                     if self._backend.has_work:
                         self._backend.step()
                         continue
+                except _cost.ProgramCompileError as e:
+                    # every later step would fail the same way: stop
+                    # serving, fail what is open, refuse what comes, and
+                    # let the error reach the operator's stderr
+                    self._fatal = e
+                    self._abort_streams("failed")
+                    raise
                 except Exception as e:  # noqa: BLE001 — keep serving
                     telemetry.flight.record(
                         "frontend_step_error", frontend=self._fid,
@@ -678,6 +690,8 @@ class ServingFrontend:
     def _submit_via_loop(self, req):
         """Hand the request to the serving thread and wait for the
         admission verdict: ("ok"|"rejected"|"invalid"|"error", exc)."""
+        if self._fatal is not None:
+            return "error", self._fatal
         box = _Box()
         self._cmd_q.put(("submit", (req, box)))
         self._wake.set()
@@ -712,6 +726,8 @@ class ServingFrontend:
             self._live[req.id] = (req, stream)
             n = len(self._live)
         self._metrics["active_streams"].set(n)
+        if self._fatal is not None:     # raced the loop's last abort
+            stream.close("failed")
 
     def _unregister(self, req):
         with self._lock:

@@ -25,7 +25,10 @@ accounting"). Three pieces, one process-global program table:
     overridable with ``MXNET_TPU_PEAK_FLOPS`` / ``MXNET_TPU_PEAK_
     BANDWIDTH``. The ridge point (peak_flops / peak_bw) classifies
     each program: arithmetic intensity above the ridge is compute
-    bound, below is memory bound.
+    bound, below is memory bound. This is the ONE peak table
+    (``bench.py`` reads it too): an accelerator kind it does not know
+    is an error, and a CPU has no peak — MFU, bandwidth utilization
+    and the roofline side are simply absent from a CPU run.
 
 In-path cost per dispatch is a handful of instrument updates (~µs
 against multi-ms dispatches); ``set_enabled(False)`` turns the in-path
@@ -42,24 +45,27 @@ import os
 import threading
 import time
 
-__all__ = ["CostedFunction", "register_program", "record_compile",
-           "note_dispatch", "get", "report", "peaks", "set_enabled",
-           "enabled", "add_compile_hook", "remove_compile_hook",
-           "reset_programs"]
+from ..base import MXNetError
+
+__all__ = ["CostedFunction", "ProgramCompileError", "register_program",
+           "record_compile", "note_dispatch", "get", "report", "peaks",
+           "device_peaks", "set_enabled", "enabled", "add_compile_hook",
+           "remove_compile_hook", "reset_programs"]
 
 _lock = threading.Lock()
 _programs = {}             # program key -> _ProgramRecord
 _compile_hooks = []
 _enabled = True
-_device_peaks = None       # cached (flops, bw, kind) from the backend
+_device = None             # the default jax device, asked for once
 _peaks_published = None    # last (flops, bw) written to the gauges
 
 
 # (device-kind substring, (peak bf16 FLOP/s, peak HBM bytes/s)).
-# Sources: public Google Cloud TPU system-architecture pages (checked
-# 2025) — same flops table as bench.py's peak_flops(); bandwidth from
-# the per-generation spec tables (v2 700 GB/s, v3 900 GB/s, v4
-# 1228 GB/s, v5e 819 GB/s, v5p 2765 GB/s, v6e/Trillium 1640 GB/s).
+# Sources: public Google Cloud TPU system-architecture pages
+# (cloud.google.com/tpu/docs/system-architecture-tpu-vm and the per-
+# generation pages, checked 2025): bf16 peak per chip v2 45, v3 123,
+# v4 275, v5e 197, v5p 459, v6e/Trillium 918 TFLOP/s; HBM bandwidth v2
+# 700, v3 900, v4 1228, v5e 819, v5p 2765, v6e 1640 GB/s.
 # Ordered: more specific substrings first ("v5 lite" before "v5").
 _PEAK_TABLE = (
     ("v5 lite", (197e12, 819e9)), ("v5litepod", (197e12, 819e9)),
@@ -71,8 +77,17 @@ _PEAK_TABLE = (
     ("v3", (123e12, 900e9)),
     ("v2", (45e12, 700e9)),
 )
-# nominal single-core numbers so CPU smoke runs produce finite ratios
-_FALLBACK_PEAKS = (1e12, 100e9)
+
+
+class ProgramCompileError(MXNetError):
+    """Lowering or compiling `program` failed. Deterministic for a fixed
+    signature — the same call fails the same way every time — so nothing
+    catches it to retry or to fall back to another path."""
+
+    def __init__(self, program, cause):
+        super().__init__(f"program {program!r} failed to lower/compile: "
+                         f"{type(cause).__name__}: {cause}")
+        self.program = program
 
 
 class _ProgramRecord:
@@ -165,39 +180,48 @@ def _metrics():
 
 # -- peaks ------------------------------------------------------------------
 
-def peaks():
-    """(peak_flops, peak_bandwidth_bytes_per_sec, device_kind).
+def device_peaks(device):
+    """(peak bf16 FLOP/s, peak HBM bytes/s) of one jax device from the
+    table above; (None, None) for a CPU, which has no peak worth the
+    name. An accelerator the table does not know raises — a guessed peak
+    would put a wrong MFU under a real device's name."""
+    if device.platform == "cpu":
+        return None, None
+    kind = str(device.device_kind)
+    low = kind.lower()
+    for sub, vals in _PEAK_TABLE:
+        if sub in low:
+            return vals
+    raise MXNetError(
+        f"no peak FLOP/s / bandwidth known for device kind {kind!r} "
+        f"(platform {device.platform!r}): add it to "
+        "telemetry.cost._PEAK_TABLE with its source, or set "
+        "MXNET_TPU_PEAK_FLOPS and MXNET_TPU_PEAK_BANDWIDTH")
 
-    Env overrides are read every call (tests, odd hardware); the
-    device-kind lookup hits the backend once and is cached. Safe
-    without jax: falls back to nominal CPU numbers."""
-    global _device_peaks
-    if _device_peaks is None:
-        kind, table = "unknown", _FALLBACK_PEAKS
-        try:
-            import jax
-            dev = jax.devices()[0]
-            kind = str(getattr(dev, "device_kind", "") or dev.platform)
-            low = kind.lower()
-            for sub, vals in _PEAK_TABLE:
-                if sub in low:
-                    table = vals
-                    break
-        except Exception:
-            pass
-        _device_peaks = (table[0], table[1], kind)
-    flops = float(os.environ.get("MXNET_TPU_PEAK_FLOPS", 0) or 0) \
-        or _device_peaks[0]
-    bw = float(os.environ.get("MXNET_TPU_PEAK_BANDWIDTH", 0) or 0) \
-        or _device_peaks[1]
-    global _peaks_published
-    if _peaks_published != (flops, bw):     # hot path: publish on change
-        m = _metrics()
+
+def peaks():
+    """(peak_flops, peak_bandwidth_bytes_per_sec, device_kind) of the
+    default device; the two peaks are None on a CPU.
+
+    Env overrides are read every call (tests, hardware the table does
+    not know) and win over the table; the backend is asked for its
+    device once."""
+    global _device, _peaks_published
+    if _device is None:
+        import jax
+        _device = jax.devices()[0]
+    flops = float(os.environ.get("MXNET_TPU_PEAK_FLOPS", 0) or 0) or None
+    bw = float(os.environ.get("MXNET_TPU_PEAK_BANDWIDTH", 0) or 0) or None
+    if not (flops and bw):
+        table = device_peaks(_device)
+        flops, bw = flops or table[0], bw or table[1]
+    if flops and bw and _peaks_published != (flops, bw):
+        m = _metrics()                      # hot path: publish on change
         m["peak_flops"].set(flops)
         m["peak_bw"].set(bw)
         m["ridge"].set(flops / bw)
         _peaks_published = (flops, bw)
-    return flops, bw, _device_peaks[2]
+    return flops, bw, str(_device.device_kind)
 
 
 # -- enable/disable the in-path accounting ----------------------------------
@@ -260,8 +284,9 @@ def register_program(program, flops=None, bytes_accessed=None,
         ai = flops / bytes_accessed
         pf, pb, _ = peaks()
         m["ai"].labels(program).set(ai)
-        m["compute_bound"].labels(program).set(
-            1.0 if ai >= pf / pb else 0.0)
+        if pf and pb:
+            m["compute_bound"].labels(program).set(
+                1.0 if ai >= pf / pb else 0.0)
     return get(program)
 
 
@@ -313,7 +338,8 @@ def note_dispatch(program, seconds):
     # shard count (each chip only did 1/N of the FLOPs in that wall)
     if flops is not None:
         pf, _, _ = peaks()
-        m["mfu"].labels(program).set(flops / seconds / pf / sh)
+        if pf:
+            m["mfu"].labels(program).set(flops / seconds / pf / sh)
         m["achieved_flops"].labels(program).set(flops / seconds / sh)
         # re-assert the static gauge so a telemetry.reset() between
         # bench rounds heals on the next dispatch (set only on change
@@ -341,8 +367,9 @@ def _snap(rec):
         out["arithmetic_intensity"] = rec.flops / rec.bytes_accessed
     if rec.flops and rec.last_seconds:
         pf, pb, _ = peaks()
-        out["mfu"] = rec.flops / rec.last_seconds / pf / sh
-        if rec.bytes_accessed:
+        if pf:
+            out["mfu"] = rec.flops / rec.last_seconds / pf / sh
+        if pb and rec.bytes_accessed:
             out["bandwidth_util"] = (rec.bytes_accessed
                                      / rec.last_seconds / pb / sh)
     return out
@@ -351,14 +378,15 @@ def _snap(rec):
 def report():
     """The /compilez + `dump_telemetry --cost` view: every program's
     registered cost, roofline placement, compile attribution and
-    dispatch totals, plus the assumed device peaks."""
+    dispatch totals, plus the device peaks (None on a CPU, and then no
+    program carries `mfu`, `bandwidth_util` or `bound`)."""
     pf, pb, kind = peaks()
     with _lock:
         progs = {p: _snap(r) for p, r in sorted(_programs.items())}
-    ridge = pf / pb
+    ridge = pf / pb if pf and pb else None
     for snap in progs.values():
         ai = snap.get("arithmetic_intensity")
-        if ai is not None:
+        if ai is not None and ridge is not None:
             snap["bound"] = "compute" if ai >= ridge else "memory"
     return {"device_kind": kind, "peak_flops": pf,
             "peak_bandwidth_bytes_per_sec": pb,
@@ -434,10 +462,8 @@ class CostedFunction:
     back down — `cost_mfu{program}` stays an honest fraction of ONE
     chip's peak at any tp.
 
-    If AOT lowering fails (exotic backend), the wrapper falls back to
-    calling the jitted function directly — the compile is then timed
-    inside the first dispatch, and the program registers without cost
-    figures (MFU gauges simply stay absent)."""
+    A failed lower/compile raises ProgramCompileError naming the
+    program; nothing is cached, so the next call fails the same way."""
 
     __slots__ = ("_fn", "program", "_steady_fn", "_call", "_cost_scale",
                  "_shards")
@@ -455,13 +481,11 @@ class CostedFunction:
         call = self._call
         if call is None:
             t0 = time.perf_counter()
-            flops = nbytes = None
             try:
-                compiled = self._fn.lower(*args).compile()
-                flops, nbytes = _cost_from_compiled(compiled)
-                call = compiled
-            except Exception:
-                call = self._fn        # jit compiles inside call #1
+                call = self._fn.lower(*args).compile()
+            except Exception as e:
+                raise ProgramCompileError(self.program, e) from e
+            flops, nbytes = _cost_from_compiled(call)
             dt = time.perf_counter() - t0
             self._call = call
             s = self._cost_scale * self._shards

@@ -74,9 +74,15 @@ _providers_lock = threading.Lock()
 _providers = {}            # name -> weakref-able callable () -> dict
 _server = None             # the default server started by serve()
 _server_lock = threading.Lock()
-_degraded_lock = threading.Lock()
+# Re-entrant, both of them: a collected ServingEngine's finalizers call
+# clear_degraded / unregister_ready_probe, and a finalizer runs on
+# whatever thread happens to allocate when the collector fires — which
+# can be a thread already inside these locks (seen: register_ready_probe
+# -> WeakMethod() -> GC -> finalizer -> the same lock, a self-deadlock
+# that hung the test suite).
+_degraded_lock = threading.RLock()
 _degraded = {}             # component name -> reason
-_ready_lock = threading.Lock()
+_ready_lock = threading.RLock()
 _ready_probes = {}         # name -> weakref-able callable () -> dict
 
 

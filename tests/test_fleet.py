@@ -21,7 +21,7 @@ from mxnet_tpu.models import GPT2Config, GPT2ForCausalLM
 from mxnet_tpu.serving import Request, ServingEngine, TokenStream
 from mxnet_tpu.serving.fleet import (
     FleetRouter, FleetWorker, WorkerClient, WorkerGone, WorkerRejected,
-    spawn_fleet, warm_engine, wire)
+    spawn_fleet, spawn_worker, warm_engine, wire)
 
 _CONFIG = dict(vocab_size=97, units=32, num_layers=2, num_heads=2,
                max_length=64, dropout=0.0, attention_dropout=0.0)
@@ -214,6 +214,13 @@ def test_fleet_http_mixed_and_disagg_bit_identical():
     finally:
         drouter.close()
         wp.close(), wd.close()
+
+
+def test_spawn_worker_refuses_a_platform_it_cannot_give_each_child():
+    """A chip belongs to one process; the launcher hands out the CPU
+    explicitly and says so instead of letting a TPU child hang."""
+    with pytest.raises(mx.MXNetError, match="chip of its own"):
+        spawn_worker(_SPEC, env={"JAX_PLATFORMS": "tpu"})
 
 
 def test_fleet_worker_control_plane_drain_and_stats():

@@ -190,6 +190,26 @@ def test_nonstream_json_body_and_usage():
                                 "completion_tokens": 4}
 
 
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_compile_failure_stops_the_loop_and_fails_streams_loudly():
+    """A unified program that cannot compile (the Mosaic kernel on a CPU)
+    fails every step the same way: the serving loop must stop and say so
+    — open streams close as failed, later requests get a 500 — instead
+    of swallowing the error forever while clients wait."""
+    with _frontend(_engine(attn_impl="pallas")) as fe:
+        status, _, text = _post(fe, {"prompt": [1, 2, 3],
+                                     "max_new_tokens": 4})
+        assert status == 200                     # headers went out first
+        assert _done(_sse(text))["status"] == "failed"
+        assert _tokens(_sse(text)) == []
+        fe._loop_thread.join(timeout=10)
+        assert not fe._loop_thread.is_alive()
+        status, _, text = _post(fe, {"prompt": [1, 2, 3],
+                                     "max_new_tokens": 4})
+        assert status == 500 and "failed to lower/compile" in text
+
+
 def test_invalid_requests_answer_400():
     eng = _engine()
     with _frontend(eng) as fe:

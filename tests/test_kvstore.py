@@ -115,6 +115,14 @@ def test_dist_sync_two_process(tmp_path):
     assert r.stdout.count("WORKER_OK") == 2, (r.stdout, r.stderr)
 
 
+def test_launcher_refuses_to_share_one_chip_between_local_ranks():
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "launch.py"),
+         "-n", "2", "--platform", "tpu", "true"],
+        capture_output=True, text=True, timeout=60)
+    assert r.returncode == 2 and "its own tpu chip" in r.stderr, r.stderr
+
+
 def test_pushpull_updates_store_and_defaults_out_to_value():
     kv = mx.kv.create("local")
     kv.init(0, mx.nd.zeros((2,)))

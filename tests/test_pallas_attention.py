@@ -1,5 +1,6 @@
-"""Fused Pallas attention kernel: interpret-mode correctness on CPU, plus
-dropout-path tests that only run when a TPU is attached.
+"""Fused Pallas attention kernel: interpret-mode correctness on CPU. The
+hardware-PRNG dropout contract (same key -> same mask) needs the chip and
+is checked by chip_smoke.py's kernels phase.
 
 Parity target: dot_product_attention semantics (ops/nn.py) — the fused
 kernel must be a drop-in for the XLA path including key-padding masks,
@@ -13,8 +14,6 @@ import jax.numpy as jnp
 
 from mxnet_tpu.ops import pallas_attention as pa
 from mxnet_tpu.ops.nn import dot_product_attention as dpa
-
-ON_TPU = jax.devices()[0].platform == "tpu"
 
 
 def _qkv(B=2, H=3, Tq=64, Tk=64, D=16, dtype=jnp.float32, seed=0):
@@ -177,29 +176,6 @@ def test_fused_dropout_interpret_unbiased():
                                          interpret=True)
                       for i in range(24)])
     plain = pa.fused_attention(q, k, v, interpret=True)
-    rel = float(jnp.abs(outs.mean(0) - plain).mean()
-                / jnp.abs(plain).mean())
-    assert rel < 0.25, rel
-
-
-@pytest.mark.skipif(not ON_TPU, reason="hardware PRNG path needs a TPU")
-def test_fused_dropout_on_tpu():
-    q, k, v = _qkv(Tq=512, Tk=512, D=64)
-    key = jax.random.PRNGKey(42)
-    o1 = pa.fused_attention(q, k, v, dropout_p=0.3, key=key)
-    o2 = pa.fused_attention(q, k, v, dropout_p=0.3, key=key)
-    assert bool(jnp.all(o1 == o2))  # same seed → same mask (bwd relies on it)
-    o3 = pa.fused_attention(q, k, v, dropout_p=0.3,
-                            key=jax.random.PRNGKey(7))
-    assert bool(jnp.any(o1 != o3))
-    g = jax.grad(lambda q: pa.fused_attention(
-        q, k, v, dropout_p=0.3, key=key).sum())(q)
-    assert bool(jnp.isfinite(g).all())
-    # unbiasedness: mean over seeds approaches the no-dropout output
-    outs = jnp.stack([pa.fused_attention(q, k, v, dropout_p=0.3,
-                                         key=jax.random.PRNGKey(i))
-                      for i in range(24)])
-    plain = pa.fused_attention(q, k, v)
     rel = float(jnp.abs(outs.mean(0) - plain).mean()
                 / jnp.abs(plain).mean())
     assert rel < 0.25, rel

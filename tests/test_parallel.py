@@ -224,3 +224,27 @@ def test_comm_report_prices_dp_collectives():
     assert par.ring_cost_bytes("all_gather", 1000, 4) == 750
     assert par.ring_cost_bytes("collective_permute", 1000, 4) == 1000
     assert par.ring_cost_bytes("all_reduce", 1000, 1) == 0
+
+
+def test_trainstep_state_keeps_its_layout_across_calls():
+    """A column-parallel weight makes the partitioner WANT to return its
+    replicated bias sharded like the gradient that updated it; the step
+    must hand its state back in the layout it declared, or the second
+    call is refused ("Sharding passed to jit does not match")."""
+    net = _make_net(seed=3)
+    net.collect_params()["1.weight"].sharding = P("tp", None)
+    mesh = par.make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    step = par.TrainStep(net, gloss.SoftmaxCrossEntropyLoss(),
+                         opt.Adam(learning_rate=0.01), mesh=mesh,
+                         batch_specs=(P("dp"), P("dp")))
+    X = mx.nd.array(np.random.default_rng(4).standard_normal(
+        (8, 16)).astype(np.float32))
+    Y = mx.nd.array(np.random.default_rng(5).integers(0, 4, 8),
+                    dtype="int32")
+    for _ in range(3):
+        assert np.isfinite(float(step(X, Y).asscalar()))
+    assert np.isfinite(step.run_steps(X, Y, steps=2).asnumpy()).all()
+    assert np.isfinite(step.run_steps(X, Y, steps=2).asnumpy()).all()
+    for spec, arr in zip(step.param_sharding_specs(), step._param_arrays):
+        assert arr.sharding.is_equivalent_to(
+            par.NamedSharding(mesh, spec), arr.ndim)

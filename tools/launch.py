@@ -14,6 +14,13 @@ Env contract (consumed by mxnet_tpu.kvstore.init_distributed):
   DMLC_PS_ROOT_URI / DMLC_PS_ROOT_PORT — coordinator address
   DMLC_NUM_WORKER                      — number of processes
   DMLC_WORKER_ID                       — this process's rank
+  JAX_PLATFORMS                        — the platform, from --platform
+
+Every rank is told its platform explicitly (--platform, default cpu). A
+chip belongs to one process at a time and this launcher cannot give each
+local rank a chip of its own, so `--launcher local` refuses to start
+more than one rank on anything but the CPU instead of letting them hang
+on each other.
 
 Usage:
   python tools/launch.py -n 4 python train.py --kv-store dist_sync
@@ -41,6 +48,8 @@ def main(argv=None):
                     help="coordinator host (rank 0's address)")
     ap.add_argument("--port", type=int, default=0,
                     help="coordinator port (0 = pick a free one)")
+    ap.add_argument("--platform", default="cpu",
+                    help="JAX_PLATFORMS given to every rank (default cpu)")
     ap.add_argument("--env", action="append", default=[],
                     help="extra KEY=VALUE for workers (repeatable)")
     ap.add_argument("command", nargs=argparse.REMAINDER)
@@ -59,6 +68,7 @@ def main(argv=None):
         for kv in args.env:
             k, _, v = kv.partition("=")
             env[k] = v
+        env["JAX_PLATFORMS"] = args.platform
         return env
 
     if args.launcher == "manual":
@@ -68,6 +78,11 @@ def main(argv=None):
             print(f"[host {r}] {ev} {' '.join(args.command)}")
         return 0
 
+    if args.platform != "cpu" and args.num_workers > 1:
+        ap.error(f"--launcher local cannot give each of {args.num_workers} "
+                 f"ranks its own {args.platform} chip (one process holds a "
+                 "chip at a time); use --platform cpu, one rank, or "
+                 "--launcher manual with one process per host")
     procs = [subprocess.Popen(args.command, env=worker_env(r))
              for r in range(args.num_workers)]
 
