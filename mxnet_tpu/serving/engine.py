@@ -214,9 +214,6 @@ def _engine_metrics(eid):
             "serving_token_latency_seconds",
             "per-token decode latency at dispatch resolution "
             "(dispatch wall / tokens the slot emitted, weighted)", _E),
-        "prefill_seconds": h("serving_prefill_seconds",
-                             "wall time of unified dispatches that "
-                             "carried at least one prefill chunk", _E),
         "decode_seconds": h("serving_decode_dispatch_seconds",
                             "unified dispatch wall time", _E),
         "drain_seconds": h("serving_drain_seconds",
@@ -343,6 +340,7 @@ def _engine_metrics(eid):
             "tier", _E),
     }
     _shed_family()                  # registered per-process; children
+    _tick_phase_family()
     _tenant_families()
     _ttft_family()
     _ttft_phase_family()
@@ -391,6 +389,39 @@ def _weight_bytes_family():
         "device bytes of the served weight operands, by storage dtype "
         "(int8 code slabs vs float32 params + dequant scales)",
         ("engine", "dtype"))
+
+
+# The spans of one scheduling tick, by phase (the span's name without
+# `serving.`), each nested where ServingEngine._tick_span opens it:
+# step > admit > sync_slot; step > assemble; step > dispatch >
+# dispatch.{launch,wait,fetch}; step > fanout > finish > sync_slot.
+TICK_PHASES = ("step", "admit", "sync_slot", "assemble", "dispatch",
+               "dispatch.launch", "dispatch.wait", "dispatch.fetch",
+               "fanout", "finish")
+
+
+def _tick_phase_family():
+    """Host seconds of the scheduling tick by phase: each tick span's
+    SELF time (its duration minus its child spans'), so the phases
+    partition the ticks' wall and sum to the total of `serving.step`."""
+    return telemetry.counter(
+        "serving_tick_phase_seconds_total",
+        "self time of the serving.<phase> spans of step(): host seconds "
+        "of the scheduling tick by phase, summing to the wall of "
+        "serving.step", ("engine", "phase"))
+
+
+class _TickSpan(span):
+    """A telemetry span that adds its self time to one child of
+    serving_tick_phase_seconds_total when it closes: the counter is the
+    spans' sum, not a second clock."""
+
+    __slots__ = ("_counter",)
+
+    def __exit__(self, exc_type, exc_val, exc_tb):
+        super().__exit__(exc_type, exc_val, exc_tb)
+        self._counter.inc(self.self_s)
+        return False
 
 
 def _shed_family():
@@ -920,6 +951,10 @@ class ServingEngine:
         self._metrics["num_slots"].set(self.num_slots)
         self._wbytes_fam = _weight_bytes_family()
         self._set_static_gauges()
+        self._tick = 0             # step() calls so far (span attr)
+        fam = _tick_phase_family()
+        self._tick_children = {ph: fam.labels(self._eid, ph)
+                               for ph in TICK_PHASES}
         self._shed = _shed_family()
         self._shed_children = {}   # (reason, priority) -> labeled child
         self._shed_counts = {}     # same keys, host-side for stats
@@ -1046,6 +1081,8 @@ class ServingEngine:
             "preempts": int(m["preempts"].value),
             "preempt_resumed": int(m["preempt_resumed"].value),
             "preempt_restarted": int(m["preempt_restarted"].value),
+            "tick_phase_seconds": {ph: c.value for ph, c
+                                   in self._tick_children.items()},
         }
 
     def tenant_stats(self):
@@ -1077,6 +1114,8 @@ class ServingEngine:
             inst.reset()
         for child in self._shed_children.values():
             child.reset()
+        for child in self._tick_children.values():
+            child.reset()
         self._metrics["num_slots"].set(self.num_slots)
         self._set_static_gauges()
         self._shed_counts = {}
@@ -1089,6 +1128,14 @@ class ServingEngine:
         self._adapter_evictions_seen = 0
         self._metrics["num_slots"].set(self.num_slots)
         self._set_pool_gauges()
+
+    def _tick_span(self, phase, **attrs):
+        """The span `serving.<phase>` of one scheduling tick; on exit
+        its self time lands in serving_tick_phase_seconds_total
+        (`stats["tick_phase_seconds"][phase]`)."""
+        sp = _TickSpan("serving." + phase, engine=self._eid, **attrs)
+        sp._counter = self._tick_children[phase]
+        return sp
 
     def _shed_inc(self, reason, priority, tenant=None):
         key = (reason, int(priority))
@@ -1842,50 +1889,57 @@ class ServingEngine:
 
         Returns every request that reached a TERMINAL state this round:
         finished, deadline-shed/-cancelled, or quarantined."""
-        now = self._clock()
-        self._fire_hook("step")
-        finished = []
-        for req in self.scheduler.pop_expired(now):
-            finished.append(self._shed_expired(req))
-        for slot in list(self.scheduler.active_slots):
-            req = self.scheduler.request_at(slot)
-            if req.t_deadline is not None and now >= req.t_deadline:
-                finished.append(self._deadline_cancel(slot))
-        for slot, req in self.scheduler.admit(now):
-            try:
-                fin = self._admit(slot, req)
-            except Exception as e:          # noqa: BLE001 — supervisor
-                q = self._on_admit_fault(slot, req, e)
-                if q is not None:
-                    finished.append(q)
-                continue
-            if fin is not None:
-                finished.append(fin)
-        if self.policy is not None:
-            # Assess AFTER admission: the overload level must reflect the
-            # backlog this tick's dispatch actually leaves queued, not the
-            # pre-admission spike that free slots are about to absorb.
-            self.policy.on_step(self, now)
-            if self.host_pool is not None \
-                    and hasattr(self.policy, "preempt_victim"):
-                # whole-request swap: with every slot busy and strictly
-                # more-urgent work queued, swap the least-urgent running
-                # request out through the host tier — its slot admits
-                # the urgent request next tick, and it resumes
-                # bit-identically later (page-in or replay)
-                victim = self.policy.preempt_victim(self)
-                if victim is not None:
-                    self._preempt_slot(victim)
-        self._set_load_gauges()
-        if self.scheduler.num_active:
-            try:
-                finished.extend(self._dispatch())
-            except _cost.ProgramCompileError:
-                raise
-            except Exception as e:          # noqa: BLE001 — supervisor
-                finished.extend(self._on_decode_fault(e))
+        self._tick += 1
+        with self._tick_span("step", tick=self._tick,
+                             queued=self.scheduler.num_queued,
+                             active=self.scheduler.num_active):
+            now = self._clock()
+            self._fire_hook("step")
+            finished = []
+            for req in self.scheduler.pop_expired(now):
+                finished.append(self._shed_expired(req))
+            for slot in list(self.scheduler.active_slots):
+                req = self.scheduler.request_at(slot)
+                if req.t_deadline is not None and now >= req.t_deadline:
+                    finished.append(self._deadline_cancel(slot))
+            for slot, req in self.scheduler.admit(now):
+                try:
+                    with self._tick_span("admit", slot=slot,
+                                         request=req.id,
+                                         prompt_len=req.prompt_len):
+                        fin = self._admit(slot, req)
+                except Exception as e:          # noqa: BLE001 — supervisor
+                    q = self._on_admit_fault(slot, req, e)
+                    if q is not None:
+                        finished.append(q)
+                    continue
+                if fin is not None:
+                    finished.append(fin)
+            if self.policy is not None:
+                # Assess AFTER admission: the overload level must reflect the
+                # backlog this tick's dispatch actually leaves queued, not the
+                # pre-admission spike that free slots are about to absorb.
+                self.policy.on_step(self, now)
+                if self.host_pool is not None \
+                        and hasattr(self.policy, "preempt_victim"):
+                    # whole-request swap: with every slot busy and strictly
+                    # more-urgent work queued, swap the least-urgent running
+                    # request out through the host tier — its slot admits
+                    # the urgent request next tick, and it resumes
+                    # bit-identically later (page-in or replay)
+                    victim = self.policy.preempt_victim(self)
+                    if victim is not None:
+                        self._preempt_slot(victim)
             self._set_load_gauges()
-        return finished
+            if self.scheduler.num_active:
+                try:
+                    finished.extend(self._dispatch())
+                except _cost.ProgramCompileError:
+                    raise
+                except Exception as e:          # noqa: BLE001 — supervisor
+                    finished.extend(self._on_decode_fault(e))
+                self._set_load_gauges()
+            return finished
 
     @loop_only
     def serve(self, requests=()):
@@ -2264,16 +2318,17 @@ class ServingEngine:
         row and the pool's page_lock mask, which change in the same
         events) to the device-resident copies. Called on admission,
         finish and cancel — never per decode dispatch."""
-        vals = (self._lengths[slot], self._cur_tok[slot],
-                self._done[slot], self._remaining[slot],
-                self._counters[slot], self._seeds[slot],
-                self._temp[slot], self._top_k[slot], self._top_p[slot],
-                self._do_sample[slot], self._eos[slot])
-        if self.adapter_pool is not None:
-            vals = vals + (self._aslot[slot],)
-        self._dstate = self._upload_fn(self._dstate, np.int32(slot),
-                                       vals, self._table_host[slot])
-        self._d_lock = self._rep(jnp.asarray(self._page_lock_host()))
+        with self._tick_span("sync_slot", slot=slot):
+            vals = (self._lengths[slot], self._cur_tok[slot],
+                    self._done[slot], self._remaining[slot],
+                    self._counters[slot], self._seeds[slot],
+                    self._temp[slot], self._top_k[slot], self._top_p[slot],
+                    self._do_sample[slot], self._eos[slot])
+            if self.adapter_pool is not None:
+                vals = vals + (self._aslot[slot],)
+            self._dstate = self._upload_fn(self._dstate, np.int32(slot),
+                                           vals, self._table_host[slot])
+            self._d_lock = self._rep(jnp.asarray(self._page_lock_host()))
 
     def _adapter_args(self, aslot):
         """The extra dispatch operands when the adapter pool is on: the
@@ -2948,6 +3003,8 @@ class ServingEngine:
             self._hist[slot] = None     # drafting starts after prefill
         self._sync_slot(slot)
         m["prefill_pending"].set(self._pending_tokens())
+        # the page map above leased pages whatever the engine's options
+        m["pool_free_pages"].set(self.page_pool.num_free)
         if pc is not None or self.adapter_pool is not None:
             self._set_pool_gauges()
         return None
@@ -3271,89 +3328,90 @@ class ServingEngine:
         fixed-shape program, then fan the results back out — emitted
         tokens, chunk-cursor advances, first tokens of prompts whose
         final chunk landed, and finish/rollback bookkeeping."""
-        spec = self.speculative
-        spec_on = spec and not self._degraded
-        B, W = self.num_slots, self._width
-        S = self.spec_tokens if spec else 1
-        toks_in = np.zeros((B, W), np.int32)
-        chunk_len = np.zeros(B, np.int32)
-        is_final = np.zeros(B, bool)
-        decode_mask = np.zeros(B, bool)
-        drafts = np.zeros((B, S - 1), np.int32) if spec else None
-        n_draft = np.zeros(B, np.int32)
-        budget = self.prefill_chunk_budget
-        active_slots = list(self.scheduler.active_slots)
-        self._fire_hook("decode", [self.scheduler.request_at(s)
-                                   for s in active_slots])
-        # prefill-budget fairness: visit slots round-robin from a
-        # rotating cursor, so concurrent long prompts take turns when
-        # the budget can't cover everyone each dispatch
-        for slot in sorted(active_slots,
-                           key=lambda s: (s - self._chunk_rr) % B):
-            pend = self._pending[slot]
-            if pend is not None and pend.size:
-                rq = self._replay[slot]
-                if rq:
-                    # quantized restart: feed the recorded chunk size
-                    # exactly — splitting it would re-quantize deep
-                    # layers under different scale views and break
-                    # continuation bit-identity. A chunk the current
-                    # dispatch budget can't cover waits for a fresh
-                    # budget; only one that can NEVER fit is split.
-                    want = min(int(rq[0]), self.chunk_tokens)
-                    if want > budget and want <= self.prefill_chunk_budget:
-                        continue
-                    n = min(want, budget)
-                    if n <= 0:
-                        continue
-                    if n >= int(rq[0]):
-                        rq.popleft()
+        with self._tick_span("assemble"):
+            spec = self.speculative
+            spec_on = spec and not self._degraded
+            B, W = self.num_slots, self._width
+            S = self.spec_tokens if spec else 1
+            toks_in = np.zeros((B, W), np.int32)
+            chunk_len = np.zeros(B, np.int32)
+            is_final = np.zeros(B, bool)
+            decode_mask = np.zeros(B, bool)
+            drafts = np.zeros((B, S - 1), np.int32) if spec else None
+            n_draft = np.zeros(B, np.int32)
+            budget = self.prefill_chunk_budget
+            active_slots = list(self.scheduler.active_slots)
+            self._fire_hook("decode", [self.scheduler.request_at(s)
+                                       for s in active_slots])
+            # prefill-budget fairness: visit slots round-robin from a
+            # rotating cursor, so concurrent long prompts take turns when
+            # the budget can't cover everyone each dispatch
+            for slot in sorted(active_slots,
+                               key=lambda s: (s - self._chunk_rr) % B):
+                pend = self._pending[slot]
+                if pend is not None and pend.size:
+                    rq = self._replay[slot]
+                    if rq:
+                        # quantized restart: feed the recorded chunk size
+                        # exactly — splitting it would re-quantize deep
+                        # layers under different scale views and break
+                        # continuation bit-identity. A chunk the current
+                        # dispatch budget can't cover waits for a fresh
+                        # budget; only one that can NEVER fit is split.
+                        want = min(int(rq[0]), self.chunk_tokens)
+                        if want > budget and want <= self.prefill_chunk_budget:
+                            continue
+                        n = min(want, budget)
+                        if n <= 0:
+                            continue
+                        if n >= int(rq[0]):
+                            rq.popleft()
+                        else:
+                            rq[0] = int(rq[0]) - n
                     else:
-                        rq[0] = int(rq[0]) - n
-                else:
-                    n = min(int(pend.size), self.chunk_tokens, budget)
-                    if n <= 0:
-                        continue    # budget spent: the chunk waits
-                    if self._quant:
-                        self.scheduler.request_at(slot) \
-                            .kv_history.append(n)
-                budget -= n
-                toks_in[slot, :n] = pend[:n]
-                chunk_len[slot] = n
-                is_final[slot] = n == pend.size
-            elif not self._done[slot] and self._remaining[slot] > 0:
-                decode_mask[slot] = True
-                toks_in[slot, 0] = self._cur_tok[slot]
-                if spec_on and self._hist[slot] is not None:
-                    d = self._proposer.propose(self._hist[slot])
-                    n_draft[slot] = d.size
-                    drafts[slot, :d.size] = d
-                    toks_in[slot, 1:1 + d.size] = d
-        self._chunk_rr = (self._chunk_rr + 1) % B
-        fn = self._unified_fn()
-        param_datas = self._placed_params()
-        st = self._dstate
-        (lengths, cur_tok, done, remaining, counters, seeds, temp,
-         top_k, top_p, do_sample, eos) = st[:11]
-        tail, table = st[11:-1], st[-1]   # (aslot,) with the pool on
-        extra = (jnp.asarray(drafts), jnp.asarray(n_draft)) \
-            if spec else ()
-        if self._quant:
-            extra = extra + (self._ks, self._vs)
-        if self._w8:
-            extra = extra + self._w8_scale_ops
+                        n = min(int(pend.size), self.chunk_tokens, budget)
+                        if n <= 0:
+                            continue    # budget spent: the chunk waits
+                        if self._quant:
+                            self.scheduler.request_at(slot) \
+                                .kv_history.append(n)
+                    budget -= n
+                    toks_in[slot, :n] = pend[:n]
+                    chunk_len[slot] = n
+                    is_final[slot] = n == pend.size
+                elif not self._done[slot] and self._remaining[slot] > 0:
+                    decode_mask[slot] = True
+                    toks_in[slot, 0] = self._cur_tok[slot]
+                    if spec_on and self._hist[slot] is not None:
+                        d = self._proposer.propose(self._hist[slot])
+                        n_draft[slot] = d.size
+                        drafts[slot, :d.size] = d
+                        toks_in[slot, 1:1 + d.size] = d
+            self._chunk_rr = (self._chunk_rr + 1) % B
+            fn = self._unified_fn()
+            param_datas = self._placed_params()
+            st = self._dstate
+            (lengths, cur_tok, done, remaining, counters, seeds, temp,
+             top_k, top_p, do_sample, eos) = st[:11]
+            tail, table = st[11:-1], st[-1]   # (aslot,) with the pool on
+            extra = (jnp.asarray(drafts), jnp.asarray(n_draft)) \
+                if spec else ()
+            if self._quant:
+                extra = extra + (self._ks, self._vs)
+            if self._w8:
+                extra = extra + self._w8_scale_ops
         t0 = self._clock()
-        with span("serving.dispatch", engine=self._eid,
-                  active=len(active_slots),
-                  prefill_tokens=int(chunk_len.sum()),
-                  drafted=int(n_draft.sum())):
-            out = fn(
-                param_datas, self._kp, self._vp, table, self._d_lock,
-                lengths, cur_tok, done, remaining, counters, seeds,
-                temp, top_k, top_p, do_sample, eos,
-                jnp.asarray(toks_in), jnp.asarray(chunk_len),
-                jnp.asarray(is_final), jnp.asarray(decode_mask),
-                *extra, *self._adapter_args(tail))
+        with self._tick_span("dispatch", active=len(active_slots),
+                             prefill_tokens=int(chunk_len.sum()),
+                             drafted=int(n_draft.sum())):
+            with self._tick_span("dispatch.launch"):
+                out = fn(
+                    param_datas, self._kp, self._vp, table, self._d_lock,
+                    lengths, cur_tok, done, remaining, counters, seeds,
+                    temp, top_k, top_p, do_sample, eos,
+                    jnp.asarray(toks_in), jnp.asarray(chunk_len),
+                    jnp.asarray(is_final), jnp.asarray(decode_mask),
+                    *extra, *self._adapter_args(tail))
             if self._quant:
                 (self._kp, self._vp, lengths, cur_tok, done, remaining,
                  counters, okc, toks, n_em, n_acc,
@@ -3364,166 +3422,172 @@ class ServingEngine:
             self._dstate = (lengths, cur_tok, done, remaining, counters,
                             seeds, temp, top_k, top_p, do_sample,
                             eos) + tail + (table,)
-            # ONE host sync per dispatch: everything small fetches
-            # together (the pools stay on device, donated through)
-            (self._lengths, self._cur_tok, self._done, self._remaining,
-             self._counters) = (
-                np.array(lengths), np.array(cur_tok), np.array(done),
-                np.array(remaining), np.array(counters))
-            toks, n_em, n_acc, ok = (np.asarray(toks), np.asarray(n_em),
-                                     np.asarray(n_acc),
-                                     np.asarray(okc))
+            # the wait for the device, apart from the copies: block on
+            # the small outputs (the pools stay on device, donated
+            # through), then fetch them — nine device-to-host copies,
+            # one after another
+            with self._tick_span("dispatch.wait"):
+                jax.block_until_ready((lengths, cur_tok, done, remaining,
+                                       counters, okc, toks, n_em, n_acc))
+            with self._tick_span("dispatch.fetch"):
+                (self._lengths, self._cur_tok, self._done,
+                 self._remaining, self._counters) = (
+                    np.array(lengths), np.array(cur_tok), np.array(done),
+                    np.array(remaining), np.array(counters))
+                toks, n_em, n_acc, ok = (
+                    np.asarray(toks), np.asarray(n_em),
+                    np.asarray(n_acc), np.asarray(okc))
         now = self._clock()
         dt = now - t0
-        m = self._metrics
-        m["decode_dispatches"].inc()
-        m["decode_steps"].inc()
-        m["decode_seconds"].observe(dt)
-        n_chunks = int((chunk_len > 0).sum())
-        if n_chunks:
-            m["prefill_chunks"].inc(n_chunks)
-            m["prefill_tokens"].inc(int(chunk_len.sum()))
-            m["prefill_seconds"].observe(dt)
-        rl = telemetry.request_log
-        finished = []
-        bad = []
-        overflowed = []
-        n_emitted = 0
-        accepted = 0
-        for slot in active_slots:
-            req = self.scheduler.request_at(slot)
-            if not ok[slot]:
-                # non-finite logits: every token this dispatch produced
-                # for the slot is garbage — discard it all, roll the
-                # request back (handled below, after accounting)
-                bad.append(slot)
-                continue
-            cl = int(chunk_len[slot])
-            if cl:
-                self._pending[slot] = self._pending[slot][cl:]
-                self._chunks_fed[slot] += 1
-                if rl.enabled:
-                    rl.event(req.id, self._eid, "prefill_chunk",
-                             dur=dt, tokens=cl,
-                             final=bool(is_final[slot]))
-                if not is_final[slot]:
+        with self._tick_span("fanout"):
+            m = self._metrics
+            m["decode_dispatches"].inc()
+            m["decode_steps"].inc()
+            m["decode_seconds"].observe(dt)
+            n_chunks = int((chunk_len > 0).sum())
+            if n_chunks:
+                m["prefill_chunks"].inc(n_chunks)
+                m["prefill_tokens"].inc(int(chunk_len.sum()))
+            rl = telemetry.request_log
+            finished = []
+            bad = []
+            overflowed = []
+            n_emitted = 0
+            accepted = 0
+            for slot in active_slots:
+                req = self.scheduler.request_at(slot)
+                if not ok[slot]:
+                    # non-finite logits: every token this dispatch produced
+                    # for the slot is garbage — discard it all, roll the
+                    # request back (handled below, after accounting)
+                    bad.append(slot)
+                    continue
+                cl = int(chunk_len[slot])
+                if cl:
+                    self._pending[slot] = self._pending[slot][cl:]
+                    self._chunks_fed[slot] += 1
+                    if rl.enabled:
+                        rl.event(req.id, self._eid, "prefill_chunk",
+                                 dur=dt, tokens=cl,
+                                 final=bool(is_final[slot]))
+                    if not is_final[slot]:
+                        req.dispatch_failures = 0
+                        req.t_not_before = 0.0
+                        continue
+                    # final chunk: the request's first token landed in the
+                    # same dispatch — the slot decodes from the next tick
+                    self._pending[slot] = None
+                    self._replay[slot] = None
+                    first = int(toks[slot, 0])
+                    req.output_tokens.append(first)
+                    req.token_times.append(now)
+                    streamed = self._stream_emit(req, [first])
                     req.dispatch_failures = 0
                     req.t_not_before = 0.0
+                    req.status = "running"
+                    rl.event(req.id, self._eid, "prefill", dur=dt,
+                             first_token=first)
+                    m["prefills"].inc()
+                    n_emitted += 1
+                    if not self._base[slot]:
+                        req.t_admit = now
+                        ttft = now - req.t_submit
+                        tier = self._kv_tier[slot]
+                        m["ttft"].observe(ttft)
+                        self._observe_ttft(req.prompt_len, ttft, tier)
+                        # close the TTFT phase budget: everything between
+                        # the admit mark and this dispatch's start is
+                        # prefill_chunks (earlier chunk dispatches + the
+                        # waits between them); the dispatch that sampled
+                        # the first token is first_decode. With the marks
+                        # on ONE clock the five phases sum to TTFT exactly
+                        # (minus re-queue gaps on restart/migration paths).
+                        t_mark = getattr(req, "t_mark", None)
+                        if rl.enabled and t_mark is not None:
+                            self._phase(req, "prefill_chunks", t0 - t_mark,
+                                        chunks=int(self._chunks_fed[slot]))
+                            self._phase(req, "first_decode", dt)
+                            rl.event(req.id, self._eid, "first_token",
+                                     ttft=ttft, kv_tier=tier)
+                        self._observe_phase_budget(req, tier)
+                        telemetry.slo.observe_ttft(
+                            ttft, priority=req.priority, tenant=req.tenant)
+                    pc = self.prefix_cache
+                    if pc is not None:
+                        # adopt the PROMPT's full pages into the radix
+                        # tree: the next request sharing this prefix
+                        # attaches instead of recomputing. Membership
+                        # changes the page_lock mask — refresh the device
+                        # copy before the next dispatch.
+                        n_full = req.prompt_len // self.page_size
+                        if n_full:
+                            pc.insert(
+                                req.prompt,
+                                [int(p)
+                                 for p in self._table_host[slot][:n_full]])
+                            self._d_lock = self._rep(jnp.asarray(
+                                self._page_lock_host()))
+                        self._set_pool_gauges()
+                    if spec:
+                        self._hist[slot] = [int(t) for t in req.prompt] \
+                            + [int(t) for t in req.output_tokens]
+                    if not streamed:
+                        overflowed.append(slot)
+                    elif self._done[slot] or self._remaining[slot] <= 0:
+                        finished.append(self._finish(slot))
                     continue
-                # final chunk: the request's first token landed in the
-                # same dispatch — the slot decodes from the next tick
-                self._pending[slot] = None
-                self._replay[slot] = None
-                first = int(toks[slot, 0])
-                req.output_tokens.append(first)
-                req.token_times.append(now)
-                streamed = self._stream_emit(req, [first])
+                if not decode_mask[slot]:
+                    continue            # chunk queued but out of budget
+                n = int(n_em[slot])
+                emitted = [int(t) for t in toks[slot, :n]]
+                req.output_tokens.extend(emitted)
+                req.token_times.extend([now] * n)
+                streamed = self._stream_emit(req, emitted) if n else True
+                # a clean dispatch clears the request's failure history —
+                # probation is for consecutive faults, not per-lifetime
                 req.dispatch_failures = 0
                 req.t_not_before = 0.0
-                req.status = "running"
-                rl.event(req.id, self._eid, "prefill", dur=dt,
-                         first_token=first)
-                m["prefills"].inc()
-                n_emitted += 1
-                if not self._base[slot]:
-                    req.t_admit = now
-                    ttft = now - req.t_submit
-                    tier = self._kv_tier[slot]
-                    m["ttft"].observe(ttft)
-                    self._observe_ttft(req.prompt_len, ttft, tier)
-                    # close the TTFT phase budget: everything between
-                    # the admit mark and this dispatch's start is
-                    # prefill_chunks (earlier chunk dispatches + the
-                    # waits between them); the dispatch that sampled
-                    # the first token is first_decode. With the marks
-                    # on ONE clock the five phases sum to TTFT exactly
-                    # (minus re-queue gaps on restart/migration paths).
-                    t_mark = getattr(req, "t_mark", None)
-                    if rl.enabled and t_mark is not None:
-                        self._phase(req, "prefill_chunks", t0 - t_mark,
-                                    chunks=int(self._chunks_fed[slot]))
-                        self._phase(req, "first_decode", dt)
-                        rl.event(req.id, self._eid, "first_token",
-                                 ttft=ttft, kv_tier=tier)
-                    self._observe_phase_budget(req, tier)
-                    telemetry.slo.observe_ttft(
-                        ttft, priority=req.priority, tenant=req.tenant)
-                pc = self.prefix_cache
-                if pc is not None:
-                    # adopt the PROMPT's full pages into the radix
-                    # tree: the next request sharing this prefix
-                    # attaches instead of recomputing. Membership
-                    # changes the page_lock mask — refresh the device
-                    # copy before the next dispatch.
-                    n_full = req.prompt_len // self.page_size
-                    if n_full:
-                        pc.insert(
-                            req.prompt,
-                            [int(p)
-                             for p in self._table_host[slot][:n_full]])
-                        self._d_lock = self._rep(jnp.asarray(
-                            self._page_lock_host()))
-                    self._set_pool_gauges()
-                if spec:
-                    self._hist[slot] = [int(t) for t in req.prompt] \
-                        + [int(t) for t in req.output_tokens]
+                if spec and self._hist[slot] is not None:
+                    self._hist[slot].extend(emitted)
+                if rl.enabled:
+                    if spec:
+                        rl.event(req.id, self._eid, "verify", dur=dt,
+                                 drafted=int(n_draft[slot]),
+                                 accepted=int(n_acc[slot]), tokens=n)
+                    else:
+                        rl.event(req.id, self._eid, "decode", dur=dt,
+                                 tokens=n)
+                n_emitted += n
+                accepted += int(n_acc[slot])
+                # dispatch resolution: a slot that got n of this dispatch's
+                # tokens saw dt/n per token — the ACTUAL emitted count
+                if n:
+                    m["token_latency"].observe(dt / n, n)
                 if not streamed:
                     overflowed.append(slot)
                 elif self._done[slot] or self._remaining[slot] <= 0:
                     finished.append(self._finish(slot))
-                continue
-            if not decode_mask[slot]:
-                continue            # chunk queued but out of budget
-            n = int(n_em[slot])
-            emitted = [int(t) for t in toks[slot, :n]]
-            req.output_tokens.extend(emitted)
-            req.token_times.extend([now] * n)
-            streamed = self._stream_emit(req, emitted) if n else True
-            # a clean dispatch clears the request's failure history —
-            # probation is for consecutive faults, not per-lifetime
-            req.dispatch_failures = 0
-            req.t_not_before = 0.0
-            if spec and self._hist[slot] is not None:
-                self._hist[slot].extend(emitted)
-            if rl.enabled:
-                if spec:
-                    rl.event(req.id, self._eid, "verify", dur=dt,
-                             drafted=int(n_draft[slot]),
-                             accepted=int(n_acc[slot]), tokens=n)
-                else:
-                    rl.event(req.id, self._eid, "decode", dur=dt,
-                             tokens=n)
-            n_emitted += n
-            accepted += int(n_acc[slot])
-            # dispatch resolution: a slot that got n of this dispatch's
-            # tokens saw dt/n per token — the ACTUAL emitted count
-            if n:
-                m["token_latency"].observe(dt / n, n)
-            if not streamed:
-                overflowed.append(slot)
-            elif self._done[slot] or self._remaining[slot] <= 0:
-                finished.append(self._finish(slot))
-        for slot in overflowed:
-            finished.append(self._overflow_cancel(slot))
-        m["tokens_emitted"].inc(n_emitted)
-        m["prefill_pending"].set(self._pending_tokens())
-        if spec:
-            drafted = int(n_draft.sum())
-            m["spec_draft_tokens"].inc(drafted)
-            m["spec_accepted_tokens"].inc(accepted)
-            m["spec_rollbacks"].inc(drafted - accepted)
-            # goodput: the unified program computes B x W query
-            # positions a dispatch; the drafted-but-rejected share is
-            # speculation waste (idle padding is a separate,
-            # structural cost the MFU gauges already show)
-            waste = (drafted - accepted) / (B * W)
-        else:
-            waste = 0.0
-        self._account_flops(fn.program, dt, wasted_fraction=waste)
-        if bad:
-            finished.extend(self._on_bad_slots(
-                bad, "non-finite logits in unified dispatch"))
-        return finished
+            for slot in overflowed:
+                finished.append(self._overflow_cancel(slot))
+            m["tokens_emitted"].inc(n_emitted)
+            m["prefill_pending"].set(self._pending_tokens())
+            if spec:
+                drafted = int(n_draft.sum())
+                m["spec_draft_tokens"].inc(drafted)
+                m["spec_accepted_tokens"].inc(accepted)
+                m["spec_rollbacks"].inc(drafted - accepted)
+                # goodput: the unified program computes B x W query
+                # positions a dispatch; the drafted-but-rejected share is
+                # speculation waste (idle padding is a separate,
+                # structural cost the MFU gauges already show)
+                waste = (drafted - accepted) / (B * W)
+            else:
+                waste = 0.0
+            self._account_flops(fn.program, dt, wasted_fraction=waste)
+            if bad:
+                finished.extend(self._on_bad_slots(
+                    bad, "non-finite logits in unified dispatch"))
+            return finished
 
     # -- per-request token streaming (serving/frontend.py subscribes) ------
     def _stream_emit(self, req, tokens):
@@ -3604,24 +3668,27 @@ class ServingEngine:
         self._aslot[slot] = 0
 
     def _finish(self, slot):
-        # read the stop cause BEFORE release zeroes the slot state:
-        # budget exhaustion leaves remaining <= 0, eos leaves budget
-        reason = "budget" if self._remaining[slot] <= 0 else "eos"
-        req = self._release_slot(slot)
-        req.status = "finished"
-        self._finish_times.append(self._clock())   # drain-rate window
-        self._metrics["requests_finished"].inc()
-        if req.t_admit is not None and req.t_finish > req.t_admit \
-                and len(req.output_tokens) > 1:
-            # per-request decode goodput (tokens/s from first token to
-            # finish) — the goodput_min SLO's observation stream
-            telemetry.slo.observe_goodput(
-                (len(req.output_tokens) - 1)
-                / (req.t_finish - req.t_admit),
-                priority=req.priority, tenant=req.tenant)
-        telemetry.request_log.end(
-            req.id, self._eid, "finished", reason=reason,
-            tokens=len(req.output_tokens))
-        self._stream_close(req)
-        self._set_pool_gauges()
-        return req
+        with self._tick_span(
+                "finish", slot=slot,
+                request=self.scheduler.request_at(slot).id):
+            # read the stop cause BEFORE release zeroes the slot state:
+            # budget exhaustion leaves remaining <= 0, eos leaves budget
+            reason = "budget" if self._remaining[slot] <= 0 else "eos"
+            req = self._release_slot(slot)
+            req.status = "finished"
+            self._finish_times.append(self._clock())   # drain-rate window
+            self._metrics["requests_finished"].inc()
+            if req.t_admit is not None and req.t_finish > req.t_admit \
+                    and len(req.output_tokens) > 1:
+                # per-request decode goodput (tokens/s from first token to
+                # finish) — the goodput_min SLO's observation stream
+                telemetry.slo.observe_goodput(
+                    (len(req.output_tokens) - 1)
+                    / (req.t_finish - req.t_admit),
+                    priority=req.priority, tenant=req.tenant)
+            telemetry.request_log.end(
+                req.id, self._eid, "finished", reason=reason,
+                tokens=len(req.output_tokens))
+            self._stream_close(req)
+            self._set_pool_gauges()
+            return req

@@ -124,8 +124,9 @@ class FlightRecorder:
         self._watchdog.start()
 
     # -- the ring ----------------------------------------------------------
-    def record(self, kind, **attrs):
-        """Append one breadcrumb to the ring (cheap: one lock + append)."""
+    def record(self, kind, /, **attrs):
+        """Append one breadcrumb to the ring (cheap: one lock + append).
+        Positional-only head: a span event carries a key named `self`."""
         ev = dict(kind=kind, t=time.time(), **attrs)
         with self._ring_lock:
             self._ring.append(ev)

@@ -13,8 +13,14 @@ funnel uses (ops/registry._profiler_active): a process that never starts
 a device trace never constructs an annotation.
 
 Event schema (one JSON object per line):
-    {"name", "ts" (unix seconds at exit), "dur" (seconds), "depth",
-     "parent" (enclosing span name or null), "thread", ...attrs}
+    {"name", "ts" (unix seconds at exit), "dur" (seconds), "self"
+     (seconds: dur minus what the spans nested directly inside it
+     covered), "depth", "parent" (enclosing span name or null),
+     "thread", ...attrs}
+
+Self times partition their root: over a finished tree of spans the
+`self` values sum to the root's `dur`. After exit the span object keeps
+both as `dur` and `self_s`, for a caller that books them.
 
 A span exited by a raising block records `status="error"` plus the
 exception type under `"error"` — the exception itself propagates
@@ -64,7 +70,8 @@ def _stack():
 class span:
     """with span("serving.decode_block", slot=3): ..."""
 
-    __slots__ = ("name", "attrs", "_ann", "_t0", "_parent", "_depth")
+    __slots__ = ("name", "attrs", "dur", "self_s", "_ann", "_t0",
+                 "_parent", "_depth", "_covered")
 
     def __init__(self, name, **attrs):
         self.name = name
@@ -73,8 +80,9 @@ class span:
 
     def __enter__(self):
         st = _stack()
-        self._parent = st[-1].name if st else None
+        self._parent = st[-1] if st else None
         self._depth = len(st)
+        self._covered = 0.0             # seconds inside child spans
         st.append(self)
         if _device_trace_running():
             import jax
@@ -84,7 +92,11 @@ class span:
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb):
-        dur = time.perf_counter() - self._t0
+        self.dur = dur = time.perf_counter() - self._t0
+        self.self_s = dur - self._covered
+        parent = self._parent
+        if parent is not None:
+            parent._covered += dur
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc_val, exc_tb)
             self._ann = None
@@ -93,7 +105,8 @@ class span:
             st.pop()
         _span_hist().labels(self.name).observe(dur)
         ev = {"name": self.name, "ts": time.time(), "dur": dur,
-              "depth": self._depth, "parent": self._parent,
+              "self": self.self_s, "depth": self._depth,
+              "parent": parent.name if parent is not None else None,
               "thread": threading.get_ident()}
         if exc_type is not None:
             # a raising block still records its span — tagged, so the
