@@ -368,7 +368,9 @@ def _kernel_fused():
 
 def _kernel_span():
     """ragged_span_attention at GPT-2 774M widths, Sq=64 (prefill chunk)
-    and Sq=1 (decode), bf16 and int8 pages, against the dense reference."""
+    and Sq=1 (decode), bf16 and int8 pages, against the dense reference.
+    The pools are packed (L, N, S, H*D) as PagedKVCache stores them, with
+    the data in layer 1 of 2 so that the kernel's layer index is run."""
     rng = np.random.default_rng(SEED)
     B, H, D, S, P = 8, 20, 64, 64, 16
     N = B * P
@@ -376,6 +378,10 @@ def _kernel_span():
     table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
     kf = rng.standard_normal((N, S, H, D)).astype(np.float32)
     vf = rng.standard_normal((N, S, H, D)).astype(np.float32)
+
+    def pool(x, dtype):
+        return jnp.asarray(np.stack([0 * x, x]), dtype).reshape(2, N, S, -1)
+
     for sq in (64, 1):
         q = jnp.asarray(rng.standard_normal((B, sq, H, D)), jnp.bfloat16)
         # mixed work: full, partial, idle and long-context slots
@@ -385,26 +391,24 @@ def _kernel_span():
                              jnp.int32)
         for pages in ("bf16", "int8"):
             if pages == "bf16":
-                kp, vp = (jnp.asarray(x, jnp.bfloat16) for x in (kf, vf))
+                kp, vp = (pool(x, jnp.bfloat16) for x in (kf, vf))
                 scales = {}
             else:
                 ks = np.abs(kf).max(axis=(1, 3)) / 127.0      # (N, H)
                 vs = np.abs(vf).max(axis=(1, 3)) / 127.0
-                kp = jnp.asarray(np.round(kf / ks[:, None, :, None]),
-                                 jnp.int8)
-                vp = jnp.asarray(np.round(vf / vs[:, None, :, None]),
-                                 jnp.int8)
-                scales = {"k_scale": jnp.asarray(ks), "v_scale":
-                          jnp.asarray(vs)}
+                kp = pool(np.round(kf / ks[:, None, :, None]), jnp.int8)
+                vp = pool(np.round(vf / vs[:, None, :, None]), jnp.int8)
+                scales = {"k_scale": jnp.asarray(np.stack([0 * ks, ks])),
+                          "v_scale": jnp.asarray(np.stack([0 * vs, vs]))}
             kern = jax.jit(lambda q, kp, vp, **kw: pa.ragged_span_attention(
-                q, kp, vp, table, lengths, q_counts=counts, **kw))
+                q, kp, vp, table, lengths, q_counts=counts, layer=1, **kw))
             check(MOSAIC in kern.lower(q, kp, vp, **scales).as_text(),
                   f"span Sq={sq} {pages}: impl='auto' took the dense path")
             with jax.default_matmul_precision("float32"):
                 want = jax.jit(
                     lambda q, kp, vp, **kw: pa.ragged_span_attention(
                         q, kp, vp, table, lengths, q_counts=counts,
-                        impl="xla", **kw))(q, kp, vp, **scales)
+                        impl="xla", layer=1, **kw))(q, kp, vp, **scales)
             out[f"sq{sq}_{pages}"] = _close(
                 kern(q, kp, vp, **scales), want, 3e-2,
                 f"span Sq={sq} {pages}")

@@ -279,14 +279,15 @@ class GPT2Attention(HybridBlock):
             qd = q._data.transpose(0, 2, 1, 3)
             if not quant:
                 qd = qd.astype(cache.k_pages.dtype)
+            # the whole pools go in and the kernel's BlockSpec picks
+            # the layer: slicing one out here would copy it
             out = ragged_span_attention(
-                qd,
-                cache.k_pages[layer_idx], cache.v_pages[layer_idx],
+                qd, cache.k_pages, cache.v_pages,
                 cache.page_table, cache.length + 1,
                 q_counts=getattr(cache, "spans", None),
                 impl=impl, interpret=interp,
-                k_scale=cache.k_scale[layer_idx] if quant else None,
-                v_scale=cache.v_scale[layer_idx] if quant else None)
+                k_scale=cache.k_scale, v_scale=cache.v_scale,
+                layer=layer_idx)
             b, tq, h, d = out.shape
             out = out.astype(q._data.dtype).reshape(b, tq, h * d)
             out = NDArray(out)

@@ -11,11 +11,16 @@ donation):
 
   * KVCache — contiguous per-layer (B, H, T_max, D) buffers written with
     `lax.dynamic_update_slice`. The fast path for fixed-batch decode.
-  * PagedKVCache — a static PAGE POOL (L, num_pages, page_size, H, D)
+  * PagedKVCache — a static PAGE POOL (L, num_pages, page_size, H*D)
     plus a per-sequence page table (B, pages_per_seq). Attention gathers
     pages through the table, so sequences own arbitrary page sets —
     the serving-style layout (cf. ragged paged attention, PAPERS.md)
-    with O(1) append and no per-length recompilation.
+    with O(1) append and no per-length recompilation. A token's heads
+    are PACKED heads-major into one row (column h*D + d): that is the
+    operand the span kernel reads, so the pool is written and read in
+    place. A pool ending in (H, D) would not be: the TPU's tiled layout
+    pads (20, 64) to (24, 128), so handing the kernel H*D columns is a
+    real copy there, of every layer on every dispatch.
 
     RAGGED mode (the continuous-batching serving path, serving/engine.py):
     `length` may be a (B,) int32 vector — each slot has its own live
@@ -53,11 +58,13 @@ def gather_kv_pages(k_pages, v_pages, idx, k_scale=None, v_scale=None):
     one program regardless of how many pages spill this step: the host
     pads short batches with page 0 and slices the valid prefix off the
     ``jax.device_get`` result. Returns (k, v, ks, vs) with k/v of shape
-    (L, P, page_size, H, D) and ks/vs (L, P, H) f32 (None on float
-    pools). Under tp=N sharded pools the take propagates the pools'
-    head-axis sharding into the slices; ``device_get`` then assembles
-    the global array — no reshard, no explicit sharding annotations
-    (same contract as the engine's _copy_page_fn)."""
+    (L, P, page_size, H*D) — pages leave the device packed as the pool
+    stores them, so no program relayouts one — and ks/vs (L, P, H) f32
+    (None on float pools). Under tp=N sharded pools the take propagates
+    the pools' sharding of the packed axis (whole-head column blocks)
+    into the slices; ``device_get`` then assembles the global array —
+    no reshard, no explicit sharding annotations (same contract as the
+    engine's _copy_page_fn)."""
     k = jnp.take(k_pages, idx, axis=1)
     v = jnp.take(v_pages, idx, axis=1)
     ks = None if k_scale is None else jnp.take(k_scale, idx, axis=1)
@@ -73,7 +80,8 @@ def scatter_kv_pages(k_pages, v_pages, idx, k_val, v_val,
 
     ``idx`` is the same fixed-width (P,) vector, padded with
     ``num_pages`` (out of range) so pad rows DROP instead of landing in
-    page 0. Payload values are written verbatim — int8 codes and their
+    page 0. Payloads are (L, P, page_size, H*D), the pool's own packed
+    rows, and are written verbatim — int8 codes and their
     f32 scale leaves land exactly as gathered, which is what makes a
     page-in bit-identical to the never-evicted run. Returns the
     updated (k_pages, v_pages, k_scale, v_scale); the engine jits this
@@ -143,10 +151,16 @@ class KVCache:
 
 @jax.tree_util.register_pytree_node_class
 class PagedKVCache:
-    """Page-pool cache: k/v pools (L, num_pages, page_size, H, D) indexed
+    """Page-pool cache: k/v pools (L, num_pages, page_size, H*D) indexed
     through a per-sequence page_table (B, pages_per_seq). `length` is a
     scalar (all sequences in lockstep — generate()'s fixed-batch decode)
     or a (B,) vector (ragged serving decode, one live length per slot).
+    The last axis packs a token's heads (column h*D + d): the ONE layout,
+    because it is what ragged_span_attention's page blocks read. Writes
+    scatter rows of that width and the kernel picks its layer and page
+    in its BlockSpec, so no program slices, reshapes or copies a pool.
+    The head count comes back from the (B, H, t, D) operand of each
+    write wherever a method needs (H, D) again.
 
     QUANTIZED page mode (``kv_dtype="int8"``): pools are stored int8
     with per-page-per-head f32 scale leaves ``k_scale``/``v_scale`` of
@@ -166,7 +180,8 @@ class PagedKVCache:
     TENSOR-PARALLEL serving (ServingEngine(tp=N)): every method here is
     already head-count-agnostic, so inside the engine's shard_map the
     SAME code runs on per-shard pool slices — k/v pools sharded on the
-    head axis (axis 3) and int8 scale leaves on theirs (axis 2), while
+    packed axis (axis 3, in whole-head blocks of D columns) and int8
+    scale leaves on their head axis (axis 2), while
     page_table / length / spans / page_lock stay replicated so every
     shard computes identical page geometry. Nothing in this file
     branches on the shard; the split is purely the caller's sharding of
@@ -233,7 +248,7 @@ class PagedKVCache:
             raise MXNetError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
         store = jnp.int8 if kv_dtype is not None else dtype
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
+        shape = (num_layers, num_pages, page_size, num_heads * head_dim)
         length = jnp.zeros((), jnp.int32) if lengths is None \
             else jnp.asarray(lengths, jnp.int32)
         scales = (None, None)
@@ -262,15 +277,16 @@ class PagedKVCache:
     def max_length(self):
         return self.page_table.shape[1] * self.page_size
 
-    def _gather(self, pages, layer, scale=None):
-        # (num_pages, page_size, H, D)[table (B, P)] → (B, T, H, D) → BHTD
+    def _gather(self, pages, layer, heads, scale=None):
+        # (num_pages, page_size, H*D)[table (B, P)] → (B, T, H, D) → BHTD
+        B, P = self.page_table.shape
         g = jnp.take(pages[layer], self.page_table, axis=0)
+        g = g.reshape(B, P, self.page_size, heads, -1)
         if scale is not None:
             # dequant the gathered view: one f32 scale per (page, head)
             gs = jnp.take(scale[layer], self.page_table, axis=0)
             g = g.astype(jnp.float32) * gs[:, :, None, :, None]
-        B, P, S, H, D = g.shape
-        return g.reshape(B, P * S, H, D).transpose(0, 2, 1, 3)
+        return g.reshape(B, self.max_length, heads, -1).transpose(0, 2, 1, 3)
 
     def _quant_encode(self, x_t, pages, page_idx, scale, layer):
         """Quantize an append chunk against the monotone page scales.
@@ -286,7 +302,8 @@ class PagedKVCache:
         chunk, so deep-layer activations depend on chunk boundaries —
         the serving engine replays a request's recorded write schedule
         on restart/migration for exactly that reason.) Returns
-        (q int8 (B,t,H,D), scale_used f32 (B,t,H))."""
+        (q int8 (B,t,H*D) packed as the pool stores it, scale_used f32
+        (B,t,H))."""
         N = self.k_pages.shape[1]
         xf = x_t.astype(jnp.float32)
         live = pages < N                               # (B, t)
@@ -305,23 +322,24 @@ class PagedKVCache:
         s = jnp.maximum(s_old, run * (1.0 / 127.0))
         q = jnp.where(s[..., None] > 0, xf / s[..., None], 0.0)
         q = jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8)
-        return q, s
+        return q.reshape(q.shape[0], t, -1), s
 
     def write(self, layer, k_new, v_new):
         """Decode write: k_new/v_new (B, H, 1, D) appended at `length`.
         Returns full gathered (B, H, T_max, D) views + updated cache.
         Quantized caches route through the write_decode scatter (which
         owns the scale bookkeeping) and return DEQUANTIZED f32 views."""
+        B, H = k_new.shape[:2]
         if self.quantized:
             new = self.write_decode(layer, k_new, v_new)
-            return (new._gather(new.k_pages, layer, new.k_scale),
-                    new._gather(new.v_pages, layer, new.v_scale), new)
+            return (new._gather(new.k_pages, layer, H, new.k_scale),
+                    new._gather(new.v_pages, layer, H, new.v_scale), new)
         page_idx = self.length // self.page_size
         slot = self.length % self.page_size
         pages = self.page_table[:, page_idx]          # (B,) physical page
-        # pool slot layout is (page, slot, H, D) → one (B, H, D) slab
-        k_t = k_new[:, :, 0, :]
-        v_t = v_new[:, :, 0, :]
+        # pool slot layout is (page, slot, H*D) → one (B, H*D) row each
+        k_t = k_new[:, :, 0, :].reshape(B, -1)
+        v_t = v_new[:, :, 0, :].reshape(B, -1)
         kp = self.k_pages.at[layer, pages, slot].set(
             k_t.astype(self.k_pages.dtype))
         vp = self.v_pages.at[layer, pages, slot].set(
@@ -329,7 +347,7 @@ class PagedKVCache:
         new = PagedKVCache(kp, vp, self.page_table, self.length,
                            page_lock=self.page_lock, spans=self.spans,
                            attn_impl=self.attn_impl)
-        return new._gather(kp, layer), new._gather(vp, layer), new
+        return new._gather(kp, layer, H), new._gather(vp, layer, H), new
 
     def write_decode(self, layer, k_new, v_new):
         """Ragged decode write: each slot appends its token(s) at its OWN
@@ -388,10 +406,12 @@ class PagedKVCache:
                                 page_lock=self.page_lock, spans=self.spans,
                                 k_scale=ks, v_scale=vs,
                                 attn_impl=self.attn_impl)
+        # one row of H*D columns per token, heads-major: the pool's own
+        # minor axis, so the scatter lands in place
         kp = self.k_pages.at[layer, pages, slot].set(
-            k_t.astype(self.k_pages.dtype), mode="drop")
+            k_t.reshape(B, t, -1).astype(self.k_pages.dtype), mode="drop")
         vp = self.v_pages.at[layer, pages, slot].set(
-            v_t.astype(self.v_pages.dtype), mode="drop")
+            v_t.reshape(B, t, -1).astype(self.v_pages.dtype), mode="drop")
         return PagedKVCache(kp, vp, self.page_table, self.length,
                             page_lock=self.page_lock, spans=self.spans,
                             attn_impl=self.attn_impl)
@@ -412,8 +432,9 @@ class PagedKVCache:
                              "(scalar length); ragged slots prefill "
                              "individually (serving.ServingEngine)")
         new = self.write_decode(layer, k, v)
-        return (new._gather(new.k_pages, layer, new.k_scale),
-                new._gather(new.v_pages, layer, new.v_scale), new)
+        H = k.shape[1]
+        return (new._gather(new.k_pages, layer, H, new.k_scale),
+                new._gather(new.v_pages, layer, H, new.v_scale), new)
 
     def advance(self, n):
         return PagedKVCache(self.k_pages, self.v_pages, self.page_table,

@@ -413,8 +413,12 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # dead pages (a slot's page extent stretches only to
 # lengths[b] + q_counts[b] - 1; an idle slot visits no page at all).
 #
-# Layout: pages enter packed as (num_pages, S, H*D) (a free minor-dim
-# reshape of the pool's (num_pages, S, H, D)); heads are static 64-aligned
+# Layout: the kernel takes the WHOLE pool as PagedKVCache stores it,
+# (L, num_pages, S, H*D), and picks its layer and page in the page
+# BlockSpec's index, so the operand is the donated pool itself and no
+# program slices or copies it. (Reshaping a pool that ends in (H, D) is
+# not free on the chip: the tiled layout pads (20, 64) to (24, 128), and
+# XLA answers with copies of the whole pool.) Heads are static 64-aligned
 # column slices exactly like the packed training kernels above, so the
 # (8, 128) Mosaic rule holds for every transformer width. The online-
 # softmax accumulators live in VMEM scratch, one row per query position,
@@ -424,9 +428,11 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 def _ragged_unsupported_reason(q, k_pages):
     """Why the ragged Pallas kernel cannot take this call on real TPU
     hardware (None when it can; interpret mode runs any shape).
-    q: (B, H, D) or (B, Sq, H, D)."""
-    H, D = q.shape[-2], q.shape[-1]
-    S = k_pages.shape[1]
+    q: (B, H, D) or (B, Sq, H, D); k_pages: the packed pool
+    (L, num_pages, S, H*D)."""
+    D = q.shape[-1]
+    S, HD = k_pages.shape[2:]
+    H = HD // D
     if (H * D) % 128 or D % 64:
         return (f"heads*head_dim={H}*{D} is not a multiple of 128 lanes "
                 "with 64-aligned head slices")
@@ -587,23 +593,26 @@ def _ragged_span_quant_kernel(table_ref, len_ref, qc_ref, kscale_ref,
 
 
 def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
-                         k_scale=None, v_scale=None):
-    """Dense XLA fallback/oracle for the multi-query kernel: full gather,
+                         k_scale=None, v_scale=None, layer=0):
+    """Dense XLA fallback/oracle for the multi-query kernel: full gather
+    of `layer`'s pages out of the packed pools, unpacked to (H, D) here,
     per-position causal-offset mask — query j of slot b attends key
     positions < lengths[b] + j. int8 pools dequant on the gathered view
     with the per-(page, head) scales — the same math the fused kernel
     epilogue applies in VMEM."""
-    B, Sq = q.shape[0], q.shape[1]
-    g = jnp.take(k_pages, page_table, axis=0)          # (B, P, S, H, D)
-    P, S = g.shape[1], g.shape[2]
-    gv = jnp.take(v_pages, page_table, axis=0)
+    B, Sq, H, D = q.shape
+    P, S = page_table.shape[1], k_pages.shape[2]
+    g = jnp.take(k_pages[layer], page_table,
+                 axis=0).reshape(B, P, S, H, D)
+    gv = jnp.take(v_pages[layer], page_table,
+                  axis=0).reshape(B, P, S, H, D)
     if k_scale is not None:
-        ks = jnp.take(k_scale, page_table, axis=0)     # (B, P, H)
-        vs = jnp.take(v_scale, page_table, axis=0)
+        ks = jnp.take(k_scale[layer], page_table, axis=0)  # (B, P, H)
+        vs = jnp.take(v_scale[layer], page_table, axis=0)
         g = g.astype(jnp.float32) * ks[:, :, None, :, None]
         gv = gv.astype(jnp.float32) * vs[:, :, None, :, None]
-    k = g.reshape(B, P * S, *g.shape[3:])              # (B, T, H, D)
-    v = gv.reshape(B, P * S, *g.shape[3:])
+    k = g.reshape(B, P * S, H, D)
+    v = gv.reshape(B, P * S, H, D)
     s = jnp.einsum("bjhd,bthd->bjht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     limit = lengths[:, None] + jnp.arange(Sq)[None, :]     # (B, Sq)
@@ -619,13 +628,15 @@ def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
 
 
 def _ragged_span_reference(q, k_pages, v_pages, page_table, lengths,
-                           q_counts, scale, k_scale=None, v_scale=None):
+                           q_counts, scale, k_scale=None, v_scale=None,
+                           layer=0):
     """Dense XLA fallback/oracle for the span kernel: the multi-query
     causal-offset math, with query rows >= q_counts[b] dead — they emit
     exact zeros (the row-mask contract the unified dispatch relies on:
     garbage rows of a mixed batch can never leak into live output)."""
     out = _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths,
-                               scale, k_scale=k_scale, v_scale=v_scale)
+                               scale, k_scale=k_scale, v_scale=v_scale,
+                               layer=layer)
     rows = jnp.arange(q.shape[1])[None, :] < q_counts[:, None]  # (B, Sq)
     return jnp.where(rows[:, :, None, None], out,
                      jnp.zeros_like(out))
@@ -633,14 +644,18 @@ def _ragged_span_reference(q, k_pages, v_pages, page_table, lengths,
 
 def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                           q_counts=None, scale=None, impl="auto",
-                          interpret=False, k_scale=None, v_scale=None):
+                          interpret=False, k_scale=None, v_scale=None,
+                          layer=0):
     """Span ragged paged-attention: ONE fixed-shape program for mixed
     prefill-chunk / decode / speculative-verify / idle work.
 
     q:              (B, Sq, H, D) — up to Sq query tokens per slot,
                     already written to the cache at positions
                     lengths-1 .. lengths+q_counts-2.
-    k_pages/v_pages:(num_pages, S, H, D) — ONE layer's page pools.
+    k_pages/v_pages:(L, num_pages, S, H*D) — the WHOLE page pools as
+                    PagedKVCache stores them (heads packed, column
+                    h*D + d); `layer` (a static int) picks the layer,
+                    inside the kernel's page BlockSpec.
     page_table:     (B, P) int32 — physical pages per slot.
     lengths:        (B,) int32 — live tokens through query 0 (its own
                     position included); query j attends key positions
@@ -649,8 +664,9 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     verify=S, prefill chunk=C, idle=0); rows past the
                     count emit exact zeros. None means every row is
                     live (the multi-query/verify case).
-    k_scale/v_scale:(num_pages, H) f32 — per-(page, head) dequant scales
-                    for int8 page pools; both set or both None. The
+    k_scale/v_scale:(L, num_pages, H) f32 — per-(page, head) dequant
+                    scales of int8 page pools, the whole leaves like the
+                    pools; both set or both None. The
                     Pallas path fuses the dequant into the page DMA
                     epilogue; the XLA path dequants the gathered view.
     impl: 'auto' (kernel on TPU; dense XLA elsewhere, or on TPU with a
@@ -659,7 +675,7 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     Returns (B, Sq, H, D) in q's dtype.
     """
     B, Sq, H, D = q.shape
-    N, S = k_pages.shape[0], k_pages.shape[1]
+    S = k_pages.shape[2]
     P = page_table.shape[1]
     s = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
@@ -669,12 +685,11 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     if impl == "xla":
         return _ragged_span_reference(q, k_pages, v_pages, page_table,
                                       lengths, q_counts, s,
-                                      k_scale=k_scale, v_scale=v_scale)
+                                      k_scale=k_scale, v_scale=v_scale,
+                                      layer=layer)
     if impl != "pallas":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
     qp = q.reshape(B, Sq, H * D)
-    kp = k_pages.reshape(N, S, H * D)
-    vp = v_pages.reshape(N, S, H * D)
     lengths = lengths.astype(jnp.int32)
     q_counts = q_counts.astype(jnp.int32)
     table = page_table.astype(jnp.int32)
@@ -689,7 +704,7 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         # Idle slots (q_count 0) pin every step to their first page and
         # the kernel body skips all of them
         last_live = jnp.maximum((lens[b] + qcs[b] - 1 + S - 1) // S - 1, 0)
-        return (tbl[b, jnp.minimum(p, last_live)], 0, 0)
+        return (layer, tbl[b, jnp.minimum(p, last_live)], 0, 0)
 
     def q_index(b, p, tbl, lens, qcs, *_scales):
         return (b, 0, 0)
@@ -699,8 +714,9 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         grid=(B, P),
         in_specs=[
             pl.BlockSpec((1, Sq, H * D), q_index),
-            pl.BlockSpec((1, S, H * D), page_index),
-            pl.BlockSpec((1, S, H * D), page_index),
+            # the layer axis is squeezed: the kernel sees (1, S, H*D)
+            pl.BlockSpec((None, 1, S, H * D), page_index),
+            pl.BlockSpec((None, 1, S, H * D), page_index),
         ],
         out_specs=pl.BlockSpec((1, Sq, H * D), q_index),
         scratch_shapes=[
@@ -713,12 +729,13 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         kernel = functools.partial(_ragged_span_quant_kernel, scale=s,
                                    S=S, Sq=Sq, H=H, D=D)
         operands = (table, lengths, q_counts,
-                    k_scale.astype(jnp.float32),
-                    v_scale.astype(jnp.float32), qp, kp, vp)
+                    k_scale[layer].astype(jnp.float32),
+                    v_scale[layer].astype(jnp.float32), qp,
+                    k_pages, v_pages)
     else:
         kernel = functools.partial(_ragged_span_kernel, scale=s, S=S,
                                    Sq=Sq, H=H, D=D)
-        operands = (table, lengths, q_counts, qp, kp, vp)
+        operands = (table, lengths, q_counts, qp, k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -738,7 +755,8 @@ def _ragged_reference(q, k_pages, v_pages, page_table, lengths, scale):
 
 
 def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                            scale=None, impl="auto", interpret=False):
+                            scale=None, impl="auto", interpret=False,
+                            layer=0):
     """Ragged paged-attention for one decode step: the Sq=1 call of
     ragged_span_attention with every row live.
 
@@ -747,17 +765,20 @@ def ragged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     length 0 yields 0s). Returns (B, H, D) in q's dtype."""
     return ragged_span_attention(q[:, None], k_pages, v_pages, page_table,
                                  lengths, q_counts=None, scale=scale,
-                                 impl=impl, interpret=interpret)[:, 0]
+                                 impl=impl, interpret=interpret,
+                                 layer=layer)[:, 0]
 
 
 def ragged_mq_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                               scale=None, impl="auto", interpret=False):
+                               scale=None, impl="auto", interpret=False,
+                               layer=0):
     """Multi-query ragged paged-attention (every query row live): the
     q_counts=None span kernel. Kept as the verify-path entry point; see
     ragged_span_attention for the full contract."""
     return ragged_span_attention(q, k_pages, v_pages, page_table,
                                  lengths, q_counts=None, scale=scale,
-                                 impl=impl, interpret=interpret)
+                                 impl=impl, interpret=interpret,
+                                 layer=layer)
 
 
 def supported(q, k, mask, layout="BHTD"):

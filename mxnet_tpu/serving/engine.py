@@ -755,7 +755,10 @@ class ServingEngine:
                     f"{afford} pages at {chip_page} B/page/chip — below "
                     f"the {P} pages one full-length slot needs")
             total_pages = min(total_pages, afford)
-        pool_shape = (L, total_pages, page_size, H, Dh)
+        # heads packed into the last axis (column h*Dh + d): the layout
+        # the span kernel reads, so every dispatch works on the donated
+        # pools in place (models/kv_cache.py)
+        pool_shape = (L, total_pages, page_size, H * Dh)
         self._kp = jnp.zeros(pool_shape, store)
         self._vp = jnp.zeros(pool_shape, store)
         if self._quant:
@@ -764,10 +767,11 @@ class ServingEngine:
         else:
             self._ks = self._vs = None
         if self._mesh is not None:
-            # the pools LIVE sharded (global shape above, head axis
-            # split over the mesh): every eager page op — scrub, CoW
-            # copy, scale zeroing — follows the input layout, and the
-            # unified dispatch's donation keeps the shards in place
+            # the pools LIVE sharded (global shape above, the packed
+            # axis split over the mesh in whole-head blocks): every
+            # eager page op — scrub, CoW copy, scale zeroing — follows
+            # the input layout, and the unified dispatch's donation
+            # keeps the shards in place
             kv_sh = named_sharding(self._kv_pspec(), mesh=self._mesh)
             self._kp = jax.device_put(self._kp, kv_sh)
             self._vp = jax.device_put(self._vp, kv_sh)
@@ -925,7 +929,7 @@ class ServingEngine:
                                            donate_argnums=(0, 1))
         else:
             def _copy_page(kp, vp, src, dst):
-                # CoW split: clone one physical page's (L, S, H, D) slab
+                # CoW split: clone one physical page's (L, S, H*D) slab
                 return (kp.at[:, dst].set(kp[:, src]),
                         vp.at[:, dst].set(vp[:, src]))
 
@@ -2472,16 +2476,17 @@ class ServingEngine:
         a paged-in int8 page needs no re-quantization — and no
         _zero_scales pass — to read back exactly."""
         P = self._pages_per_slot
-        L, _, S, H, Dh = self._kp.shape
+        L = self._kp.shape[0]
         for i in range(0, len(items), P):
             blk = items[i:i + P]
             idx = np.full(P, self.page_pool.num_pages, np.int32)
-            kval = np.zeros((L, P, S, H, Dh), self._kp.dtype)
+            # P pages as the pools hold them: (L, P, S, H*D), (L, P, H)
+            kval = np.zeros((L, P) + self._kp.shape[2:], self._kp.dtype)
             vval = np.zeros_like(kval)
             ksv = vsv = None
             if self._quant:
-                ksv = np.zeros((L, P, H), np.float32)
-                vsv = np.zeros((L, P, H), np.float32)
+                ksv = np.zeros((L, P) + self._ks.shape[2:], np.float32)
+                vsv = np.zeros_like(ksv)
             for j, (page, pl) in enumerate(blk):
                 idx[j] = int(page)
                 kval[:, j] = pl["k"]
@@ -2766,9 +2771,9 @@ class ServingEngine:
         if (not pages or length < 1 or length > self.max_length
                 or len(pages) != min(P, -(-length // S))):
             return False
-        L, _, S_, H, Dh = self._kp.shape
+        L, _, S_, HD = self._kp.shape
         k0 = np.asarray(pages[0].get("k"))
-        if k0.shape != (L, S_, H, Dh) \
+        if k0.shape != (L, S_, HD) \
                 or k0.dtype != np.dtype(self._kp.dtype) \
                 or self._quant != ("ks" in pages[0]):
             return False
@@ -3014,12 +3019,13 @@ class ServingEngine:
 
     # -- tensor parallelism ------------------------------------------------
     def _kv_pspec(self):
-        """KV pool layout under tp: (L, pages, page, H, Dh) with the
-        HEAD axis split over the mesh. Page structure is replicated, so
+        """KV pool layout under tp: (L, pages, page, H*Dh) with the
+        packed axis split over the mesh in whole-head blocks of Dh
+        columns (H % tp == 0). Page structure is replicated, so
         the page table, the lock mask, and every host-side lease
         decision are shard-count-independent — prefix sharing, CoW and
         migration never see the mesh."""
-        return PartitionSpec(None, None, None, AXIS_TP, None)
+        return PartitionSpec(None, None, None, AXIS_TP)
 
     def _scale_pspec(self):
         # int8 dequant scales are per-(layer, page, head): they shard

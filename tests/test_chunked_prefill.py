@@ -47,12 +47,15 @@ def _tiny(vocab=97, layers=2, units=32, heads=2, max_len=64, seed=3):
 # span kernel vs the dense oracle
 # ---------------------------------------------------------------------------
 
-def _pool(B=5, H=2, D=16, S=8, P=4, Sq=8, dtype=jnp.float32, seed=0):
+def _pool(B=5, H=2, D=16, S=8, P=4, Sq=8, dtype=jnp.float32, seed=0,
+          layers=1):
+    """Pools packed as PagedKVCache stores them: (layers, N, S, H*D),
+    heads-major in the last axis, every layer other data."""
     rng = np.random.default_rng(seed)
     N = B * P
     q = jnp.asarray(rng.standard_normal((B, Sq, H, D)), dtype)
-    kp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
+    kp = jnp.asarray(rng.standard_normal((layers, N, S, H * D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((layers, N, S, H * D)), dtype)
     table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
     return q, kp, vp, table
 
@@ -116,6 +119,29 @@ def test_span_kernel_bf16_tolerance():
                                    interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("sq", [1, 64])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_span_kernel_reads_its_layer_of_the_packed_pool(sq, layer):
+    """The kernel is handed the whole (L, N, S, H*D) pools and picks
+    `layer` in its page BlockSpec: at Sq=1 (decode) and Sq=64 (a full
+    prefill chunk) it equals the dense reference on that layer, and a
+    one-layer pool cut out by hand gives the same bits."""
+    q, kp, vp, table = _pool(S=64, Sq=sq, seed=5, layers=3)
+    L = jnp.asarray([9, 70, 1, 256 - sq + 1, 130], jnp.int32)
+    qc = jnp.asarray([sq, max(sq // 2, 1), sq, sq, 0], jnp.int32)
+    ref = pa._ragged_span_reference(q, kp, vp, table, L, qc,
+                                    1.0 / np.sqrt(16), layer=layer)
+    out = pa.ragged_span_attention(q, kp, vp, table, L, q_counts=qc,
+                                   impl="pallas", interpret=True,
+                                   layer=layer)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    alone = pa.ragged_span_attention(
+        q, kp[layer:layer + 1], vp[layer:layer + 1], table, L,
+        q_counts=qc, impl="pallas", interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(alone))
 
 
 def test_span_kernel_rows_equal_isolated_chunks():

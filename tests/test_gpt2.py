@@ -161,8 +161,8 @@ def test_paged_decode_write_lands_in_right_page():
     np.testing.assert_allclose(got, [1, 2, 3, 4, 5, 6, 0, 0])
     # 6 tokens span 2 physical pages of size 4
     pool = np.asarray(cache.k_pages)[0]
-    assert (pool[0, :, 0, 0] == [1, 2, 3, 4]).all()
-    assert (pool[1, :2, 0, 0] == [5, 6]).all()
+    assert (pool[0, :, 0] == [1, 2, 3, 4]).all()
+    assert (pool[1, :2, 0] == [5, 6]).all()
 
 
 @pytest.mark.skipif(len(jax.devices()) < 2,

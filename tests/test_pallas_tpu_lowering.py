@@ -22,8 +22,10 @@ from mxnet_tpu.ops.nn import dot_product_attention as dpa
 from mxnet_tpu.serving import Request, ServingEngine
 from mxnet_tpu.telemetry import cost
 
-# GPT-2 774M attention widths, the engine's page size and slot count
+# GPT-2 774M attention widths, the engine's page size and slot count;
+# pools of LAYERS layers packed (LAYERS, pages, PAGE, H*D), read at LAYER
 H, D, PAGE, SLOTS, PAGES_PER_SLOT = 20, 64, 64, 8, 16
+LAYERS, LAYER = 3, 2
 
 
 def _lowers_to_mosaic(fn, *args):
@@ -48,12 +50,12 @@ def _sds(shape, dtype):
 def _span_args(sq, qdtype, page_dtype):
     n = SLOTS * PAGES_PER_SLOT
     args = [_sds((SLOTS, sq, H, D), qdtype),
-            _sds((n, PAGE, H, D), page_dtype),
-            _sds((n, PAGE, H, D), page_dtype),
+            _sds((LAYERS, n, PAGE, H * D), page_dtype),
+            _sds((LAYERS, n, PAGE, H * D), page_dtype),
             _sds((SLOTS, PAGES_PER_SLOT), "int32"),
             _sds((SLOTS,), "int32"), _sds((SLOTS,), "int32")]
     if jnp.dtype(page_dtype) == jnp.int8:
-        args += [_sds((n, H), "float32")] * 2
+        args += [_sds((LAYERS, n, H), "float32")] * 2
     return args
 
 
@@ -64,7 +66,7 @@ def _span_args(sq, qdtype, page_dtype):
 def test_ragged_span_attention_lowers(on_tpu, sq, qdtype, page_dtype):
     def fn(q, kp, vp, table, lens, qc, ks=None, vs=None):
         return pa.ragged_span_attention(q, kp, vp, table, lens, q_counts=qc,
-                                        k_scale=ks, v_scale=vs)
+                                        k_scale=ks, v_scale=vs, layer=LAYER)
 
     assert _lowers_to_mosaic(fn, *_span_args(sq, qdtype, page_dtype)) == 1
 
@@ -82,8 +84,8 @@ def test_auto_on_tpu_warns_before_taking_the_dense_reference(on_tpu):
     'auto' may use the dense reference, but never silently."""
     n = SLOTS * PAGES_PER_SLOT
     args = (_sds((SLOTS, 2, 5, D), "bfloat16"),
-            _sds((n, PAGE, 5, D), "bfloat16"),
-            _sds((n, PAGE, 5, D), "bfloat16"),
+            _sds((LAYERS, n, PAGE, 5 * D), "bfloat16"),
+            _sds((LAYERS, n, PAGE, 5 * D), "bfloat16"),
             _sds((SLOTS, PAGES_PER_SLOT), "int32"), _sds((SLOTS,), "int32"))
     with pytest.warns(UserWarning, match="dense XLA reference.*5\\*64"):
         exp = jax.export.export(jax.jit(pa.ragged_span_attention),

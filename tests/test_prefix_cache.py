@@ -218,7 +218,7 @@ def test_write_decode_drops_write_to_locked_page():
     val = jnp.full((B, H, 1, D), 7.0)
     cache = cache.write_decode(0, val, val)
     pool = np.asarray(cache.k_pages)[0]
-    assert pool[0, 1, 0, 0] == 7.0         # unlocked write landed
+    assert pool[0, 1, 0] == 7.0            # unlocked write landed
     assert (pool[2] == 0).all()            # locked write dropped
 
 

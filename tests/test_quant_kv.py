@@ -80,21 +80,24 @@ def _serve(net, prompts, max_new=8, sampled=False, ids=None, **kw):
 # ---------------------------------------------------------------------------
 
 def _quant_pool(B=5, H=2, D=16, S=8, P=4, Sq=8, qdtype=jnp.float32,
-                seed=0):
+                seed=0, layers=1):
     """int8 page pools with realistic per-(page, head) scales: codes
     are real quantizations of gaussian slabs, so dequantized values
-    exercise the fused epilogue with non-degenerate magnitudes."""
+    exercise the fused epilogue with non-degenerate magnitudes. Packed
+    as PagedKVCache stores them: codes (layers, N, S, H*D) heads-major,
+    scales (layers, N, H)."""
     rng = np.random.default_rng(seed)
     N = B * P
     q = jnp.asarray(rng.standard_normal((B, Sq, H, D)), qdtype)
-    k = rng.standard_normal((N, S, H, D))
-    v = rng.standard_normal((N, S, H, D))
-    ks = np.abs(k).max(axis=(1, 3)) / 127.0            # (N, H)
-    vs = np.abs(v).max(axis=(1, 3)) / 127.0
-    kq = np.clip(np.round(k / ks[:, None, :, None]), -127, 127)
-    vq = np.clip(np.round(v / vs[:, None, :, None]), -127, 127)
+    k = rng.standard_normal((layers, N, S, H, D))
+    v = rng.standard_normal((layers, N, S, H, D))
+    ks = np.abs(k).max(axis=(2, 4)) / 127.0            # (layers, N, H)
+    vs = np.abs(v).max(axis=(2, 4)) / 127.0
+    kq = np.clip(np.round(k / ks[:, :, None, :, None]), -127, 127)
+    vq = np.clip(np.round(v / vs[:, :, None, :, None]), -127, 127)
     table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
-    return (q, jnp.asarray(kq, jnp.int8), jnp.asarray(vq, jnp.int8),
+    return (q, jnp.asarray(kq.reshape(layers, N, S, H * D), jnp.int8),
+            jnp.asarray(vq.reshape(layers, N, S, H * D), jnp.int8),
             table, jnp.asarray(ks, jnp.float32),
             jnp.asarray(vs, jnp.float32))
 
@@ -148,15 +151,37 @@ def test_quant_span_kernel_sq1_matches_mq_reference():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("sq", [1, 64])
+def test_quant_span_kernel_reads_its_layer_of_the_packed_pool(sq):
+    """int8 pools and their scale leaves go in whole, `layer` picks the
+    page blocks (BlockSpec) and the prefetched scales (k_scale[layer]):
+    kernel vs the dense dequant oracle on that layer, Sq=1 and Sq=64."""
+    q, kq, vq, table, ks, vs = _quant_pool(S=64, Sq=sq, seed=3, layers=3)
+    L = jnp.asarray([9, 70, 1, 256 - sq + 1, 130], jnp.int32)
+    qc = jnp.asarray([sq, max(sq // 2, 1), sq, sq, 0], jnp.int32)
+    ref = pa._ragged_span_reference(q, kq, vq, table, L, qc,
+                                    1.0 / np.sqrt(16), k_scale=ks,
+                                    v_scale=vs, layer=1)
+    out = pa.ragged_span_attention(q, kq, vq, table, L, q_counts=qc,
+                                   impl="pallas", interpret=True,
+                                   k_scale=ks, v_scale=vs, layer=1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    other = pa.ragged_span_attention(q, kq, vq, table, L, q_counts=qc,
+                                     impl="pallas", interpret=True,
+                                     k_scale=ks, v_scale=vs, layer=2)
+    assert not np.array_equal(np.asarray(out), np.asarray(other))
+
+
 def test_ragged_supported_int8_min_tile_gate():
     """Real-TPU support gate: int8 page blocks need the (32, 128) min
     tile, so S % 32 pools must fall back to XLA on hardware. The same
     shapes at fp32 (S % 8 only) stay supported."""
     H, D, S = 2, 64, 8
     q = jnp.zeros((3, H, D), jnp.float32)
-    assert pa.ragged_supported(q, jnp.zeros((4, S, H, D), jnp.float32))
-    assert not pa.ragged_supported(q, jnp.zeros((4, S, H, D), jnp.int8))
-    assert pa.ragged_supported(q, jnp.zeros((4, 32, H, D), jnp.int8))
+    assert pa.ragged_supported(q, jnp.zeros((1, 4, S, H * D), jnp.float32))
+    assert not pa.ragged_supported(q, jnp.zeros((1, 4, S, H * D), jnp.int8))
+    assert pa.ragged_supported(q, jnp.zeros((1, 4, 32, H * D), jnp.int8))
 
 
 # ---------------------------------------------------------------------------

@@ -43,13 +43,15 @@ def _greedy_full(net, prompt, n_new):
 # ---------------------------------------------------------------------------
 
 def _pool(B=3, H=2, D=16, S=8, P=4, dtype=jnp.float32, seed=0):
+    """One-layer pools, packed as PagedKVCache stores them:
+    (1, N, S, H*D), heads-major in the last axis."""
     rng = np.random.default_rng(seed)
     N = B * P
     q = jnp.asarray(rng.standard_normal((B, H, D)), dtype)
     kp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
     vp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
     table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
-    return q, kp, vp, table
+    return q, kp.reshape(1, N, S, H * D), vp.reshape(1, N, S, H * D), table
 
 
 @pytest.mark.parametrize("lengths", [[5, 17, 32], [0, 1, 8],
@@ -116,11 +118,11 @@ def test_write_decode_lands_at_per_slot_offsets():
     val = jnp.arange(B, dtype=jnp.float32).reshape(B, 1, 1, 1) + 1.0
     val = jnp.broadcast_to(val, (B, H, 1, D))
     cache = cache.write_decode(0, val, 2 * val)
-    pool = np.asarray(cache.k_pages)[0]       # (num_pages, S, H, D)
+    pool = np.asarray(cache.k_pages)[0]       # (num_pages, S, H*D)
     table = np.asarray(cache.page_table)
     for b, length in enumerate([0, 5, 9]):
         page, slot = divmod(length, S)
-        assert pool[table[b, page], slot, 0, 0] == b + 1.0
+        assert pool[table[b, page], slot, 0] == b + 1.0
     # nothing else was touched
     assert (pool != 0).sum() == B * D
 
@@ -129,7 +131,7 @@ def test_write_decode_full_slot_drops_instead_of_clobbering():
     B, H, D, S = 2, 1, 2, 4
     cache = PagedKVCache.create(1, B, H, 8, D, page_size=S,
                                 lengths=jnp.asarray([8, 3], jnp.int32))
-    live = jnp.ones((1, cache.k_pages.shape[1], S, H, D))
+    live = jnp.ones((1, cache.k_pages.shape[1], S, H * D))
     cache = PagedKVCache(live, live, cache.page_table, cache.length)
     val = jnp.full((B, H, 1, D), 7.0)
     cache = cache.write_decode(0, val, val)
@@ -138,7 +140,7 @@ def test_write_decode_full_slot_drops_instead_of_clobbering():
     # slot 0 is at capacity: every one of ITS pages still holds 1.0
     assert (pool[table[0]] == 1.0).all()
     # slot 1 wrote at position 3
-    assert pool[table[1, 0], 3, 0, 0] == 7.0
+    assert pool[table[1, 0], 3, 0] == 7.0
 
 
 def test_ragged_key_mask_per_slot():
@@ -189,6 +191,70 @@ def test_ragged_decode_logits_match_full_forward(attn_impl):
         full = net(mx.nd.array(ids[None], dtype="int32")).asnumpy()
         np.testing.assert_allclose(got[b], full[0, -1], rtol=2e-4,
                                    atol=2e-5, err_msg=f"slot {b}")
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_unified_dispatch_writes_packed_heads_major_rows(kv_dtype):
+    """After the engine's unified dispatches the pool row of (layer,
+    page, slot) holds that token's K (and V) for ALL heads, packed
+    heads-major (column h*D + d): compared with the (H, D) rows the
+    dense forward writes into a contiguous KVCache. Float pages equal
+    them. An int8 row holds each head's codes: round(x / s) under the
+    scale s that head's page had reached at that token (layer 0, where
+    no quantized attention lies upstream: within one code), and in every
+    layer a head's D codes point where its D values point. A D-major or
+    head-swapped row fails each of these."""
+    net, cfg = _tiny(heads=4)
+    H, D, S = cfg.num_heads, cfg.units // cfg.num_heads, 8
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, cfg.vocab_size, 21).tolist()
+    dense = net.make_cache(1, 64)
+    _, dense = net(mx.nd.array(np.asarray(prompt, np.int32)[None],
+                               dtype="int32"), dense)
+    eng = ServingEngine(net, num_slots=2, max_length=64, page_size=S,
+                        chunk_tokens=8, attn_impl="xla",
+                        kv_dtype=kv_dtype)
+    eng.submit(Request(prompt, 2))
+    while eng._pending_tokens() or not eng._mapped.any():
+        eng.step()
+    slot = int(np.flatnonzero(eng._mapped)[0])
+    table = eng._table_host[slot]
+    assert eng._kp.shape == (cfg.num_layers, 2 * 64 // S, S, H * D)
+    for pool, want in ((eng._kp, dense.k), (eng._vp, dense.v)):
+        pool, want = np.asarray(pool), np.asarray(want)  # (L,1,H,T,D)
+        for layer in range(cfg.num_layers):
+            for t in range(len(prompt)):
+                row = pool[layer, table[t // S], t % S].reshape(H, D)
+                ref = want[layer, 0, :, t, :]
+                if kv_dtype is None:
+                    np.testing.assert_allclose(row, ref, rtol=1e-5,
+                                               atol=1e-6)
+                    continue
+                cos = (row * ref).sum(-1) / (
+                    np.linalg.norm(row, axis=-1)
+                    * np.linalg.norm(ref, axis=-1))
+                assert (cos > 0.9).all(), (layer, t, cos)
+                if layer == 0:
+                    # the monotone scale: the running absmax / 127 over
+                    # this page's tokens up to t
+                    t0 = t - t % S
+                    run = np.abs(want[0, 0, :, t0:t + 1]).max(axis=(1, 2))
+                    code = np.round(ref / (run[:, None] / 127.0))
+                    assert np.abs(row - code).max() <= 1, (t, row, code)
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas_interpret"])
+def test_engine_greedy_stream_equals_full_forward(attn_impl):
+    """The engine's greedy stream over the packed pool, through the dense
+    reference and through the span kernel (interpret mode) with its
+    layer in the BlockSpec, is the full-forward greedy stream."""
+    net, cfg = _tiny()
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (3, 11)]
+    eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
+                        chunk_tokens=8, attn_impl=attn_impl)
+    assert eng.generate(prompts, 4) == [_greedy_full(net, p, 4)
+                                        for p in prompts]
 
 
 @pytest.mark.slow

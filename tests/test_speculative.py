@@ -52,7 +52,8 @@ def _pool(B=3, H=2, D=16, S=8, P=4, Sq=4, dtype=jnp.float32, seed=0):
     kp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
     vp = jnp.asarray(rng.standard_normal((N, S, H, D)), dtype)
     table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
-    return q, kp, vp, table
+    # packed one-layer pools, as PagedKVCache stores them
+    return q, kp.reshape(1, N, S, H * D), vp.reshape(1, N, S, H * D), table
 
 
 @pytest.mark.parametrize("lengths", [[5, 17, 29], [1, 8, 23],
@@ -127,7 +128,7 @@ def test_write_decode_multitoken_lands_at_per_slot_offsets():
     for b, length in enumerate([1, 6]):
         for j in range(t):
             page, slot = divmod(length + j, S)
-            assert pool[table[b, page], slot, 0, 0] == b * t + j + 1.0
+            assert pool[table[b, page], slot, 0] == b * t + j + 1.0
     assert (pool != 0).sum() == B * t * D   # nothing else touched
 
 
@@ -146,12 +147,12 @@ def test_write_decode_multitoken_drops_past_capacity_and_locked():
     pool = np.asarray(cache.k_pages)[0]
     table = np.asarray(cache.page_table)
     # slot 0: position 7 written, 8 and 9 dropped (capacity)
-    assert pool[table[0, 1], 3, 0, 0] == 7.0
+    assert pool[table[0, 1], 3, 0] == 7.0
     assert (pool[table[0]] != 0).sum() == D
     # slot 1: positions 2, 3 aimed at the locked page 0 -> dropped;
     # position 4 lands in page 1
     assert (pool[table[1, 0]] == 0).all()
-    assert pool[table[1, 1], 0, 0, 0] == 7.0
+    assert pool[table[1, 1], 0, 0] == 7.0
 
 
 # ---------------------------------------------------------------------------
