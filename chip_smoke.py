@@ -42,6 +42,7 @@ from mxnet_tpu.models import (BertForMaskedLM, GPT2ForCausalLM,
 from mxnet_tpu.ops import attention as att
 from mxnet_tpu.ops import pallas_attention as pa
 from mxnet_tpu.ops.nn import dot_product_attention as dpa
+from mxnet_tpu.ops.ssm import ssd_chunk_update
 from mxnet_tpu.runtime import enable_compile_cache
 from mxnet_tpu.serving import Request, ServingEngine, ServingFrontend
 
@@ -415,6 +416,72 @@ def _kernel_span():
     return out
 
 
+def _kernel_span_gqa():
+    """ragged_span_attention at Falcon-H1-34B's heads: 20 query heads over
+    4 KV heads of 128, so the kernel stacks 5 query heads a KV head along
+    its rows; Sq=64 and Sq=1, bf16 pages, layer 1 of 2."""
+    rng = np.random.default_rng(SEED + 1)
+    B, Hq, Hkv, D, S, P = 8, 20, 4, 128, 64, 10
+    N = B * P
+    table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
+    kp, vp = (jnp.asarray(np.stack([np.zeros((N, S, Hkv * D), np.float32),
+                                    rng.standard_normal((N, S, Hkv * D))]),
+                          jnp.bfloat16) for _ in range(2))
+    out = {}
+    for sq in (64, 1):
+        q = jnp.asarray(rng.standard_normal((B, sq, Hq, D)), jnp.bfloat16)
+        lengths = jnp.asarray([1, 64, 65, 300, 640 - sq, 17, 512, 400],
+                              jnp.int32)
+        counts = jnp.asarray([sq, sq, max(sq // 2, 1), sq, sq, 0, 1, sq],
+                             jnp.int32)
+        call = lambda impl: jax.jit(
+            lambda q, kp, vp: pa.ragged_span_attention(
+                q, kp, vp, table, lengths, q_counts=counts, layer=1,
+                num_kv_heads=Hkv, impl=impl))
+        check(MOSAIC in call("auto").lower(q, kp, vp).as_text(),
+              f"GQA span Sq={sq}: impl='auto' took the dense path")
+        with jax.default_matmul_precision("float32"):
+            want = call("xla")(q, kp, vp)
+        out[f"sq{sq}"] = _close(call("auto")(q, kp, vp), want, 3e-2,
+                                f"GQA span Sq={sq}")
+    return out
+
+
+def _kernel_ssd():
+    """ssd_chunk_update at Falcon-H1-34B's mixer: 32 heads of 128, state
+    256, 2 groups; 64 rows (a prefill chunk, a decode row, a dead slot, a
+    fresh slot) and 8, against the einsum form; the pool's other layer
+    and the dead slot's state must come back untouched."""
+    rng = np.random.default_rng(SEED + 2)
+    B, H, P, G, N = 8, 32, 128, 2, 256
+    normal = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    A = -jnp.exp(jnp.asarray(0.3 * normal(H)))
+    D = jnp.asarray(1 + 0.1 * normal(H))
+    state = jnp.asarray(normal(2, B, H, P, N))
+    out = {}
+    for w in (64, 8):
+        x = jnp.asarray(normal(B, w, H, P), jnp.bfloat16)
+        Bm, Cm = (jnp.asarray(0.3 * normal(B, w, G, N), jnp.bfloat16)
+                  for _ in range(2))
+        dt = jax.nn.softplus(jnp.asarray(normal(B, w, H)))
+        counts = jnp.asarray([w, 1, 0, w // 2, 1, w, 3, 1], jnp.int32)
+        fresh = jnp.asarray([1, 0, 0, 0, 1, 0, 0, 0], bool)
+        call = lambda impl: jax.jit(lambda x, dt, Bm, Cm, st: ssd_chunk_update(
+            x, dt, A, Bm, Cm, D, st, counts, 1, impl=impl, fresh=fresh))
+        check(MOSAIC in call("auto").lower(x, dt, Bm, Cm, state).as_text(),
+              f"ssd W={w}: impl='auto' took the einsum path")
+        with jax.default_matmul_precision("float32"):
+            want_y, want_s = call("xla")(x, dt, Bm, Cm, state)
+        y, new = call("auto")(x, dt, Bm, Cm, state)
+        out[f"w{w}_y"] = _close(y, want_y, 3e-2, f"ssd W={w} rows")
+        out[f"w{w}_state"] = _close(new, want_s, 3e-2, f"ssd W={w} state")
+        check(bool(jnp.all(new[0] == state[0])),
+              f"ssd W={w}: the layer not asked for changed")
+        check(bool(jnp.all(new[1, 2] == state[1, 2])),
+              f"ssd W={w}: a slot with no live row changed its state")
+    return out
+
+
 def _kernel_flash():
     """flash_attention_data at T=8192: jax's Pallas kernel (not the
     lax.scan) must have produced it, and it must match dense attention."""
@@ -437,6 +504,7 @@ def _kernel_flash():
 
 def phase_kernels():
     return {"fused": _kernel_fused(), "span": _kernel_span(),
+            "span_gqa": _kernel_span_gqa(), "ssd": _kernel_ssd(),
             "flash": _kernel_flash()}
 
 

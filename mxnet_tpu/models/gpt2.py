@@ -410,6 +410,14 @@ class GPT2ForCausalLM(HybridBlock):
             return logits
         return logits, cache
 
+    def state_spec(self):
+        """What a serving slot holds for this model: KV pages of
+        `num_kv_heads` x `head_dim` a layer and no `recurrent` state
+        (models/falcon_h1.py declares some)."""
+        c = self.config
+        return {"num_layers": c.num_layers, "num_kv_heads": c.num_heads,
+                "head_dim": c.units // c.num_heads, "recurrent": {}}
+
     # -- decode -----------------------------------------------------------
     def make_cache(self, batch, max_length, paged=False, page_size=64,
                    dtype=None, page_table=None, lengths=None,

@@ -189,7 +189,7 @@ class PagedKVCache:
 
     def __init__(self, k_pages, v_pages, page_table, length,
                  page_lock=None, spans=None, k_scale=None, v_scale=None,
-                 attn_impl="auto"):
+                 attn_impl="auto", recurrent=None):
         self.k_pages = k_pages
         self.v_pages = v_pages
         self.page_table = page_table
@@ -212,12 +212,22 @@ class PagedKVCache:
         self.k_scale = k_scale
         self.v_scale = v_scale
         self.attn_impl = attn_impl
+        # optional pytree of per-slot state that does not grow with the
+        # sequence (a state-space layer's convolution tail and SSM
+        # state), each leaf a whole pool (L, B, ...) like the pages. The
+        # cache only carries it through a forward: the model reads and
+        # replaces its leaves (`with_recurrent`), the serving engine
+        # donates them with the pages
+        self.recurrent = recurrent
 
     @classmethod
     def create(cls, num_layers, batch, num_heads, max_length, head_dim,
                dtype=jnp.float32, page_size=64, num_pages=None,
                page_table=None, lengths=None, attn_impl="auto",
-               kv_dtype=None):
+               kv_dtype=None, num_kv_heads=None, recurrent=None):
+        """`num_heads` query heads over `num_kv_heads` KV heads (None:
+        as many): the pools hold the KV heads only."""
+        num_heads = int(num_kv_heads or num_heads)
         if max_length % page_size:
             raise MXNetError(
                 f"max_length {max_length} not a multiple of page_size "
@@ -259,7 +269,7 @@ class PagedKVCache:
         return cls(jnp.zeros(shape, store), jnp.zeros(shape, store),
                    jnp.asarray(page_table, jnp.int32), length,
                    k_scale=scales[0], v_scale=scales[1],
-                   attn_impl=attn_impl)
+                   attn_impl=attn_impl, recurrent=recurrent)
 
     @property
     def quantized(self):
@@ -346,7 +356,8 @@ class PagedKVCache:
             v_t.astype(self.v_pages.dtype))
         new = PagedKVCache(kp, vp, self.page_table, self.length,
                            page_lock=self.page_lock, spans=self.spans,
-                           attn_impl=self.attn_impl)
+                           attn_impl=self.attn_impl,
+                            recurrent=self.recurrent)
         return new._gather(kp, layer, H), new._gather(vp, layer, H), new
 
     def write_decode(self, layer, k_new, v_new):
@@ -405,7 +416,8 @@ class PagedKVCache:
             return PagedKVCache(kp, vp, self.page_table, self.length,
                                 page_lock=self.page_lock, spans=self.spans,
                                 k_scale=ks, v_scale=vs,
-                                attn_impl=self.attn_impl)
+                                attn_impl=self.attn_impl,
+                            recurrent=self.recurrent)
         # one row of H*D columns per token, heads-major: the pool's own
         # minor axis, so the scatter lands in place
         kp = self.k_pages.at[layer, pages, slot].set(
@@ -414,7 +426,8 @@ class PagedKVCache:
             v_t.reshape(B, t, -1).astype(self.v_pages.dtype), mode="drop")
         return PagedKVCache(kp, vp, self.page_table, self.length,
                             page_lock=self.page_lock, spans=self.spans,
-                            attn_impl=self.attn_impl)
+                            attn_impl=self.attn_impl,
+                            recurrent=self.recurrent)
 
     def write_prompt(self, layer, k, v):
         """Prefill write of a whole (B, H, T, D) chunk starting at
@@ -440,7 +453,8 @@ class PagedKVCache:
         return PagedKVCache(self.k_pages, self.v_pages, self.page_table,
                             self.length + n, page_lock=self.page_lock,
                             spans=self.spans, k_scale=self.k_scale,
-                            v_scale=self.v_scale, attn_impl=self.attn_impl)
+                            v_scale=self.v_scale, attn_impl=self.attn_impl,
+                            recurrent=self.recurrent)
 
     def key_mask(self, extra=0):
         """Validity over key positions: (T_max,) in lockstep mode,
@@ -450,11 +464,18 @@ class PagedKVCache:
             return pos[None, :] < (self.length + extra)[:, None]
         return pos < (self.length + extra)
 
+    def with_recurrent(self, recurrent):
+        """This cache with its recurrent-state pytree replaced."""
+        new = self.advance(0)
+        new.recurrent = recurrent
+        return new
+
     def tree_flatten(self):
         return (self.k_pages, self.v_pages, self.page_table,
                 self.length, self.page_lock, self.spans,
-                self.k_scale, self.v_scale), self.attn_impl
+                self.k_scale, self.v_scale, self.recurrent), self.attn_impl
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, attn_impl=aux)
+        *rest, recurrent = children
+        return cls(*rest, attn_impl=aux, recurrent=recurrent)

@@ -37,6 +37,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .kernel_paths import note_path
+
 NEG_INF = -1e30
 # Whole-row VMEM budget cap. Verified on v5e: T=1024 forward+backward
 # compiles and runs for both f32 and bf16 (Mosaic reuses the (T, T)
@@ -474,7 +476,9 @@ def _resolve_ragged_impl(impl, interpret, q, k_pages):
 
 def _ragged_span_kernel(table_ref, len_ref, qc_ref, q_ref, k_ref, v_ref,
                         o_ref, m_ref, l_ref, acc_ref, *, scale, S,
-                        Sq, H, D):
+                        Sq, H, D, group=1):
+    """H KV heads, each against a block of Sq rows: `group` query heads
+    stacked, Sq // group positions each (plain multi-head: group 1)."""
     b = pl.program_id(0)
     p = pl.program_id(1)
     length = len_ref[b]
@@ -496,6 +500,10 @@ def _ragged_span_kernel(table_ref, len_ref, qc_ref, q_ref, k_ref, v_ref,
         # row j's causal window is pos < length + j, and rows past the
         # slot's span are fully masked (they emit zeros)
         rows = lax.broadcasted_iota(jnp.int32, (Sq, S), 0)
+        # under grouped KV heads a row's query position is its index
+        # within its own head's stack, built from compares alone
+        for _ in range(1, group):
+            rows = rows - jnp.where(rows >= Sq // group, Sq // group, 0)
         cols = p * S + lax.broadcasted_iota(jnp.int32, (Sq, S), 1)
         valid = (cols < length + rows) & (rows < qn)
         for h in range(H):
@@ -602,15 +610,18 @@ def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
     epilogue applies in VMEM."""
     B, Sq, H, D = q.shape
     P, S = page_table.shape[1], k_pages.shape[2]
+    Hkv = k_pages.shape[3] // D
     g = jnp.take(k_pages[layer], page_table,
-                 axis=0).reshape(B, P, S, H, D)
+                 axis=0).reshape(B, P, S, Hkv, D)
     gv = jnp.take(v_pages[layer], page_table,
-                  axis=0).reshape(B, P, S, H, D)
+                  axis=0).reshape(B, P, S, Hkv, D)
     if k_scale is not None:
         ks = jnp.take(k_scale[layer], page_table, axis=0)  # (B, P, H)
         vs = jnp.take(v_scale[layer], page_table, axis=0)
         g = g.astype(jnp.float32) * ks[:, :, None, :, None]
         gv = gv.astype(jnp.float32) * vs[:, :, None, :, None]
+    if Hkv != H:        # grouped: KV head h serves query heads h*G ..
+        g, gv = (jnp.repeat(a, H // Hkv, axis=3) for a in (g, gv))
     k = g.reshape(B, P * S, H, D)
     v = gv.reshape(B, P * S, H, D)
     s = jnp.einsum("bjhd,bthd->bjht", q.astype(jnp.float32),
@@ -645,17 +656,25 @@ def _ragged_span_reference(q, k_pages, v_pages, page_table, lengths,
 def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                           q_counts=None, scale=None, impl="auto",
                           interpret=False, k_scale=None, v_scale=None,
-                          layer=0):
+                          layer=0, num_kv_heads=None):
     """Span ragged paged-attention: ONE fixed-shape program for mixed
     prefill-chunk / decode / speculative-verify / idle work.
 
     q:              (B, Sq, H, D) — up to Sq query tokens per slot,
                     already written to the cache at positions
                     lengths-1 .. lengths+q_counts-2.
-    k_pages/v_pages:(L, num_pages, S, H*D) — the WHOLE page pools as
+    k_pages/v_pages:(L, num_pages, S, H_kv*D) — the WHOLE page pools as
                     PagedKVCache stores them (heads packed, column
                     h*D + d); `layer` (a static int) picks the layer,
                     inside the kernel's page BlockSpec.
+    num_kv_heads:   H_kv, where fewer KV heads than the H query heads
+                    (None: as many). KV head h serves the G = H / H_kv
+                    query heads h*G .. h*G+G-1; their rows are STACKED
+                    along the kernel's row axis against that head's page
+                    columns (row g*Sq + j of column block h is query head
+                    h*G + g at position j), so one (G*Sq, D) x (D, S)
+                    product a head a page does the work of G. Grid, page
+                    index map and DMAs do not change; float pages only.
     page_table:     (B, P) int32 — physical pages per slot.
     lengths:        (B,) int32 — live tokens through query 0 (its own
                     position included); query j attends key positions
@@ -674,14 +693,21 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     interpret=True runs it on CPU), 'xla'.
     Returns (B, Sq, H, D) in q's dtype.
     """
-    B, Sq, H, D = q.shape
+    B, Sq, Hq, D = q.shape
     S = k_pages.shape[2]
     P = page_table.shape[1]
     s = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     quant = k_scale is not None
+    H = Hq if num_kv_heads is None else int(num_kv_heads)
+    G = Hq // H
+    if Hq % H or k_pages.shape[3] != H * D or (quant and G > 1):
+        raise ValueError(
+            f"{Hq} query heads over {H} KV heads of {D} do not match "
+            f"{k_pages.dtype} pages {k_pages.shape[3]} wide")
     if q_counts is None:
         q_counts = jnp.full((B,), Sq, jnp.int32)
     impl = _resolve_ragged_impl(impl, interpret, q, k_pages)
+    note_path("ragged_span_attention", impl)
     if impl == "xla":
         return _ragged_span_reference(q, k_pages, v_pages, page_table,
                                       lengths, q_counts, s,
@@ -689,7 +715,10 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                                       layer=layer)
     if impl != "pallas":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
-    qp = q.reshape(B, Sq, H * D)
+    Sr = G * Sq
+    qp = q.reshape(B, Sq, H * D) if G == 1 else \
+        q.reshape(B, Sq, H, G, D).transpose(0, 3, 1, 2, 4) \
+        .reshape(B, Sr, H * D)
     lengths = lengths.astype(jnp.int32)
     q_counts = q_counts.astype(jnp.int32)
     table = page_table.astype(jnp.int32)
@@ -713,16 +742,16 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         num_scalar_prefetch=n_scalar,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, Sq, H * D), q_index),
+            pl.BlockSpec((1, Sr, H * D), q_index),
             # the layer axis is squeezed: the kernel sees (1, S, H*D)
             pl.BlockSpec((None, 1, S, H * D), page_index),
             pl.BlockSpec((None, 1, S, H * D), page_index),
         ],
-        out_specs=pl.BlockSpec((1, Sq, H * D), q_index),
+        out_specs=pl.BlockSpec((1, Sr, H * D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((H, Sq, 128), jnp.float32),   # running max
-            pltpu.VMEM((H, Sq, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((H, Sq, D), jnp.float32),     # running numerator
+            pltpu.VMEM((H, Sr, 128), jnp.float32),   # running max
+            pltpu.VMEM((H, Sr, 128), jnp.float32),   # running denominator
+            pltpu.VMEM((H, Sr, D), jnp.float32),     # running numerator
         ],
     )
     if quant:
@@ -734,17 +763,20 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     k_pages, v_pages)
     else:
         kernel = functools.partial(_ragged_span_kernel, scale=s, S=S,
-                                   Sq=Sq, H=H, D=D)
+                                   Sq=Sr, H=H, D=D, group=G)
         operands = (table, lengths, q_counts, qp, k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sr, H * D), q.dtype),
         interpret=interpret,
         compiler_params=_compiler_params(
             interpret, dimension_semantics=("parallel", "arbitrary")),
     )(*operands)
-    return out.reshape(B, Sq, H, D)
+    if G == 1:
+        return out.reshape(B, Sq, H, D)
+    return out.reshape(B, G, Sq, H, D).transpose(0, 2, 3, 1, 4) \
+        .reshape(B, Sq, Hq, D)
 
 
 def _ragged_reference(q, k_pages, v_pages, page_table, lengths, scale):

@@ -87,6 +87,7 @@ from ..telemetry import cost as _cost
 from ..telemetry import ledger as _ledger
 from ..base import MXNetError
 from ..gluon.block import _trace_channel
+from ..ops.kernel_paths import PATHS as _KERNEL_PATHS
 from ..models.kv_cache import (PagedKVCache, gather_kv_pages,
                                scatter_kv_pages)
 from ..ndarray.ndarray import NDArray
@@ -315,6 +316,16 @@ def _engine_metrics(eid):
             "preempted requests that fell back to the replay/restart "
             "path (swap payload or prefix nodes gone) — output still "
             "bit-identical, compute is not saved", _E),
+        "recurrent_state_bytes": g(
+            "serving_recurrent_state_bytes",
+            "device bytes of the slots' recurrent state (fixed-size "
+            "per-slot leaves the model declares beside its KV pages; 0 "
+            "for a model with pages only)", _E),
+        "state_resets": c(
+            "serving_state_resets_total",
+            "slots that began from zero recurrent state: admissions and "
+            "re-prefills with no context, which the program zeroes from "
+            "the slot's length (no host-side clearing)", _E),
         "kv_spill_seconds": h(
             "serving_kv_spill_seconds",
             "wall time of one spill batch (device page gather + host "
@@ -409,6 +420,18 @@ def _tick_phase_family():
         "self time of the serving.<phase> spans of step(): host seconds "
         "of the scheduling tick by phase, summing to the wall of "
         "serving.step", ("engine", "phase"))
+
+
+def _kernel_path_family():
+    """Which implementation each kernel call of a unified program took
+    when it was traced: 'pallas' (the Mosaic kernel) or 'xla' (the dense
+    or einsum form). On a TPU anything under 'xla' is a kernel that fell
+    off its path."""
+    return telemetry.counter(
+        "serving_kernel_path_total",
+        "kernel calls of this engine's unified programs by the "
+        "implementation they were traced with", ("engine", "kernel",
+                                                 "path"))
 
 
 class _TickSpan(span):
@@ -549,6 +572,34 @@ class ServingEngine:
         self._tp = int(tp or 1)
         if self._tp < 1:
             raise MXNetError(f"tp must be >= 1, got {tp}")
+        # what a slot holds is the model's to declare (state_spec()):
+        # KV pages of so many KV heads, and `recurrent` leaves of fixed
+        # size a slot (a state-space layer's convolution tail and SSM
+        # state). No model is known here by name.
+        slot_state = model.state_spec() if hasattr(model, "state_spec") else {
+            "num_layers": cfg.num_layers, "num_kv_heads": cfg.num_heads,
+            "head_dim": cfg.units // cfg.num_heads, "recurrent": {}}
+        if slot_state["recurrent"]:
+            # recurrent state is not paged and cannot be shared, copied
+            # page by page, split over heads or rebuilt from pages: what
+            # leases, copies or ships pages knows nothing of it yet
+            # (docs/SERVING.md "Recurrent state")
+            for feature, on in (
+                    ("prefix_cache", prefix_cache),
+                    ("speculative", speculative),
+                    ("host_kv_bytes", host_kv_bytes is not None),
+                    ("tp", self._tp > 1),
+                    ("kv_dtype", kv_dtype is not None),
+                    ("weight_dtype", weight_dtype is not None),
+                    ("adapter_pool", adapter_pool is not None)):
+                if on:
+                    raise MXNetError(
+                        f"{feature} is not supported for a model that "
+                        "declares recurrent state "
+                        f"({sorted(slot_state['recurrent'])}): a slot's state "
+                        "beside its KV pages cannot be shared by prefix, "
+                        "verified and rolled back, spilled, sharded, "
+                        "quantized or adapted yet")
         if self._tp > 1:
             if cfg.num_heads % self._tp:
                 raise MXNetError(
@@ -722,19 +773,29 @@ class ServingEngine:
         self._quant = kv_dtype is not None
         self.kv_dtype = "int8" if self._quant else str(jnp.dtype(dt))
         store = jnp.dtype(jnp.int8) if self._quant else jnp.dtype(dt)
-        L, H = cfg.num_layers, cfg.num_heads
-        Dh = cfg.units // cfg.num_heads
+        # the pools hold the KV heads: fewer than the query heads
+        # under grouped-query attention
+        L, H, Dh = (slot_state["num_layers"], slot_state["num_kv_heads"],
+                    slot_state["head_dim"])
         page_bytes = 2 * L * page_size * H * Dh * store.itemsize
         if self._quant:
             page_bytes += 2 * L * H * 4    # f32 scales ride each page
         self._hbm_budget = None if hbm_budget_bytes is None \
             else int(hbm_budget_bytes)
         self._hbm_includes_weights = bool(hbm_budget_includes_weights)
+        # recurrent state: one whole leaf (L, slots, ...) per declared
+        # name, resident like the pages and donated with them
+        rec_shapes = {
+            name: ((L, B) + tuple(shape), jnp.dtype(rdt))
+            for name, (shape, rdt) in slot_state["recurrent"].items()}
+        self._rec_bytes = sum(int(np.prod(shape)) * rdt.itemsize
+                              for shape, rdt in rec_shapes.values())
         if self._hbm_budget is not None:
             # under tp each CHIP holds 1/tp of every page (the head
             # axis shards), so the budget — the quantity that actually
-            # OOMs — is per chip and buys tp x the pages
-            page_budget = self._hbm_budget
+            # OOMs — is per chip and buys tp x the pages. The slots'
+            # recurrent state comes out of it first.
+            page_budget = self._hbm_budget - self._rec_bytes
             if self._hbm_includes_weights:
                 # the served weight slab comes out of the same per-chip
                 # HBM the pages do: charging it here is what turns the
@@ -766,6 +827,8 @@ class ServingEngine:
             self._vs = jnp.zeros((L, total_pages, H), jnp.float32)
         else:
             self._ks = self._vs = None
+        self._rec = {name: jnp.zeros(shape, rdt)
+                     for name, (shape, rdt) in rec_shapes.items()}
         if self._mesh is not None:
             # the pools LIVE sharded (global shape above, the packed
             # axis split over the mesh in whole-head blocks): every
@@ -959,6 +1022,8 @@ class ServingEngine:
         fam = _tick_phase_family()
         self._tick_children = {ph: fam.labels(self._eid, ph)
                                for ph in TICK_PHASES}
+        self._path_children = {}   # (kernel, path) -> labeled child
+        self._paths_before = None  # PATHS when a program was last built
         self._shed = _shed_family()
         self._shed_children = {}   # (reason, priority) -> labeled child
         self._shed_counts = {}     # same keys, host-side for stats
@@ -1087,6 +1152,11 @@ class ServingEngine:
             "preempt_restarted": int(m["preempt_restarted"].value),
             "tick_phase_seconds": {ph: c.value for ph, c
                                    in self._tick_children.items()},
+            "recurrent_state_bytes": self._rec_bytes,
+            "state_resets": int(m["state_resets"].value),
+            "kernel_paths": {f"{kernel}/{path}": int(c.value)
+                             for (kernel, path), c
+                             in self._path_children.items()},
         }
 
     def tenant_stats(self):
@@ -1108,6 +1178,7 @@ class ServingEngine:
         self._metrics["kv_bytes_per_token"].set(pb / self.page_size)
         self._metrics["tp_shards"].set(self._tp)
         self._metrics["weight_quant_enabled"].set(int(self._w8))
+        self._metrics["recurrent_state_bytes"].set(self._rec_bytes)
         for wd, nb in self._weight_bytes.items():
             self._wbytes_fam.labels(self._eid, wd).set(nb)
 
@@ -1460,6 +1531,8 @@ class ServingEngine:
             "kv_pages": kv,
             "slot_state": list(self._dstate) + [self._d_lock],
         }
+        if self._rec:
+            out["recurrent_state"] = list(self._rec.values())
         if self._w8:
             shadow = sum(
                 int(p.data()._data.size
@@ -1760,6 +1833,13 @@ class ServingEngine:
         self._metrics["queue_depth"].set(self.scheduler.num_queued)
         return request
 
+    def _refuse_recurrent(self, feature):
+        if self._rec:
+            raise MXNetError(
+                f"{feature} is not supported for a model that declares "
+                f"recurrent state ({sorted(self._rec)}): a request's "
+                "state beside its KV pages is not exported")
+
     @loop_only
     def export_requests(self):
         """Remove and return EVERY queued and in-flight request
@@ -1769,6 +1849,7 @@ class ServingEngine:
         best-effort — the caller may be abandoning a wedged replica,
         whose device state no longer matters; host-side lease
         accounting is always rolled back."""
+        self._refuse_recurrent("export_requests")
         out = list(self.scheduler.queued_requests())
         for q in self.scheduler._queues:
             q.clear()
@@ -1825,6 +1906,7 @@ class ServingEngine:
         already terminal, never admitted, or still mid-prefill (its
         un-fed chunk queue is host state the payload format does not
         carry — the caller retries after the final chunk lands)."""
+        self._refuse_recurrent("export_handoff")
         slot = None
         for s in self.scheduler.active_slots:
             if self.scheduler.request_at(s).id == request_id:
@@ -2764,6 +2846,8 @@ class ServingEngine:
         payload cannot land here (geometry/dtype mismatch, page-pool
         pressure): the caller falls back to the replay restart, which
         reaches the same tokens by recomputing."""
+        if self._rec:
+            return False    # a payload carries pages, not recurrent state
         kvp = req.kv_payload
         pages = kvp.get("pages") or []
         length = int(kvp.get("length", -1))
@@ -2991,6 +3075,9 @@ class ServingEngine:
                 req.kv_history = []
                 req.kv_attach = int(offset)
                 self._replay[slot] = None
+        if self._rec and not offset:
+            # no context: the program reads zeros for this slot's state
+            m["state_resets"].inc()
         self._base[slot] = base
         self._lengths[slot] = offset
         self._cur_tok[slot] = 0
@@ -3092,6 +3179,40 @@ class ServingEngine:
         return placed
 
     # -- unified dispatch --------------------------------------------------
+    def _device_state(self):
+        """Everything a dispatch updates in place, as the ONE pytree the
+        unified program takes and donates: the page pools, the int8 scale
+        pools where pages are quantized, the recurrent state where the
+        model declares some."""
+        state = {"k": self._kp, "v": self._vp}
+        if self._quant:
+            state.update(ks=self._ks, vs=self._vs)
+        if self._rec:
+            state["rec"] = self._rec
+        return state
+
+    def _take_device_state(self, state):
+        self._kp, self._vp = state["k"], state["v"]
+        if self._quant:
+            self._ks, self._vs = state["ks"], state["vs"]
+        if self._rec:
+            self._rec = state["rec"]
+
+    def _count_kernel_paths(self, before):
+        """serving_kernel_path_total: which implementation each kernel
+        call of a program took when the program was traced (its first
+        call), as ops/kernel_paths.PATHS counted them since
+        `before`."""
+        for (kernel, path), n in _KERNEL_PATHS.items():
+            n -= before.get((kernel, path), 0)
+            if n:
+                child = self._path_children.get((kernel, path))
+                if child is None:
+                    child = self._path_children[(kernel, path)] = \
+                        _kernel_path_family().labels(self._eid, kernel,
+                                                     path)
+                child.inc(n)
+
     def _unified_fn(self):
         """The unified program for this dispatch: greedy-only (no
         sort/RNG in-program) when no active slot samples, the general
@@ -3113,6 +3234,7 @@ class ServingEngine:
             fn = self._wrap_program(self._build_unified(greedy_only),
                                     name)
             self._programs[greedy_only] = fn
+            self._paths_before = dict(_KERNEL_PATHS)
         return fn
 
     def _build_unified(self, greedy_only=False):
@@ -3128,6 +3250,7 @@ class ServingEngine:
         spec = self.speculative
         S = self.spec_tokens
         quant = self._quant
+        recurrent = bool(self._rec)
         tp = self._tp
         # w8: positions whose param_arrays entry is an int8 code array;
         # the per-out-tile dequant scales arrive as the operands right
@@ -3138,14 +3261,12 @@ class ServingEngine:
         # before FullyConnected runs
         w8_idx = tuple(q.index for q in self._w8_plan)
 
-        def unified(param_arrays, kp, vp, table, lock, lengths, cur_tok,
+        def unified(param_arrays, state, table, lock, lengths, cur_tok,
                     done, remaining, counters, seeds, temp, top_k,
                     top_p, do_sample, eos, toks_in, chunk_len, is_final,
                     decode_mask, *rest):
             if spec:
                 drafts, n_draft, *rest = rest
-            if quant:
-                ks, vs, *rest = rest
             wscales = ()
             if w8_idx:
                 wscales = tuple(rest[:len(w8_idx)])
@@ -3179,15 +3300,11 @@ class ServingEngine:
                 else:
                     qn = jnp.where(prefilling, chunk_len,
                                    jnp.where(active, 1, 0))
-                if quant:
-                    cache = PagedKVCache(kp, vp, table, lengths,
-                                         page_lock=lock, spans=qn,
-                                         k_scale=ks, v_scale=vs,
-                                         attn_impl=impl)
-                else:
-                    cache = PagedKVCache(kp, vp, table, lengths,
-                                         page_lock=lock, spans=qn,
-                                         attn_impl=impl)
+                cache = PagedKVCache(
+                    state["k"], state["v"], table, lengths,
+                    page_lock=lock, spans=qn, k_scale=state.get("ks"),
+                    v_scale=state.get("vs"), attn_impl=impl,
+                    recurrent=state.get("rec"))
                 logits, cache = model.forward(NDArray(toks_in), cache)
                 lg = logits._data
                 pos = jnp.arange(W)[None, :]
@@ -3274,18 +3391,18 @@ class ServingEngine:
                 _trace_channel.pop_frame()
                 for p, d in zip(params, saved):
                     p._data = d
-            out = (cache.k_pages, cache.v_pages, new_len, new_cur,
-                   new_done, new_rem, new_cnt, ok, toks, n_em,
-                   n_acc_em)
+            new_state = {"k": cache.k_pages, "v": cache.v_pages}
             if quant:
-                out = out + (cache.k_scale, cache.v_scale)
-            return out
+                new_state.update(ks=cache.k_scale, vs=cache.v_scale)
+            if recurrent:
+                new_state["rec"] = cache.recurrent
+            return (new_state, new_len, new_cur, new_done, new_rem,
+                    new_cnt, ok, toks, n_em, n_acc_em)
 
-        # the scale pools are state like kp/vp: donated through every
-        # dispatch (positions 20/21, or 22/23 after the spec operands)
-        donate = (1, 2)
-        if quant:
-            donate += (22, 23) if spec else (20, 21)
+        # everything a dispatch updates in place is ONE pytree, donated
+        # whole: the page pools, the int8 scale pools, the recurrent
+        # state (_device_state)
+        donate = (1,)
         if tp == 1:
             return jax.jit(unified, donate_argnums=donate)
         # tp > 1: the SAME body runs shard_map'ed over the {tp: N}
@@ -3298,13 +3415,15 @@ class ServingEngine:
         # replication check (check_rep off: psum breaks jax's
         # conservative replication inference).
         kv, rep = self._kv_pspec(), PartitionSpec()
-        # positions 3..19: table, lock, the 11 slot scalars, toks_in,
+        state_spec = {"k": kv, "v": kv}
+        if quant:
+            state_spec.update(ks=self._scale_pspec(),
+                              vs=self._scale_pspec())
+        # positions 2..18: table, lock, the 11 slot scalars, toks_in,
         # chunk_len, is_final, decode_mask — all replicated
-        in_specs = [tuple(self._param_specs), kv, kv] + [rep] * 17
+        in_specs = [tuple(self._param_specs), state_spec] + [rep] * 17
         if spec:
             in_specs += [rep, rep]            # drafts, n_draft
-        if quant:
-            in_specs += [self._scale_pspec()] * 2
         # w8 dequant scales: column-parallel scales shard with the out
         # dim they describe, row-parallel scales are replicated (see
         # serving/weight_quant.py for why row scales are shard-
@@ -3319,9 +3438,7 @@ class ServingEngine:
                          rep]                  # scale
             if self.adapter_pool.quantized:
                 in_specs += [rep, rep]         # a_scale, b_scale
-        out_specs = [kv, kv] + [rep] * 9
-        if quant:
-            out_specs += [self._scale_pspec()] * 2
+        out_specs = [state_spec] + [rep] * 9
         fn = shard_map_compat(unified, mesh=self._mesh,
                               in_specs=tuple(in_specs),
                               out_specs=tuple(out_specs),
@@ -3402,8 +3519,6 @@ class ServingEngine:
             tail, table = st[11:-1], st[-1]   # (aslot,) with the pool on
             extra = (jnp.asarray(drafts), jnp.asarray(n_draft)) \
                 if spec else ()
-            if self._quant:
-                extra = extra + (self._ks, self._vs)
             if self._w8:
                 extra = extra + self._w8_scale_ops
         t0 = self._clock()
@@ -3412,19 +3527,20 @@ class ServingEngine:
                              drafted=int(n_draft.sum())):
             with self._tick_span("dispatch.launch"):
                 out = fn(
-                    param_datas, self._kp, self._vp, table, self._d_lock,
+                    param_datas, self._device_state(), table, self._d_lock,
                     lengths, cur_tok, done, remaining, counters, seeds,
                     temp, top_k, top_p, do_sample, eos,
                     jnp.asarray(toks_in), jnp.asarray(chunk_len),
                     jnp.asarray(is_final), jnp.asarray(decode_mask),
                     *extra, *self._adapter_args(tail))
-            if self._quant:
-                (self._kp, self._vp, lengths, cur_tok, done, remaining,
-                 counters, okc, toks, n_em, n_acc,
-                 self._ks, self._vs) = out
-            else:
-                (self._kp, self._vp, lengths, cur_tok, done, remaining,
-                 counters, okc, toks, n_em, n_acc) = out
+                if self._paths_before is not None:
+                    # the program was built for this dispatch and this
+                    # call traced it
+                    self._count_kernel_paths(self._paths_before)
+                    self._paths_before = None
+            (state, lengths, cur_tok, done, remaining, counters, okc,
+             toks, n_em, n_acc) = out
+            self._take_device_state(state)
             self._dstate = (lengths, cur_tok, done, remaining, counters,
                             seeds, temp, top_k, top_p, do_sample,
                             eos) + tail + (table,)

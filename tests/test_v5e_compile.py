@@ -98,3 +98,74 @@ def test_write_then_attend_works_on_the_pool_in_place(one_chip, page_dtype):
     pools = 2 * LAYER_ELEMS * LAYERS * jnp.dtype(page_dtype).itemsize
     scales = 2 * LAYERS * PAGES * H * 4 if quant else 0
     assert mem.alias_size_in_bytes == pools + scales
+
+
+# falcon_h1_34b.chat_backlog: the whole unified greedy program of
+# ServingEngine at the cell's own engine block and model kwargs
+# (benchmarks/configs/falcon_h1_34b.json), weights described, not made
+def _described(shape, dtype):
+    """An NDArray that holds a shape and a type and no values."""
+    from mxnet_tpu.ndarray.ndarray import NDArray
+    arr = NDArray.__new__(NDArray)
+    arr._data = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+    arr._node = arr._grad = None
+    arr._grad_req, arr._version = "null", 0
+    return arr
+
+
+def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
+    """One donated pytree holds both page pools and both recurrent-state
+    pools, all aliased to the outputs; the program holds no copy or slice
+    as large as a layer of either; and it leaves the 1 GB spare that
+    `assumed.num_slots` of the cell's configuration asks for. Prints the
+    arguments and temporaries that text cites."""
+    import json
+    from mxnet_tpu import models
+    from mxnet_tpu.serving import ServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "falcon_h1_34b.json")) as f:
+        cfg = json.load(f)
+    kw, ekw = cfg["model"]["kwargs"], cfg["engine"]
+    net = models.FalconH1ForCausalLM(models.falcon_h1_34b_config(**kw))
+    for p in net.collect_params().values():
+        p._data = _described(p.shape, kw["dtype"])
+    eng = ServingEngine(net, attn_impl="pallas", **ekw)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=one_chip)
+    slots, width = ekw["num_slots"], ekw["chunk_tokens"]
+    row = lambda dtype: jax.ShapeDtypeStruct((slots,), jnp.dtype(dtype),
+                                             sharding=one_chip)
+    state = eng._device_state()
+    assert sorted(state) == ["k", "rec", "v"]
+    compiled = eng._build_unified(greedy_only=True).lower(
+        tuple(sds(p.data()._data) for p in eng._params),
+        jax.tree_util.tree_map(sds, state), sds(eng._dstate[-1]),
+        sds(eng._d_lock), *[sds(a) for a in eng._dstate[:11]],
+        jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip),
+        row("int32"), row("bool"), row("bool")).compile()
+    hlo = compiled.as_text()
+    layers = kw["num_layers"]
+    assert hlo.count("tpu_custom_call") == 2 * layers    # span + ssd each
+    leaves = jax.tree_util.tree_leaves(state)
+    # a pool, or one layer of one (an activation can be as large as a
+    # layer of pages: told apart by the dimensions themselves)
+    # (the convolution tails, 1 MB a layer, are read and written whole)
+    large = [a for a in leaves if a.nbytes // layers > 8 << 20]
+    pools = {a.shape for a in large} | {a.shape[1:] for a in large}
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice|transpose)\(", hlo)
+        if tuple(int(d) for d in m.group(1).split(",")
+                 if d != "1") in pools]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(a.nbytes for a in leaves)
+    assert eng.stats["recurrent_state_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(state["rec"]))
+    print(f"falcon_h1_34b unified greedy program, {slots} slots, {layers} "
+          f"layers: {mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB aliased")
+    # the allocator's limit on the chip is 16.91e9 bytes (PERF.md)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
+        < 16.91e9
