@@ -37,7 +37,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel_paths import note_path
+from .kernel_paths import note_path, note_tile
 
 NEG_INF = -1e30
 # Whole-row VMEM budget cap. Verified on v5e: T=1024 forward+backward
@@ -399,12 +399,44 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # cache view from HBM every decoded token — at GPT-2 774M serving shapes
 # that is max_length/live_length times more HBM traffic than the tokens
 # actually alive. The kernel follows the ragged paged attention design
-# (arxiv 2604.15464): grid (slots, pages-per-slot), the page table and
-# per-slot lengths ride in scalar-prefetch SMEM so the BlockSpec index_map
-# DMAs exactly the pages the slot owns, and pages past the live extent
-# re-map to the slot's last live page — Pallas elides the DMA when
-# consecutive grid steps ask for the same block, so HBM traffic scales
-# with the LIVE length, not max_length.
+# (arxiv 2604.15464): the page table and per-slot lengths ride in
+# scalar-prefetch SMEM so the BlockSpec index_map DMAs exactly the pages
+# the slot owns, and HBM traffic scales with the LIVE length, not
+# max_length.
+#
+# Grid (slots, ceil(P / KB)): a step consumes a BLOCK of KB consecutive
+# logical pages of its slot, KB*S keys. The block arrives as KB page
+# operands of the same pool, operand i of step p holding logical page
+# p*KB + i through _span_block_table, the scalar-prefetch table of
+# physical pages a step an operand; each operand is pipelined (double-
+# buffered) on its own. At a step where operand i has no live page (past
+# the slot's extent, a slot shorter than the block, an idle slot) the
+# table repeats the page the operand held the step before, slots
+# included: Pallas elides the DMA of a repeated block index, so each live
+# page is fetched once and no other. P that KB does not divide needs no
+# padding of the page table: the block table is as wide as the grid and
+# the position mask below drops the keys.
+#
+# For each KV head the step runs ONCE over the block: (Sr, D) x (D, KB*S)
+# scores in float32, one mask, one row max / exp / row sum over
+# (Sr, KB*S), (Sr, KB*S) x (KB*S, D) with the probabilities cast to the
+# page dtype, one rescale of the (Sr, D) accumulator. The block's keys of
+# a head are the KB pages' column slices set one under another
+# (sublane-aligned, so the concatenation moves no lane); int8 pages are
+# widened and multiplied by their own (page, head) scale first, read at
+# the block table's page, so HBM traffic stays one byte an element.
+#
+# The heads are walked in passes of _SPAN_PASS_HEADS heads whose columns
+# are whole 128-lane tiles (_span_passes), a pass ONE dynamic
+# tile-aligned lane slice of every operand in a ROLLED loop: the body is
+# traced and lowered for one pass, not H times (36 layers x 20 heads
+# unrolled were most of the 42 s the GPT-2 program took to trace on the
+# chip's host: PERF.md, PR 28), and a pass still holds independent heads
+# for the scheduler to interleave.
+#
+# KB is chosen by _span_block_pages from what the call's shapes show (the
+# page size, the stacked rows, P): never from a model's name, never from an
+# argument. kernel_paths.TILES records the block each call was built with.
 #
 # Each of the B slots in a (B, Sq, H, D) dispatch consumes q_counts[b]
 # query tokens: a decode slot 1, a speculative verify S, a prefill chunk
@@ -412,8 +444,9 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # lengths[b]-1+j, so it may attend key positions < lengths[b]+j — the
 # per-position CAUSAL OFFSET — and rows >= q_counts[b] are dead: they
 # accumulate nothing and emit exact zeros. The grid skips dead rows AND
-# dead pages (a slot's page extent stretches only to
-# lengths[b] + q_counts[b] - 1; an idle slot visits no page at all).
+# dead blocks (a slot's page extent stretches only to
+# lengths[b] + q_counts[b] - 1; an idle slot visits no block at all);
+# inside the last live block, keys past the extent are masked by position.
 #
 # Layout: the kernel takes the WHOLE pool as PagedKVCache stores it,
 # (L, num_pages, S, H*D), and picks its layer and page in the page
@@ -424,7 +457,7 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # column slices exactly like the packed training kernels above, so the
 # (8, 128) Mosaic rule holds for every transformer width. The online-
 # softmax accumulators live in VMEM scratch, one row per query position,
-# and persist across the sequential minor page-grid dimension.
+# and persist across the sequential minor block-grid dimension.
 # ---------------------------------------------------------------------------
 
 def _ragged_unsupported_reason(q, k_pages):
@@ -474,19 +507,110 @@ def _resolve_ragged_impl(impl, interpret, q, k_pages):
     return "xla"
 
 
-def _ragged_span_kernel(table_ref, len_ref, qc_ref, q_ref, k_ref, v_ref,
-                        o_ref, m_ref, l_ref, acc_ref, *, scale, S,
-                        Sq, H, D, group=1):
+# What bounds the span kernel's block of keys, measured on a v5e (PR 28,
+# PERF.md section 6): a grid step costs about 7 us at GPT-2's 20 heads
+# whatever its keys and 5 ns a key on top, so the block is as wide as the
+# slot has pages, up to the widest measured; at 320 stacked rows a head
+# 256 keys (320 KB of float32 scores a head) beat both 128 and 512
+_SPAN_MAX_KEYS = 1024
+_SPAN_SCORE_BYTES = 512 * 1024
+# heads a pass of the rolled head loop: the scheduler interleaves their
+# independent chains (10 a pass were 4% faster at GPT-2's widths and cost
+# twice the lowering; 2 a pass were 23% slower at Falcon-H1's)
+_SPAN_PASS_HEADS = 4
+
+
+def _span_block_pages(S, Sr, P):
+    """KB, the pages one grid step of the span kernel attends: as many
+    as keep KB*S within _SPAN_MAX_KEYS and the (Sr, KB*S) float32 scores
+    of a head within _SPAN_SCORE_BYTES; a power of two, so that equal
+    shapes give equal blocks; at least one page and at most the P a slot
+    has. All static at trace time."""
+    keys = min(_SPAN_SCORE_BYTES // (4 * Sr), _SPAN_MAX_KEYS)
+    kb = 1
+    while 2 * kb * S <= keys and 2 * kb <= P:
+        kb *= 2
+    return kb
+
+
+def _span_live_pages(lengths, q_counts, S):
+    """Pages a slot's queries reach: the furthest live query (row
+    q_count - 1) sits at position length + q_count - 2; an idle slot
+    (q_count 0) owns none at all — the ceil formula alone would still
+    give it ceil((length - 1) / S)."""
+    return jnp.where(q_counts == 0, 0,
+                     (lengths + q_counts - 1 + S - 1) // S)
+
+
+def _span_block_table(page_table, lengths, q_counts, S, KB):
+    """(B, ceil(P / KB) * KB) int32: at [b, p*KB + i] the physical page
+    that operand i of the span kernel holds at grid step (b, p). That is
+    logical page p*KB + i of slot b while it is live (the extent
+    stretched to cover the slot's furthest live query, nothing for an
+    idle slot); at every other step the page the operand held at the
+    step BEFORE, in the grid's own order, slots included: the block
+    index repeats, the pipeline skips the DMA, and the kernel fetches
+    each live page once and no other (but what the operands hold before
+    their first live page: the first slot's pages). Computed in XLA, a
+    few small integer operations, identical in every layer of a
+    program; the index_maps and the int8 kernel's scale lookup only
+    read it, so a scale always belongs to the page the DMA fetched."""
+    B, P = page_table.shape
+    steps = B * -(-P // KB)
+    n_live = _span_live_pages(lengths, q_counts, S)
+    j = jnp.arange(steps // B * KB)
+    live = (j[None, :] < n_live[:, None]).reshape(steps, KB)
+    phys = page_table[:, jnp.minimum(j, P - 1)].reshape(steps, KB)
+    held = lax.cummax(jnp.where(live, jnp.arange(steps)[:, None], 0), axis=0)
+    return jnp.take_along_axis(phys, held, axis=0).reshape(B, -1)
+
+
+def _span_passes(H, D):
+    """(heads, rolled): how the span kernel walks its H packed heads. A
+    pass takes `heads` of them, up to _SPAN_PASS_HEADS, whose columns are
+    whole 128-lane tiles (four heads of 64, of 128), so that a pass is
+    ONE tile-aligned lane slice of every operand, dynamic in a ROLLED
+    loop over the passes: the body is traced and lowered for one pass,
+    not H times. One pass that holds every head runs inline; widths that
+    give no such pass (the interpreted tests' small heads) run a head a
+    pass inline."""
+    for heads in range(min(_SPAN_PASS_HEADS, H), 0, -1):
+        if H % heads == 0 and (heads * D) % 128 == 0:
+            return heads, heads < H
+    return 1, False
+
+
+def _ragged_span_kernel(pages_ref, len_ref, qc_ref, *refs, scale, S, Sq, H,
+                        D, KB, group=1, quant=False):
     """H KV heads, each against a block of Sq rows: `group` query heads
-    stacked, Sq // group positions each (plain multi-head: group 1)."""
+    stacked, Sq // group positions each (plain multi-head: group 1), and
+    KB pages of keys a step. pages_ref: _span_block_table's. refs:
+    [k_scale, v_scale] (int8 pages: the per-(page, head) float32 scales,
+    in SMEM beside it), q, KB pages of K, KB pages of V, out, and the
+    running max, denominator and numerator."""
+    kscale_ref = vscale_ref = None
+    if quant:
+        kscale_ref, vscale_ref, *refs = refs
+    q_ref, (o_ref, m_ref, l_ref, acc_ref) = refs[0], refs[1 + 2 * KB:]
+    k_refs, v_refs = refs[1:1 + KB], refs[1 + KB:1 + 2 * KB]
+    heads, rolled = _span_passes(H, D)
+    lanes = heads * D
+
+    def each_pass(body):
+        """body(first head, columns) for every pass over the heads."""
+        if rolled:
+            lax.fori_loop(0, H // heads, lambda j, _: body(
+                j * heads, pl.ds(pl.multiple_of(j * lanes, lanes), lanes)),
+                None)
+        else:
+            for j in range(H // heads):
+                body(j * heads, pl.ds(j * lanes, lanes))
+
     b = pl.program_id(0)
     p = pl.program_id(1)
     length = len_ref[b]
     qn = qc_ref[b]
-    # the furthest live query (row qn-1) reaches position length + qn - 2;
-    # an idle slot (qn == 0) owns no pages at all — the ceil formula alone
-    # would still visit ceil((length-1)/S) of them
-    n_live = jnp.where(qn == 0, 0, (length + qn - 1 + S - 1) // S)
+    n_live = _span_live_pages(length, qn, S)
 
     @pl.when(p == 0)
     def _init():
@@ -494,110 +618,80 @@ def _ragged_span_kernel(table_ref, len_ref, qc_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(p < n_live)
+    @pl.when(p * KB < n_live)
     def _accumulate():
-        # rows are query positions, columns token positions in this page;
-        # row j's causal window is pos < length + j, and rows past the
-        # slot's span are fully masked (they emit zeros)
-        rows = lax.broadcasted_iota(jnp.int32, (Sq, S), 0)
+        # rows are query positions, columns token positions in this
+        # block; row j's causal window is pos < length + j, and rows past
+        # the slot's span are fully masked (they emit zeros). A page of
+        # the block past the live extent holds another page's keys: its
+        # positions are >= length + qn - 1, so the same mask drops them
+        rows = lax.broadcasted_iota(jnp.int32, (Sq, KB * S), 0)
         # under grouped KV heads a row's query position is its index
         # within its own head's stack, built from compares alone
         for _ in range(1, group):
             rows = rows - jnp.where(rows >= Sq // group, Sq // group, 0)
-        cols = p * S + lax.broadcasted_iota(jnp.int32, (Sq, S), 1)
+        cols = p * (KB * S) \
+            + lax.broadcasted_iota(jnp.int32, (Sq, KB * S), 1)
         valid = (cols < length + rows) & (rows < qn)
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            q = q_ref[0, :, c0:c1]                     # (Sq, D)
-            k = k_ref[0, :, c0:c1]                     # (S, D)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)           # (Sq, S)
-            m_prev = m_ref[h][:, :1]                   # (Sq, 1)
-            l_prev = l_ref[h][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-            alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
-                              jnp.exp(m_prev - m_new))
-            v = v_ref[0, :, c0:c1]                     # (S, D)
-            pv = lax.dot_general(e.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            acc_ref[h] = acc_ref[h] * alpha + pv
-            l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref[h].shape)
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref[h].shape)
+        if quant:
+            phys = [pages_ref[b, p * KB + i] for i in range(KB)]
+            head_of_lane = lax.broadcasted_iota(
+                jnp.int32, (1, lanes), 1) // D
+
+        def block(page_refs, scale_ref, h0, columns):
+            """A pass's (KB*S, lanes) keys or values of this block: the
+            KB pages' column slices one under another (sublane-aligned:
+            no lane moves), int8 pages widened and multiplied each by
+            its own (page, head) scales first."""
+            parts = [r[0, :, columns] for r in page_refs]
+            if quant:
+                for i, x in enumerate(parts):
+                    sc = scale_ref[phys[i], h0]
+                    for t in range(1, heads):
+                        sc = jnp.where(head_of_lane < t, sc,
+                                       scale_ref[phys[i], h0 + t])
+                    parts[i] = x.astype(jnp.float32) * sc
+            return parts[0] if KB == 1 else jnp.concatenate(parts, axis=0)
+
+        def attend(h0, columns):
+            qs = q_ref[0, :, columns]                      # (Sq, lanes)
+            ks = block(k_refs, kscale_ref, h0, columns)    # (KB*S, lanes)
+            vs = block(v_refs, vscale_ref, h0, columns)
+            for t in range(heads):
+                h, c0, c1 = h0 + t, t * D, (t + 1) * D
+                s = lax.dot_general(qs[:, c0:c1], ks[:, c0:c1],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+                s = jnp.where(valid, s * scale, NEG_INF)   # (Sq, KB*S)
+                m_prev = m_ref[h][:, :1]                   # (Sq, 1)
+                l_prev = l_ref[h][:, :1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+                alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
+                                  jnp.exp(m_prev - m_new))
+                v = vs[:, c0:c1]
+                pv = lax.dot_general(e.astype(v.dtype), v,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+                acc_ref[h] = acc_ref[h] * alpha + pv
+                l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
+                l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+        each_pass(attend)
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _emit():
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            o_ref[0, :, c0:c1] = (
-                acc_ref[h]
-                / jnp.maximum(l_ref[h][:, :1], 1e-30)).astype(o_ref.dtype)
+        def emit(h0, columns):
+            outs = [acc_ref[h0 + t]
+                    / jnp.maximum(l_ref[h0 + t][:, :1], 1e-30)
+                    for t in range(heads)]
+            o_ref[0, :, columns] = (
+                outs[0] if heads == 1
+                else jnp.concatenate(outs, axis=1)).astype(o_ref.dtype)
 
-
-def _ragged_span_quant_kernel(table_ref, len_ref, qc_ref, kscale_ref,
-                              vscale_ref, q_ref, k_ref, v_ref, o_ref,
-                              m_ref, l_ref, acc_ref, *, scale, S, Sq,
-                              H, D):
-    """Span kernel over int8 pages with a fused dequant epilogue on the
-    page DMA: the per-(page, head) f32 scales ride the scalar-prefetch
-    lane next to the page table, the kernel recomputes this grid step's
-    physical page (the same remap page_index uses, so the looked-up
-    scale always matches the block the DMA fetched) and widens the int8
-    page block in VMEM — HBM traffic stays one byte per element."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    length = len_ref[b]
-    qn = qc_ref[b]
-    n_live = jnp.where(qn == 0, 0, (length + qn - 1 + S - 1) // S)
-    # mirror of page_index's DMA-eliding remap: which physical page is
-    # actually sitting in k_ref/v_ref right now
-    last_live = jnp.maximum((length + qn - 1 + S - 1) // S - 1, 0)
-    page_phys = table_ref[b, jnp.minimum(p, last_live)]
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(p < n_live)
-    def _accumulate():
-        rows = lax.broadcasted_iota(jnp.int32, (Sq, S), 0)
-        cols = p * S + lax.broadcasted_iota(jnp.int32, (Sq, S), 1)
-        valid = (cols < length + rows) & (rows < qn)
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            q = q_ref[0, :, c0:c1]                     # (Sq, D)
-            k = k_ref[0, :, c0:c1].astype(jnp.float32) \
-                * kscale_ref[page_phys, h]             # (S, D) dequant
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)           # (Sq, S)
-            m_prev = m_ref[h][:, :1]                   # (Sq, 1)
-            l_prev = l_ref[h][:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-            alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
-                              jnp.exp(m_prev - m_new))
-            v = v_ref[0, :, c0:c1].astype(jnp.float32) \
-                * vscale_ref[page_phys, h]             # (S, D) dequant
-            pv = lax.dot_general(e, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            acc_ref[h] = acc_ref[h] * alpha + pv
-            l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref[h].shape)
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref[h].shape)
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _emit():
-        for h in range(H):
-            c0, c1 = h * D, (h + 1) * D
-            o_ref[0, :, c0:c1] = (
-                acc_ref[h]
-                / jnp.maximum(l_ref[h][:, :1], 1e-30)).astype(o_ref.dtype)
+        each_pass(emit)
 
 
 def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
@@ -672,9 +766,9 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     query heads h*G .. h*G+G-1; their rows are STACKED
                     along the kernel's row axis against that head's page
                     columns (row g*Sq + j of column block h is query head
-                    h*G + g at position j), so one (G*Sq, D) x (D, S)
-                    product a head a page does the work of G. Grid, page
-                    index map and DMAs do not change; float pages only.
+                    h*G + g at position j), so one (G*Sq, D) x (D, KB*S)
+                    product a head a block of pages does the work of G.
+                    Grid, page index maps and DMAs do not change.
     page_table:     (B, P) int32 — physical pages per slot.
     lengths:        (B,) int32 — live tokens through query 0 (its own
                     position included); query j attends key positions
@@ -700,7 +794,7 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     quant = k_scale is not None
     H = Hq if num_kv_heads is None else int(num_kv_heads)
     G = Hq // H
-    if Hq % H or k_pages.shape[3] != H * D or (quant and G > 1):
+    if Hq % H or k_pages.shape[3] != H * D:
         raise ValueError(
             f"{Hq} query heads over {H} KV heads of {D} do not match "
             f"{k_pages.dtype} pages {k_pages.shape[3]} wide")
@@ -716,37 +810,35 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     if impl != "pallas":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
     Sr = G * Sq
+    KB = _span_block_pages(S, Sr, P)
+    note_tile("ragged_span_attention", pages=KB, keys=KB * S, rows=Sr)
     qp = q.reshape(B, Sq, H * D) if G == 1 else \
         q.reshape(B, Sq, H, G, D).transpose(0, 3, 1, 2, 4) \
         .reshape(B, Sr, H * D)
     lengths = lengths.astype(jnp.int32)
     q_counts = q_counts.astype(jnp.int32)
-    table = page_table.astype(jnp.int32)
-    # the scalar-prefetch index_map signature grows with every prefetch
-    # operand; the float path keeps its 3-operand spec byte-identical
-    n_scalar = 5 if quant else 3
+    pages = _span_block_table(page_table.astype(jnp.int32), lengths,
+                              q_counts, S, KB)
 
-    def page_index(b, p, tbl, lens, qcs, *_scales):
-        # pages past the live extent (stretched to cover the slot's
-        # furthest live query) re-map to the last live page: the block
-        # index repeats, so the pipeline skips the DMA (ragged traffic).
-        # Idle slots (q_count 0) pin every step to their first page and
-        # the kernel body skips all of them
-        last_live = jnp.maximum((lens[b] + qcs[b] - 1 + S - 1) // S - 1, 0)
-        return (layer, tbl[b, jnp.minimum(p, last_live)], 0, 0)
+    # the index_maps take the grid position and then every scalar-prefetch
+    # operand: the block table, the lengths, the counts and, over int8
+    # pages, the two scale leaves
+    def page_index(i):
+        return lambda b, p, pages, *_: (layer, pages[b, p * KB + i], 0, 0)
 
-    def q_index(b, p, tbl, lens, qcs, *_scales):
+    def q_index(b, p, *_prefetched):
         return (b, 0, 0)
 
+    # the layer axis is squeezed: the kernel sees KB pages (1, S, H*D) of
+    # each pool, every one an operand of its own over the SAME pool
+    page_specs = [pl.BlockSpec((None, 1, S, H * D), page_index(i))
+                  for i in range(KB)]
+    scales = (k_scale[layer].astype(jnp.float32),
+              v_scale[layer].astype(jnp.float32)) if quant else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_scalar,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, Sr, H * D), q_index),
-            # the layer axis is squeezed: the kernel sees (1, S, H*D)
-            pl.BlockSpec((None, 1, S, H * D), page_index),
-            pl.BlockSpec((None, 1, S, H * D), page_index),
-        ],
+        num_scalar_prefetch=3 + len(scales),
+        grid=(B, pl.cdiv(P, KB)),
+        in_specs=[pl.BlockSpec((1, Sr, H * D), q_index)] + 2 * page_specs,
         out_specs=pl.BlockSpec((1, Sr, H * D), q_index),
         scratch_shapes=[
             pltpu.VMEM((H, Sr, 128), jnp.float32),   # running max
@@ -754,17 +846,10 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
             pltpu.VMEM((H, Sr, D), jnp.float32),     # running numerator
         ],
     )
-    if quant:
-        kernel = functools.partial(_ragged_span_quant_kernel, scale=s,
-                                   S=S, Sq=Sq, H=H, D=D)
-        operands = (table, lengths, q_counts,
-                    k_scale[layer].astype(jnp.float32),
-                    v_scale[layer].astype(jnp.float32), qp,
-                    k_pages, v_pages)
-    else:
-        kernel = functools.partial(_ragged_span_kernel, scale=s, S=S,
-                                   Sq=Sr, H=H, D=D, group=G)
-        operands = (table, lengths, q_counts, qp, k_pages, v_pages)
+    kernel = functools.partial(_ragged_span_kernel, scale=s, S=S, Sq=Sr,
+                               H=H, D=D, KB=KB, group=G, quant=quant)
+    operands = (pages, lengths, q_counts, *scales, qp,
+                *([k_pages] * KB), *([v_pages] * KB))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -87,7 +87,8 @@ from ..telemetry import cost as _cost
 from ..telemetry import ledger as _ledger
 from ..base import MXNetError
 from ..gluon.block import _trace_channel
-from ..ops.kernel_paths import PATHS as _KERNEL_PATHS
+from ..ops.kernel_paths import PATHS as _KERNEL_PATHS, \
+    TILES as _KERNEL_TILES
 from ..models.kv_cache import (PagedKVCache, gather_kv_pages,
                                scatter_kv_pages)
 from ..ndarray.ndarray import NDArray
@@ -432,6 +433,17 @@ def _kernel_path_family():
         "kernel calls of this engine's unified programs by the "
         "implementation they were traced with", ("engine", "kernel",
                                                  "path"))
+
+
+def _kernel_tile_family():
+    """The block a Mosaic kernel call of a unified program was BUILT with,
+    where the kernel chooses one from the call's shapes (the span kernel:
+    pages and keys a grid step, rows a head): `tile` is the sizes as
+    `name=value` pairs."""
+    return telemetry.counter(
+        "serving_kernel_tile_total",
+        "Mosaic kernel calls of this engine's unified programs by the "
+        "block sizes they were built with", ("engine", "kernel", "tile"))
 
 
 class _TickSpan(span):
@@ -1023,7 +1035,9 @@ class ServingEngine:
         self._tick_children = {ph: fam.labels(self._eid, ph)
                                for ph in TICK_PHASES}
         self._path_children = {}   # (kernel, path) -> labeled child
-        self._paths_before = None  # PATHS when a program was last built
+        self._tile_children = {}   # (kernel, tile) -> labeled child
+        # (PATHS, TILES) when a program was last built
+        self._paths_before = None
         self._shed = _shed_family()
         self._shed_children = {}   # (reason, priority) -> labeled child
         self._shed_counts = {}     # same keys, host-side for stats
@@ -1157,6 +1171,9 @@ class ServingEngine:
             "kernel_paths": {f"{kernel}/{path}": int(c.value)
                              for (kernel, path), c
                              in self._path_children.items()},
+            "kernel_tiles": {f"{kernel}/{tile}": int(c.value)
+                             for (kernel, tile), c
+                             in self._tile_children.items()},
         }
 
     def tenant_stats(self):
@@ -3199,19 +3216,24 @@ class ServingEngine:
             self._rec = state["rec"]
 
     def _count_kernel_paths(self, before):
-        """serving_kernel_path_total: which implementation each kernel
-        call of a program took when the program was traced (its first
-        call), as ops/kernel_paths.PATHS counted them since
-        `before`."""
-        for (kernel, path), n in _KERNEL_PATHS.items():
-            n -= before.get((kernel, path), 0)
-            if n:
-                child = self._path_children.get((kernel, path))
-                if child is None:
-                    child = self._path_children[(kernel, path)] = \
-                        _kernel_path_family().labels(self._eid, kernel,
-                                                     path)
-                child.inc(n)
+        """serving_kernel_path_total and serving_kernel_tile_total: which
+        implementation each kernel call of a program took, and which
+        block it was built with, when the program was traced (its first
+        call), as ops/kernel_paths PATHS and TILES counted them since
+        `before`, the copies of both taken when the program was built."""
+        for registry, seen, children, family in (
+                (_KERNEL_PATHS, before[0], self._path_children,
+                 _kernel_path_family),
+                (_KERNEL_TILES, before[1], self._tile_children,
+                 _kernel_tile_family)):
+            for key, n in registry.items():
+                n -= seen.get(key, 0)
+                if n:
+                    child = children.get(key)
+                    if child is None:
+                        child = children[key] = family().labels(
+                            self._eid, *key)
+                    child.inc(n)
 
     def _unified_fn(self):
         """The unified program for this dispatch: greedy-only (no
@@ -3234,7 +3256,8 @@ class ServingEngine:
             fn = self._wrap_program(self._build_unified(greedy_only),
                                     name)
             self._programs[greedy_only] = fn
-            self._paths_before = dict(_KERNEL_PATHS)
+            self._paths_before = (dict(_KERNEL_PATHS),
+                                  dict(_KERNEL_TILES))
         return fn
 
     def _build_unified(self, greedy_only=False):

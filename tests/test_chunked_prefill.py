@@ -167,6 +167,169 @@ def test_span_kernel_rows_equal_isolated_chunks():
 
 
 # ---------------------------------------------------------------------------
+# the block of pages a grid step attends: its edges
+# ---------------------------------------------------------------------------
+# S=8, P=7 (a prime: no block larger than a page divides it), Sq=8. W is
+# the block's keys; (lengths, q_counts) are given in units the case names.
+
+def _edge_cases(W):
+    return {
+        # the furthest live key is the last of a block, the first of the
+        # next, and the one before the block's last
+        "context_ends_one_before_a_block_boundary": ([W - 1], [1]),
+        "context_ends_on_a_block_boundary": ([W], [1]),
+        "context_ends_one_after_a_block_boundary": ([W + 1], [1]),
+        "span_crosses_a_block_boundary": ([W - 3], [8]),
+        "span_ends_on_the_last_key_of_the_table": ([7 * 8 - 7], [8]),
+        "idle_slot_between_two_busy_ones": ([W + 5, W + 5, W + 5],
+                                            [8, 0, 8]),
+        "counts_0_1_and_Sq_in_one_call": ([2 * W - 1, 3, W + 2], [0, 1, 8]),
+        "one_page_slots_shorter_than_a_block": ([1, 8, 2], [1, 1, 7]),
+    }
+
+
+def _int8_pool(kp, vp, H=2):
+    """(codes, scales) of float pools (L, N, S, H*D), a scale a page a
+    head, as PagedKVCache quantizes."""
+    out = []
+    for x in (kp, vp):
+        L, N, S, HD = x.shape
+        x = np.asarray(x).reshape(L, N, S, H, HD // H)
+        sc = np.abs(x).max(axis=(2, 4)) / 127.0            # (L, N, H)
+        codes = np.round(x / sc[:, :, None, :, None]).reshape(L, N, S, HD)
+        out += [jnp.asarray(codes, jnp.int8), jnp.asarray(sc, jnp.float32)]
+    return out
+
+
+@pytest.mark.parametrize("pages", ["float32", "int8"])
+@pytest.mark.parametrize("kb", [None, 2, 3])
+@pytest.mark.parametrize("case", sorted(_edge_cases(0)))
+def test_span_kernel_block_edges(monkeypatch, case, kb, pages):
+    """Every edge of the key block against the dense reference, dead rows
+    EXACT zeros: at the block the adaptive rule picks here (4 pages of
+    the 7) and at 2 and 3 pages a step, over float and int8 pages, the
+    page table a permutation so that no block is contiguous in the pool."""
+    if kb is not None:
+        monkeypatch.setattr(pa, "_span_block_pages", lambda S, Sr, P: kb)
+    W = 8 * (kb or pa._span_block_pages(8, 8, 7))
+    assert W == 8 * (kb or 4)
+    lens, counts = _edge_cases(W)[case]
+    q, kp, vp, table = _pool(B=len(lens), P=7, seed=11)
+    assert (np.diff(np.asarray(table), axis=1) != 1).any()
+    kw = {}
+    if pages == "int8":
+        kp, ks, vp, vs = _int8_pool(kp, vp)
+        kw = dict(k_scale=ks, v_scale=vs)
+    L, qc = jnp.asarray(lens, jnp.int32), jnp.asarray(counts, jnp.int32)
+    ref = pa._ragged_span_reference(q, kp, vp, table, L, qc,
+                                    1.0 / np.sqrt(16), **kw)
+    out = pa.ragged_span_attention(q, kp, vp, table, L, q_counts=qc,
+                                   impl="pallas", interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    dead = np.arange(8)[None, :] >= np.asarray(counts)[:, None]
+    assert (np.asarray(out)[dead] == 0).all()
+    live = ~dead & (np.asarray(lens) > 0)[:, None]
+    assert np.abs(np.asarray(out)[live]).min() > 0
+
+
+def test_span_kernel_fetches_each_live_page_once_and_no_other():
+    """The block table, walked as the pipeline walks the grid (an operand
+    starts a DMA when its block index differs from the step before):
+    every live page of every slot is fetched exactly once, by the
+    operand of its place in the block; a slot shorter than a block, an idle slot
+    and the steps past a slot's extent fetch NOTHING (the operand keeps
+    the page it held, across slots too); and every index stays inside a
+    table that KB does not divide."""
+    S, KB, P = 8, 4, 7
+    table = np.arange(5 * P, dtype=np.int32).reshape(5, P)[::-1]
+    lens = np.asarray([P * S - 7, 4 * S + 1, 2, 30, 3 * S], np.int32)
+    counts = np.asarray([8, 1, 1, 0, 2], np.int32)
+    n_live = [7, 5, 1, 0, 4]
+    pages = np.asarray(pa._span_block_table(
+        jnp.asarray(table), jnp.asarray(lens), jnp.asarray(counts), S, KB))
+    assert pages.shape == (5, 2 * KB)
+    assert set(pages.ravel()) <= set(table.ravel())
+    steps = pages.reshape(-1, KB)               # grid order, (b, p) flat
+    fetched = [int(pg) for i in range(KB) for n, pg in enumerate(steps[:, i])
+               if n == 0 or pg != steps[n - 1, i]]
+    live = [int(pg) for b in range(5) for pg in table[b, :n_live[b]]]
+    assert sorted(fetched) == sorted(live)      # slot 0 is live from step 0
+    for b in range(5):                          # and where it belongs
+        for j in range(n_live[b]):
+            assert pages[b, j] == table[b, j]
+
+
+def test_span_kernel_records_the_block_it_was_built_with():
+    from mxnet_tpu.ops import kernel_paths
+    key = ("ragged_span_attention", "pages=4,keys=32,rows=8")
+    before = kernel_paths.TILES.get(key, 0)
+    q, kp, vp, table = _pool(P=7)
+    L = jnp.asarray([9, 17, 1, 30, 12], jnp.int32)
+    pa.ragged_span_attention(q, kp, vp, table, L, interpret=True)
+    assert kernel_paths.TILES[key] == before + 1
+    n = sum(kernel_paths.TILES.values())
+    pa.ragged_span_attention(q, kp, vp, table, L, impl="xla")
+    assert sum(kernel_paths.TILES.values()) == n      # no kernel, no tile
+
+
+@pytest.mark.parametrize("S,Sr,P,want", [
+    (64, 64, 16, 16),     # gpt2_774m.doc_backlog: a slot's 1024 keys a step
+    (64, 320, 10, 4),     # falcon_h1_34b.chat_backlog: 5 heads x 64 rows
+    (64, 1, 16, 16),      # a decode-only call: the key limit binds
+    (64, 64, 2, 2),       # never more than the slot's pages
+    (64, 64, 64, 16),     # nor more than 1024 keys
+    (64, 1024, 16, 2),    # rows so tall that two pages fill the scores
+    (256, 64, 8, 4),      # wide pages
+    (64, 64, 10, 8),      # a power of two under a P that is none
+    (8, 8, 7, 4),         # the tests' own shapes
+])
+def test_span_block_rule(S, Sr, P, want):
+    """The adaptive rule as ops/pallas_attention.py documents it: up to
+    1024 keys and 512 KiB of float32 scores a head, a power of two, at
+    most the slot's pages."""
+    assert pa._span_block_pages(S, Sr, P) == want
+
+
+@pytest.mark.parametrize("H,D,want", [
+    (20, 64, (4, True)),      # GPT-2 774M: five passes of 256 lanes
+    (12, 64, (4, True)),
+    (4, 128, (4, False)),     # Falcon-H1's KV heads: one pass, inline
+    (8, 128, (4, True)),
+    (6, 64, (2, True)),       # 4 does not divide 6: two heads, 128 lanes
+    (2, 16, (1, False)),      # these tests' heads: a head at a time
+    (3, 64, (1, False)),      # 192 columns: no whole tile of heads
+])
+def test_span_passes(H, D, want):
+    assert pa._span_passes(H, D) == want
+
+
+@pytest.mark.parametrize("pages", ["float32", "int8"])
+@pytest.mark.parametrize("H,D", [(8, 64), (6, 64), (4, 128), (3, 64)])
+def test_span_kernel_head_loop_rolled_and_unrolled(H, D, pages):
+    """The head loop as the chip runs it: rolled over passes of four or
+    two 64-wide heads (a dynamic lane slice of every operand, the int8
+    scales picked by lane), one inline pass of four 128-wide heads, and a
+    head at a time where 3 x 64 columns give no whole tile; blocks of 4
+    of 6 pages, mixed work, against the dense reference."""
+    q, kp, vp, table = _pool(B=4, H=H, D=D, P=6, seed=13)
+    kw = {}
+    if pages == "int8":
+        kp, ks, vp, vs = _int8_pool(kp, vp, H)
+        kw = dict(k_scale=ks, v_scale=vs)
+    L = jnp.asarray([31, 33, 7, 41], jnp.int32)
+    qc = jnp.asarray([8, 1, 0, 5], jnp.int32)
+    ref = pa._ragged_span_reference(q, kp, vp, table, L, qc,
+                                    1.0 / np.sqrt(D), **kw)
+    out = pa.ragged_span_attention(q, kp, vp, table, L, q_counts=qc,
+                                   impl="pallas", interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=3e-5, atol=3e-5)
+    dead = np.arange(8)[None, :] >= np.asarray(qc)[:, None]
+    assert (np.asarray(out)[dead] == 0).all()
+
+
+# ---------------------------------------------------------------------------
 # engine bit-identity vs the pre-unification golden capture
 # ---------------------------------------------------------------------------
 # The workloads below are byte-for-byte the ones the golden file was

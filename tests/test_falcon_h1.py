@@ -171,6 +171,11 @@ def test_serving_engine_logits_match_the_reference(impl):
     path = "xla" if impl == "xla" else "pallas"
     assert st["kernel_paths"] == {f"ragged_span_attention/{path}": 2,
                                   f"ssd_chunk_update/{path}": 2}
+    # the block the two span calls were built with (all 4 pages of 16
+    # tokens a slot has; 2 query heads a KV head x 16 rows), beside
+    # kernel_paths; the dense form has none
+    assert st["kernel_tiles"] == ({} if impl == "xla" else {
+        "ragged_span_attention/pages=4,keys=64,rows=32": 2})
 
 
 def _cached_mixer(net, u, between=None):
@@ -297,6 +302,46 @@ def test_span_kernel_under_grouped_kv_heads(group):
         assert lowered(num_kv_heads=Hkv) == lowered()
     with pytest.raises(ValueError, match="query heads over"):
         pa.ragged_span_attention(q, kp, vp, table, lengths, num_kv_heads=3)
+
+
+@pytest.mark.parametrize("pages", ["float32", "int8"])
+@pytest.mark.parametrize("group,kb", [(2, None), (5, None), (5, 2), (5, 3)])
+def test_span_kernel_blocks_under_grouped_kv_heads(monkeypatch, group, kb,
+                                                   pages):
+    """The block of pages under stacked query heads (Falcon-H1 stacks 5):
+    P = 10 pages that neither the rule's block (8 pages here) nor 3
+    divides, contexts that end one key before, on and one key after a
+    block's edge, a span across it, an idle slot between busy ones and
+    counts of 0, 1 and Sq in one call; float and int8 pages; against the
+    dense reference, dead rows exact zeros."""
+    if kb is not None:
+        monkeypatch.setattr(pa, "_span_block_pages", lambda S, Sr, P: kb)
+    rng = np.random.default_rng(6)
+    B, Sq, Hkv, D, S, P = 6, 8, 2, 32, 8, 10
+    KB = kb or pa._span_block_pages(S, group * Sq, P)
+    assert KB == (kb or 8)
+    W, Hq, N = KB * S, Hkv * group, B * P
+    q = jnp.asarray(rng.standard_normal((B, Sq, Hq, D)), jnp.float32)
+    kp, vp = (rng.standard_normal((2, N, S, Hkv, D)) for _ in range(2))
+    kw = {}
+    if pages == "int8":
+        ks, vs = (np.abs(x).max(axis=(2, 4)) / 127.0 for x in (kp, vp))
+        kp, vp = (np.round(x / sc[:, :, None, :, None])
+                  for x, sc in ((kp, ks), (vp, vs)))
+        kw = dict(k_scale=jnp.asarray(ks, jnp.float32),
+                  v_scale=jnp.asarray(vs, jnp.float32))
+    kp, vp = (jnp.asarray(x.reshape(2, N, S, Hkv * D),
+                          jnp.int8 if kw else jnp.float32) for x in (kp, vp))
+    table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
+    lengths = jnp.asarray([W - 1, W, 5, W + 1, W - 3, P * S - 7], jnp.int32)
+    counts = jnp.asarray([1, 1, 0, 1, 8, 8], jnp.int32)
+    call = lambda **k: pa.ragged_span_attention(
+        q, kp, vp, table, lengths, q_counts=counts, layer=1,
+        num_kv_heads=Hkv, **kw, **k)
+    got = np.asarray(call(impl="pallas", interpret=True))
+    np.testing.assert_allclose(got, call(impl="xla"), rtol=2e-5, atol=2e-5)
+    dead = np.arange(Sq)[None, :] >= np.asarray(counts)[:, None]
+    assert (got[dead] == 0).all() and np.abs(got[~dead]).min() > 0
 
 
 @pytest.mark.parametrize("feature, kwargs", [

@@ -68,7 +68,14 @@ def test_ragged_span_attention_lowers(on_tpu, sq, qdtype, page_dtype):
         return pa.ragged_span_attention(q, kp, vp, table, lens, q_counts=qc,
                                         k_scale=ks, v_scale=vs, layer=LAYER)
 
+    from mxnet_tpu.ops.kernel_paths import TILES
+    before = dict(TILES)
     assert _lowers_to_mosaic(fn, *_span_args(sq, qdtype, page_dtype)) == 1
+    # one call, built with the block the adaptive rule documents: the 16
+    # pages a slot has, 1024 keys a grid step, at every row count
+    assert {k: n - before.get(k, 0) for k, n in TILES.items()
+            if n != before.get(k, 0)} == {
+        ("ragged_span_attention", f"pages=16,keys=1024,rows={sq}"): 1}
 
 
 def test_ragged_decode_attention_is_the_sq1_span_call(on_tpu):

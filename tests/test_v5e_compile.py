@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.models import PagedKVCache
+from mxnet_tpu.ops import kernel_paths
 from mxnet_tpu.ops import pallas_attention as pa
 
 # gpt2_774m.doc_backlog: 16 slots x 16 pages of 64 tokens, 20 heads of 64,
@@ -62,6 +63,15 @@ def _write_then_attend(kp, vp, ks, vs, table, lengths, spans, lock, q, k, v):
     return cache.k_pages, cache.v_pages, cache.k_scale, cache.v_scale, out
 
 
+def _tiles_since(before):
+    """The blocks the span kernel calls traced since `before` (a copy of
+    kernel_paths.TILES) were built with: {tile: calls}."""
+    return {tile: n - before.get((kernel, tile), 0)
+            for (kernel, tile), n in kernel_paths.TILES.items()
+            if kernel == "ragged_span_attention"
+            and n != before.get((kernel, tile), 0)}
+
+
 def _big_instructions(hlo):
     """(opcode, shape) of every copy, slice or transpose whose result is
     at least one layer of the pool large."""
@@ -84,6 +94,7 @@ def test_write_then_attend_works_on_the_pool_in_place(one_chip, page_dtype):
     pool = sds((LAYERS, PAGES, PAGE, H * D), page_dtype)
     scale = sds((LAYERS, PAGES, H), "float32") if quant else None
     x = sds((SLOTS, H, SQ, D), "bfloat16")
+    tiles = dict(kernel_paths.TILES)
     compiled = jax.jit(
         _write_then_attend,
         donate_argnums=(0, 1, 2, 3) if quant else (0, 1)).lower(
@@ -93,6 +104,10 @@ def test_write_then_attend_works_on_the_pool_in_place(one_chip, page_dtype):
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == LAYERS
     assert _big_instructions(hlo) == []
+    # the block the adaptive rule documents for 64 rows a head and 16
+    # pages of 64 a slot: all of them, 1024 keys a grid step, float and
+    # int8 pages alike
+    assert _tiles_since(tiles) == {"pages=16,keys=1024,rows=64": LAYERS}
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 64 << 20
     pools = 2 * LAYER_ELEMS * LAYERS * jnp.dtype(page_dtype).itemsize
@@ -138,6 +153,7 @@ def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
                                              sharding=one_chip)
     state = eng._device_state()
     assert sorted(state) == ["k", "rec", "v"]
+    tiles = dict(kernel_paths.TILES)
     compiled = eng._build_unified(greedy_only=True).lower(
         tuple(sds(p.data()._data) for p in eng._params),
         jax.tree_util.tree_map(sds, state), sds(eng._dstate[-1]),
@@ -147,6 +163,9 @@ def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
     hlo = compiled.as_text()
     layers = kw["num_layers"]
     assert hlo.count("tpu_custom_call") == 2 * layers    # span + ssd each
+    # 5 query heads a KV head stack 320 rows: 512 KiB of float32 scores
+    # a head leave room for four pages, 256 keys, a grid step (P = 10)
+    assert _tiles_since(tiles) == {"pages=4,keys=256,rows=320": layers}
     leaves = jax.tree_util.tree_leaves(state)
     # a pool, or one layer of one (an activation can be as large as a
     # layer of pages: told apart by the dimensions themselves)
