@@ -1,7 +1,7 @@
 """Trace-safety pass: no host syncs inside jit-traced code.
 
-The whole stack's compile-flat guarantee (steady_state_compiles == 0,
-docs/PERF_NOTES.md) rests on traced functions treating runtime tensor
+The whole stack's compile-flat guarantee (steady_state_compiles == 0)
+rests on traced functions treating runtime tensor
 values as opaque: the moment traced code calls `float()`/`int()`/
 `bool()`/`len()`/`.item()`/`np.asarray()` on a traced value, branches
 on one with `if`/`while`, or formats one into a cache key or metric

@@ -58,25 +58,6 @@ KNOBS = {k.name: k for k in [
          "residual-free ops (add/reshape/...) would otherwise free "
          "before backward; set 0 on memory-constrained first-order "
          "training (create_graph then raises)."),
-    # bench knobs (bench.py)
-    Knob("BENCH_WORKLOAD", str, "both",
-         "bench.py workload: both|bert|bert_large|resnet50|gpt2_decode|"
-         "decode"),
-    Knob("BENCH_BATCH", str, "",
-         "bench.py candidate batch sizes, best-effort descending; empty "
-         "= per-workload default (bert 32,16,8; bert_large 16,8,4; "
-         "resnet50 256,128,64)"),
-    Knob("BENCH_STEPS", int, 10, "bench.py timed steps"),
-    Knob("BENCH_SEQ_LEN", int, 512, "BERT bench sequence length"),
-    Knob("BENCH_MASKED", int, 76, "BERT bench masked positions per row"),
-    Knob("BENCH_IMAGE_SIZE", int, 224, "ResNet bench image size"),
-    Knob("BENCH_PEAK_FLOPS", float, 0.0,
-         "Override per-chip peak FLOP/s for MFU math (0 = device table)"),
-    Knob("BENCH_DECODE_BATCH", int, 8, "GPT-2 decode bench batch"),
-    Knob("BENCH_PROMPT_LEN", int, 128, "GPT-2 decode bench prompt length"),
-    Knob("BENCH_NEW_TOKENS", int, 128, "GPT-2 decode bench new tokens"),
-    Knob("BENCH_DECODE_IMAGES", int, 512, "decode bench image count"),
-    Knob("BENCH_DECODE_SIZE", int, 480, "decode bench source image size"),
     # distributed bootstrap (reference launcher env, kvstore.py)
     Knob("DMLC_PS_ROOT_URI", str, "", "coordinator host (launcher env)"),
     Knob("DMLC_PS_ROOT_PORT", str, "", "coordinator port (launcher env)"),
@@ -84,8 +65,8 @@ KNOBS = {k.name: k for k in [
     Knob("DMLC_WORKER_ID", int, 0, "process rank (launcher env)"),
     # jax passthroughs the framework sets/reads
     Knob("JAX_DEFAULT_PRNG_IMPL", str, "",
-         "PRNG impl; bench.py defaults to 'rbg' on TPU (hardware RNG "
-         "dropout masks)"),
+         "PRNG impl; 'rbg' is the TPU's hardware generator (dropout "
+         "masks), set by the BERT benchmark cell's configuration"),
     Knob("XLA_FLAGS", str, "",
          "XLA flags; tests force --xla_force_host_platform_device_count=8 "
          "for the virtual mesh"),

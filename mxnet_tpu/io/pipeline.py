@@ -14,9 +14,7 @@ pinned batches. Here:
     compatible with the reference), decodes + augments + batches on the
     pool, and PREFETCHES: `prefetch` batches are always in flight, and
     each batch is handed to jax asynchronously so host decode of batch
-    N+1 overlaps device compute of batch N;
-  * bench: `python bench.py --workload decode` measures images/sec
-    through this pipeline.
+    N+1 overlaps device compute of batch N.
 """
 from __future__ import annotations
 

@@ -43,7 +43,7 @@ class BertConfig:
         self.dtype = dtype
 
     def num_params(self):
-        """Analytic parameter count (for MFU math in bench.py)."""
+        """Analytic parameter count."""
         c = self
         embed = (c.vocab_size + c.max_length + c.type_vocab_size) * c.units \
             + 2 * c.units

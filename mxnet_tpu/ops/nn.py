@@ -216,9 +216,7 @@ def BatchNorm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
     side reductions XLA fuses into the producing conv's epilogue — and the
     normalize is folded to y = x*a + b with per-channel a, b precomputed in
     f32 then cast to the activation dtype, so the apply pass is a single
-    bf16 FMA instead of subtract/convert/mul chains (this one change is
-    ~+13% end-to-end on ResNet-50 training; docs/PERF_NOTES.md has the
-    measured breakdown)."""
+    bf16 FMA instead of subtract/convert/mul chains."""
     red = tuple(i for i in range(data.ndim) if i != axis)
     bshape = tuple(data.shape[axis] if i == axis else 1
                    for i in range(data.ndim))

@@ -22,8 +22,7 @@ from .. import telemetry as _telemetry
 __all__ = ["collective_summary", "comm_report", "ring_cost_bytes"]
 
 # comm_report publishes its totals so the compiled-step wire budget sits
-# next to the runtime serving/training metrics in one snapshot — a
-# BENCH round can carry both without re-parsing the report text
+# next to the runtime serving/training metrics in one snapshot
 _wire_bytes = _telemetry.gauge(
     "comm_wire_bytes_per_step",
     "static ring-model wire bytes per link per compiled step")

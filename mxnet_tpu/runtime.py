@@ -25,8 +25,8 @@ def enable_compile_cache():
     nothing is set here. Otherwise the cache is <checkout>/.jax_cache —
     a fixed path, because a later process only finds the entries of an
     earlier one at the same path. The ONE place the cache is configured:
-    every entry point (bench.py, chip_smoke.py, the fleet worker,
-    examples/) calls it before its first compile."""
+    every entry point (benchmarks/run.py, chip_smoke.py, the fleet
+    worker, examples/) calls it before its first compile."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -216,8 +216,6 @@ def _engine_metrics(eid):
             "serving_token_latency_seconds",
             "per-token decode latency at dispatch resolution "
             "(dispatch wall / tokens the slot emitted, weighted)", _E),
-        "decode_seconds": h("serving_decode_dispatch_seconds",
-                            "unified dispatch wall time", _E),
         "drain_seconds": h("serving_drain_seconds",
                            "serve(): last submit -> queue+slots empty", _E),
         "dispatch_errors": c(
@@ -395,7 +393,7 @@ def _weight_bytes_family():
     `float32` child is everything still full-width (embeddings, the
     tied LM head, norms, biases, the dequant scales); w8-off puts the
     whole slab under `float32`. The dtype split IS the capacity
-    headline — `bench.py gpt2_serving_w8` gates on the ~4x shrink."""
+    headline: int8 slabs are about a quarter of the float32 ones."""
     return telemetry.gauge(
         "serving_weight_bytes",
         "device bytes of the served weight operands, by storage dtype "
@@ -508,9 +506,7 @@ class ServingEngine:
     the engine's lifetime. prefill_chunk_budget: prompt tokens per
     dispatch across ALL slots (default chunk_tokens), round-robined so
     concurrent long prompts share the prefill lane fairly while decode
-    rows ride every dispatch untouched. decode_block / prefill_bucket
-    are accepted for compatibility and ignored — there is no K-step
-    scan and no bucket axis anymore. attn_impl: 'auto' (ragged Pallas
+    rows ride every dispatch untouched. attn_impl: 'auto' (ragged Pallas
     kernel on TPU, dense XLA elsewhere), 'pallas', 'pallas_interpret'
     (the kernel in interpret mode — CPU tests), or 'xla'. max_queue
     bounds the admission queue (None = unbounded); a full queue rejects
@@ -532,7 +528,6 @@ class ServingEngine:
     history — and the same unified forward verifies all of them.
     Greedy output is bit-identical to speculative=False; sampled output
     is distribution-preserving and reproducible across schedules.
-    spec_max_ngram/spec_min_ngram bound the lookup n-gram sizes.
 
     Every engine reports into mx.telemetry as per-engine labeled
     children (docs/OBSERVABILITY.md): TTFT, admission wait, per-token
@@ -542,16 +537,14 @@ class ServingEngine:
     """
 
     def __init__(self, model, num_slots, max_length=None, page_size=64,
-                 decode_block=None, attn_impl="auto", prefill_bucket=None,
-                 chunk_tokens=None, prefill_chunk_budget=None,
-                 dtype=None, max_queue=None, prefix_cache=False,
-                 prefix_cache_pages=None, speculative=False,
-                 spec_tokens=4, spec_max_ngram=3, spec_min_ngram=1,
-                 num_priorities=3, policy=None, max_retries=3,
-                 retry_backoff_s=0.02, clock=None, adapter_pool=None,
-                 tenant_quotas=None, kv_dtype=None,
-                 hbm_budget_bytes=None, host_kv_bytes=None, tp=1,
-                 tp_devices=None, weight_dtype=None,
+                 attn_impl="auto", chunk_tokens=None,
+                 prefill_chunk_budget=None, dtype=None, max_queue=None,
+                 prefix_cache=False, prefix_cache_pages=None,
+                 speculative=False, spec_tokens=4, num_priorities=3,
+                 policy=None, max_retries=3, retry_backoff_s=0.02,
+                 clock=None, adapter_pool=None, tenant_quotas=None,
+                 kv_dtype=None, hbm_budget_bytes=None, host_kv_bytes=None,
+                 tp=1, tp_devices=None, weight_dtype=None,
                  hbm_budget_includes_weights=False):
         self.model = model
         cfg = model.config
@@ -566,11 +559,6 @@ class ServingEngine:
                              f"model's position range {cfg.max_length}")
         self.max_length = max_length
         self.page_size = int(page_size)
-        # legacy knobs of the bucketed/K-step engine: accepted so old
-        # configs keep constructing, but the unified dispatch has no
-        # bucket axis and no step fusion for them to tune
-        self.decode_block = decode_block
-        self.prefill_bucket = prefill_bucket
         self.attn_impl = attn_impl
         # tensor-parallel serving (docs/SERVING.md "Tensor-parallel
         # serving"): tp > 1 runs the ONE unified program shard_map'ed
@@ -636,9 +624,7 @@ class ServingEngine:
             if self.spec_tokens < 2:
                 raise MXNetError("spec_tokens must be >= 2 (the current "
                                  "token + at least one draft)")
-            self._proposer = PromptLookupProposer(
-                self.spec_tokens - 1, max_ngram=spec_max_ngram,
-                min_ngram=spec_min_ngram)
+            self._proposer = PromptLookupProposer(self.spec_tokens - 1)
             # per-slot token history (prompt + emitted) the prompt-lookup
             # drafter matches against — the request's OWN history only,
             # so drafting is schedule-independent
@@ -811,9 +797,8 @@ class ServingEngine:
             if self._hbm_includes_weights:
                 # the served weight slab comes out of the same per-chip
                 # HBM the pages do: charging it here is what turns the
-                # w8 ~4x weight shrink into ADMITTED pages (the
-                # gpt2_serving_w8 bench runs both engines at one fixed
-                # budget where fp32 weights are the binding constraint)
+                # w8 ~4x weight shrink into ADMITTED pages where fp32
+                # weights are the binding constraint
                 page_budget -= self._weight_bytes_per_chip
                 if page_budget <= 0:
                     raise MXNetError(
@@ -3588,7 +3573,6 @@ class ServingEngine:
             m = self._metrics
             m["decode_dispatches"].inc()
             m["decode_steps"].inc()
-            m["decode_seconds"].observe(dt)
             n_chunks = int((chunk_len > 0).sum())
             if n_chunks:
                 m["prefill_chunks"].inc(n_chunks)

@@ -4,8 +4,8 @@ A `FaultPlan` is a deterministic, seed-driven schedule of faults
 injected through the engine's existing `dispatch_hook` seam (the hook
 fires at the top of every step and immediately before every prefill
 and decode dispatch, with the requests about to be dispatched). The
-chaos soak tests (tests/test_robustness.py) and the overload bench
-drive the supervisor with it; nothing here runs in production paths.
+chaos soak tests (tests/test_robustness.py) drive the supervisor with
+it; nothing here runs in production paths.
 
 Fault kinds (each an independent per-dispatch probability under one
 `numpy` Generator, so a given seed + workload replays the same plan):

@@ -231,7 +231,7 @@ class _WorkerHandler(_Handler):
         if not fw.ship_payload:
             # replay fallback mode: the blob carries kv_history only,
             # the decode worker re-prefills (bit-identical, just
-            # slower) — the bench's ablation arm
+            # slower)
             exported.kv_payload = None
         blob = wire.encode_request(exported)
         blob["final"] = False
@@ -340,7 +340,7 @@ class _WorkerHandler(_Handler):
                         "emitted": len(req.output_tokens),
                         "sent": sent,
                         # the full stitched phase budget (handoff
-                        # included) — the router and bench read TTFT
+                        # included) — the router reads the TTFT
                         # decomposition from here
                         "phases": {k: float(v) for k, v
                                    in (req.phases or {}).items()}})

@@ -33,9 +33,7 @@ in the flight ring. `recover_after` consecutive non-overloaded steps
 clear it. All thresholds default from engine shape (watermarks at
 1x/2x num_slots) so `SheddingPolicy()` is usable as-is.
 
-The policy is pure host arithmetic over a handful of counters — its
-in-path cost is bounded by the <2% A/B budget the overload bench
-(`bench.py gpt2_serving_overload`) measures.
+The policy is pure host arithmetic over a handful of counters.
 """
 from __future__ import annotations
 

@@ -25,10 +25,10 @@ accounting"). Three pieces, one process-global program table:
     overridable with ``MXNET_TPU_PEAK_FLOPS`` / ``MXNET_TPU_PEAK_
     BANDWIDTH``. The ridge point (peak_flops / peak_bw) classifies
     each program: arithmetic intensity above the ridge is compute
-    bound, below is memory bound. This is the ONE peak table
-    (``bench.py`` reads it too): an accelerator kind it does not know
-    is an error, and a CPU has no peak — MFU, bandwidth utilization
-    and the roofline side are simply absent from a CPU run.
+    bound, below is memory bound. This is the ONE peak table: an
+    accelerator kind it does not know is an error, and a CPU has no
+    peak — MFU, bandwidth utilization and the roofline side are simply
+    absent from a CPU run.
 
 In-path cost per dispatch is a handful of instrument updates (~µs
 against multi-ms dispatches); ``set_enabled(False)`` turns the in-path

@@ -39,7 +39,6 @@ def _engine(net, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_length", 64)
     kw.setdefault("page_size", 8)
-    kw.setdefault("decode_block", 4)
     kw.setdefault("attn_impl", "xla")
     return ServingEngine(net, **kw)
 

@@ -45,12 +45,12 @@ def hand_prefill_flops(Tb, C, L, V, T):
 
 
 C, H, L, V, T = 256, 4, 2, 512, 64
-B, PAGE, SPEC_S, K = 4, 16, 4, 2
+B, PAGE, SPEC_S = 4, 16, 4
 
 
 @pytest.fixture(scope="module")
 def gpt2_engines():
-    """One plain (K-step greedy decode) and one speculative engine over
+    """One plain (greedy decode) and one speculative engine over
     a shared GPT-2, both served once — compiled programs, registered
     costs, goodput counters and ledger providers all live."""
     cfg = GPT2Config(vocab_size=V, units=C, num_layers=L, num_heads=H,
@@ -59,7 +59,7 @@ def gpt2_engines():
     mx.rng.seed(0)
     net.initialize(mx.init.Normal(0.02))
     eng = ServingEngine(net, num_slots=B, max_length=T, page_size=PAGE,
-                        decode_block=K, attn_impl="xla")
+                        attn_impl="xla")
     done = eng.serve([Request(list(range(1, 11)), 6, request_id=i)
                       for i in range(B)])
     assert len(done) == B
@@ -435,46 +435,3 @@ def test_trainstep_register_cost_analysis():
     snap = ledger.snapshot()
     comp = snap["components"][step._cost_key]
     assert comp["params"]["bytes"] > 0
-
-
-# -- bench_compare ----------------------------------------------------------
-
-def test_bench_compare_regression_gate(tmp_path, capsys):
-    import tools.bench_compare as bc
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({"metric": "serving_tokens_per_sec",
-                               "value": 100.0, "unit": "tokens/sec",
-                               "vs_baseline": 1.0}) + "\n"
-                   + json.dumps({"metric": "p99_latency_ms",
-                                 "value": 10.0, "unit": "ms",
-                                 "vs_baseline": 0.0}))
-    # driver-round shape: records embedded in "tail"
-    new.write_text(json.dumps({"tail": "\n".join([
-        json.dumps({"metric": "serving_tokens_per_sec", "value": 80.0,
-                    "unit": "tokens/sec", "vs_baseline": 1.0}),
-        json.dumps({"metric": "p99_latency_ms", "value": 10.2,
-                    "unit": "ms", "vs_baseline": 0.0})])}))
-    rc = bc.main([str(old), str(new), "--threshold", "0.05"])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REGRESSED" in out and "serving_tokens_per_sec" in out
-    # latency moved 2% — inside the noise band
-    assert out.count("REGRESSED") == 1
-    # same files, inverted order: throughput 100 vs 80 is an improvement
-    rc = bc.main([str(new), str(old)])
-    assert rc == 0
-    assert "improved" in capsys.readouterr().out
-    # lower-is-better: latency regressing 10 -> 12 fails
-    worse = tmp_path / "worse.json"
-    worse.write_text(json.dumps([{"metric": "p99_latency_ms",
-                                  "value": 12.0, "unit": "ms",
-                                  "vs_baseline": 0.0}]))
-    rc = bc.main([str(old), str(worse), "--metric", "p99_latency_ms"])
-    assert rc == 1
-    # no overlap -> input error
-    lone = tmp_path / "lone.json"
-    lone.write_text(json.dumps({"metric": "other", "value": 1.0,
-                                "unit": "x", "vs_baseline": 0.0}))
-    assert bc.main([str(old), str(lone)]) == 2

@@ -47,7 +47,6 @@ def _engine(**kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_length", 32)
     kw.setdefault("page_size", 8)
-    kw.setdefault("decode_block", 2)
     kw.setdefault("attn_impl", "xla")
     return ServingEngine(_tiny(), **kw)
 

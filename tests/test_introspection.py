@@ -7,9 +7,11 @@ recorder (stall / queue-full storm / trainer NaN, each dumping exactly
 once), the span error-status satellite, empty-histogram percentile
 semantics, and the metrics-catalog checker.
 """
+import glob
 import json
 import math
 import os
+import re
 import subprocess
 import sys
 import threading
@@ -39,7 +41,6 @@ def _tiny_engine(**kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_length", 32)
     kw.setdefault("page_size", 8)
-    kw.setdefault("decode_block", 2)
     kw.setdefault("attn_impl", "xla")
     return ServingEngine(net, **kw), cfg
 
@@ -522,3 +523,33 @@ def test_metrics_catalog_is_complete():
     assert proc.returncode == 0, \
         f"catalog check failed:\n{proc.stdout}\n{proc.stderr}"
     assert "OK:" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# the documents name files that exist
+# ---------------------------------------------------------------------------
+
+# a `python[3] <path>.py` command, or a backticked path under one of the
+# tree's directories (a trailing `:line` / `::name` is not part of it)
+_DOC_PATH = re.compile(
+    r"python3? ((?:[\w.-]+/)*[\w.-]+\.py)\b"
+    r"|`((?:tools|docs|benchmarks|tests|mxnet_tpu)/[^`\s:]*)")
+
+
+def test_documents_name_files_that_exist():
+    """README.md, docs/*.md and the verify skill send a builder to
+    commands and files; every one of them is in the tree."""
+    docs = [os.path.join(REPO, "README.md"),
+            os.path.join(REPO, ".claude", "skills", "verify", "SKILL.md")]
+    docs += sorted(glob.glob(os.path.join(REPO, "docs", "*.md")))
+    missing = []
+    for doc in docs:
+        with open(doc) as f:
+            text = f.read()
+        for m in _DOC_PATH.finditer(text):
+            path = m.group(1) or m.group(2)
+            if not glob.glob(os.path.join(REPO, path)):
+                line = text.count("\n", 0, m.start()) + 1
+                missing.append(f"{os.path.relpath(doc, REPO)}:{line}: {path}")
+    assert not missing, "documents name files that are gone:\n" + \
+        "\n".join(missing)

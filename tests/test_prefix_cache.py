@@ -250,7 +250,7 @@ def _mixed_requests(cfg, rng, n=8, shared_frac=0.75, prefix_len=24,
 
 def _run(net, req_kws, **engine_kw):
     eng = ServingEngine(net, num_slots=3, max_length=64, page_size=8,
-                        decode_block=3, attn_impl="xla", **engine_kw)
+                        attn_impl="xla", **engine_kw)
     reqs = [Request(**kw) for kw in req_kws]
     eng.serve(reqs)
     return eng, {r.id: r.output_tokens for r in reqs}
@@ -283,7 +283,7 @@ def test_engine_prefix_cache_cow_fully_cached_prompt():
     rng = np.random.default_rng(12)
     prompt = rng.integers(0, cfg.vocab_size, 16).tolist()   # 2 pages of 8
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=2, attn_impl="xla", prefix_cache=True)
+                        attn_impl="xla", prefix_cache=True)
     (r1,) = eng.serve([Request(prompt, 5, request_id="a")])
     # the whole prompt is now cached; snapshot the tree's pages
     pc = eng.prefix_cache
@@ -303,7 +303,7 @@ def test_engine_prefix_cache_hit_skips_prefill_tokens():
     rng = np.random.default_rng(13)
     system = rng.integers(0, cfg.vocab_size, 32).tolist()
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=2, attn_impl="xla", prefix_cache=True)
+                        attn_impl="xla", prefix_cache=True)
     eng.serve([Request(system + [1, 2], 3, request_id=0)])
     base = eng.stats["prefill_tokens"]
     eng.serve([Request(system + [3, 4, 5], 3, request_id=1)])
@@ -320,7 +320,7 @@ def test_engine_churn_respects_budget_and_refcount_baseline():
     rng = np.random.default_rng(14)
     budget = 8
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla", prefix_cache=True,
+                        attn_impl="xla", prefix_cache=True,
                         prefix_cache_pages=budget)
     # 12 distinct prompts x 3 pages each = 36 pages of churn through an
     # 8-page budget
@@ -340,7 +340,7 @@ def test_engine_prefix_cache_disabled_pool_drains_clean():
     net, cfg = _tiny()
     rng = np.random.default_rng(15)
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla")
+                        attn_impl="xla")
     eng.serve([Request(rng.integers(0, cfg.vocab_size, 9).tolist(), 3,
                        request_id=i) for i in range(5)])
     assert eng.page_pool.num_free == eng.page_pool.num_pages
@@ -385,7 +385,7 @@ def test_cancel_queued_request():
     net, cfg = _tiny()
     rng = np.random.default_rng(17)
     eng = ServingEngine(net, num_slots=1, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla")
+                        attn_impl="xla")
     keep = Request(rng.integers(0, cfg.vocab_size, 4).tolist(), 3,
                    request_id="keep")
     drop = Request(rng.integers(0, cfg.vocab_size, 4).tolist(), 3,
@@ -412,7 +412,7 @@ def test_cancel_running_request_frees_slot_and_pages():
     net, cfg = _tiny()
     rng = np.random.default_rng(18)
     eng = ServingEngine(net, num_slots=1, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla", prefix_cache=True)
+                        attn_impl="xla", prefix_cache=True)
     hog = Request(rng.integers(0, cfg.vocab_size, 6).tolist(), 24,
                   request_id="hog")
     nxt = Request(rng.integers(0, cfg.vocab_size, 6).tolist(), 4,
@@ -447,7 +447,7 @@ def test_concurrent_submit_rejection_counter_is_exact():
     submitted - admitted, no drops, no double counts."""
     net, cfg = _tiny()
     eng = ServingEngine(net, num_slots=2, max_length=16, page_size=8,
-                        decode_block=1, attn_impl="xla", max_queue=6)
+                        attn_impl="xla", max_queue=6)
     n_threads, per_thread = 6, 20
     admitted = []
     rejected = []

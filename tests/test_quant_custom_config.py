@@ -163,12 +163,12 @@ def test_legacy_custom_op_class_api():
 # ---------------------------------------------------------------------------
 
 def test_config_catalog():
-    assert mx.config.get("BENCH_STEPS") == 10
-    os.environ["BENCH_STEPS"] = "3"
+    assert mx.config.get("MXTPU_DECODE_THREADS") == 0
+    os.environ["MXTPU_DECODE_THREADS"] = "3"
     try:
-        assert mx.config.get("BENCH_STEPS") == 3
+        assert mx.config.get("MXTPU_DECODE_THREADS") == 3
     finally:
-        del os.environ["BENCH_STEPS"]
+        del os.environ["MXTPU_DECODE_THREADS"]
     with pytest.raises(MXNetError, match="unknown"):
         mx.config.get("NOT_A_KNOB")
     desc = mx.config.describe()
@@ -178,12 +178,12 @@ def test_config_catalog():
         assert "MXNET_TOTALLY_BOGUS_KNOB" in mx.config.check_env()
     finally:
         del os.environ["MXNET_TOTALLY_BOGUS_KNOB"]
-    os.environ["BENCH_MASKED"] = "xyz"
+    os.environ["DMLC_NUM_WORKER"] = "xyz"
     try:
         with pytest.raises(MXNetError, match="valid int"):
-            mx.config.get("BENCH_MASKED")
+            mx.config.get("DMLC_NUM_WORKER")
     finally:
-        del os.environ["BENCH_MASKED"]
+        del os.environ["DMLC_NUM_WORKER"]
 
 
 # ---------------------------------------------------------------------------

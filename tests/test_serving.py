@@ -269,7 +269,7 @@ def test_engine_greedy_matches_full_recompute():
     # interpret-mode kernel has its own parity test above, and the slow
     # lane's Poisson soak runs the engine on pallas_interpret
     eng = ServingEngine(net, num_slots=3, max_length=64, page_size=8,
-                        decode_block=3, attn_impl="xla")
+                        attn_impl="xla")
     got = eng.generate(prompts, 8)
     assert got == want
     assert eng.stats["requests_finished"] == 4
@@ -282,7 +282,7 @@ def test_engine_eos_and_budget_free_slots_early():
     free_run = _greedy_full(net, p0, 8)
     eos = free_run[2]          # force an early stop on the 3rd token
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=4, attn_impl="xla")
+                        attn_impl="xla")
     r_eos = Request(p0, 8, eos_token_id=eos)
     r_long = Request(rng.integers(0, cfg.vocab_size, 6).tolist(), 8)
     done = eng.serve([r_eos, r_long])
@@ -303,18 +303,17 @@ def test_engine_sampled_reproducible_across_admission_order():
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                for n in (3, 7, 11, 5)]
 
-    def run(order, slots, block):
+    def run(order, slots):
         eng = ServingEngine(net, num_slots=slots, max_length=64,
-                            page_size=8, decode_block=block,
-                            attn_impl="xla")
+                            page_size=8, attn_impl="xla")
         reqs = [Request(prompts[i], 6, do_sample=True, temperature=0.8,
                         top_k=20, top_p=0.95, seed=100 + i,
                         request_id=i) for i in order]
         eng.serve(reqs)
         return {r.id: r.output_tokens for r in reqs}
 
-    a = run([0, 1, 2, 3], 2, 3)
-    b = run([3, 1, 0, 2], 4, 5)
+    a = run([0, 1, 2, 3], 2)
+    b = run([3, 1, 0, 2], 4)
     assert a == b
 
 
@@ -325,7 +324,7 @@ def test_engine_mixed_sampling_modes_one_program():
     rng = np.random.default_rng(4)
     p = rng.integers(0, cfg.vocab_size, 5).tolist()
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=4, attn_impl="xla")
+                        attn_impl="xla")
     greedy = Request(p, 6, request_id="g")
     sampled = Request(p, 6, do_sample=True, temperature=0.7, top_k=10,
                       seed=9, request_id="s")
@@ -392,7 +391,7 @@ def test_engine_drains_more_requests_than_slots():
     prompts = [rng.integers(0, cfg.vocab_size, 1 + (i % 4)).tolist()
                for i in range(7)]
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla")
+                        attn_impl="xla")
     outs = eng.generate(prompts, 1 + 3)
     assert len(outs) == 7
     assert all(len(o) == 4 for o in outs)
@@ -408,6 +407,18 @@ def test_engine_rejects_oversized_prompt():
         eng.submit(Request(list(range(17)), 4))
 
 
+@pytest.mark.parametrize("name", ["decode_block", "prefill_bucket",
+                                  "spec_max_ngram", "spec_min_ngram"])
+def test_engine_refuses_retired_arguments(name):
+    """The bucketed engine's two tuning knobs and the proposer's n-gram
+    bounds are gone from the constructor: a config that still names one
+    fails loudly instead of being silently ignored."""
+    net, _ = _tiny()
+    with pytest.raises(TypeError, match=name):
+        ServingEngine(net, num_slots=1, max_length=16, page_size=8,
+                      attn_impl="xla", **{name: 2})
+
+
 def test_engine_respects_capacity_budget():
     """A request whose budget exceeds the slot's remaining KV capacity
     is truncated to what fits instead of writing out of bounds."""
@@ -415,7 +426,7 @@ def test_engine_respects_capacity_budget():
     rng = np.random.default_rng(6)
     p = rng.integers(0, cfg.vocab_size, 12).tolist()
     eng = ServingEngine(net, num_slots=1, max_length=16, page_size=8,
-                        decode_block=4, attn_impl="xla")
+                        attn_impl="xla")
     (req,) = eng.serve([Request(p, 50)])
     # 12 prompt tokens, 16-slot capacity: 4 writes + the final sampled
     # token = 5 generated
@@ -498,7 +509,7 @@ def test_engine_soak_poisson_arrivals():
     net, cfg = _tiny()
     rng = np.random.default_rng(8)
     eng = ServingEngine(net, num_slots=4, max_length=64, page_size=8,
-                        decode_block=4, attn_impl="pallas_interpret")
+                        attn_impl="pallas_interpret")
     reqs = []
     for i in range(12):
         n = int(rng.integers(1, 30))

@@ -141,11 +141,10 @@ def test_http_edge_adopts_and_echoes_trace_context():
             return r.status, dict(r.headers), json.loads(r.read())
 
     # the frontend's backend mirrors tests/test_frontend.py's engine
-    # shape (num_slots=2, max_length=32, decode_block=2, no prefix
+    # shape (num_slots=2, max_length=32, no prefix
     # cache) so its programs are already compiled in a tier-1 run
     backend = ServingEngine(_tiny(), num_slots=2, max_length=32,
-                            page_size=8, decode_block=2,
-                            attn_impl="xla")
+                            page_size=8, attn_impl="xla")
     with ServingFrontend(backend, keepalive_s=0.05,
                          step_idle_s=0.005) as fe:
         code, hdrs, body = post(

@@ -293,7 +293,7 @@ def test_engine_spec_greedy_bit_identical_interleaved():
     rng = np.random.default_rng(4)
     prompts = _mixed_prompts(cfg, rng)
     eng_off = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                            decode_block=3, attn_impl="xla")
+                            attn_impl="xla")
     off = eng_off.generate(prompts, 9)
     eng_on = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
                            attn_impl="xla", speculative=True,
@@ -314,7 +314,6 @@ def test_engine_spec_greedy_bit_identical_interpret_kernel():
     rng = np.random.default_rng(5)
     prompts = _mixed_prompts(cfg, rng, n=3)
     off = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=2,
                         attn_impl="pallas_interpret").generate(prompts, 6)
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
                         attn_impl="pallas_interpret", speculative=True,
@@ -334,7 +333,7 @@ def test_engine_spec_with_prefix_cache_bit_identical():
     prompts = [shared + pat * 2, shared + [3], pat * 5,
                shared + pat * 2]          # last one: full-prompt CoW hit
     off = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
-                        decode_block=3, attn_impl="xla"
+                        attn_impl="xla"
                         ).generate(prompts, 8)
     eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
                         attn_impl="xla", speculative=True, spec_tokens=4,
@@ -399,8 +398,7 @@ def test_engine_spec_sampled_frequency_matches_spec_off():
     N = 240
 
     def run(speculative):
-        kw = dict(speculative=True, spec_tokens=3) if speculative else \
-            dict(decode_block=2)
+        kw = dict(speculative=True, spec_tokens=3) if speculative else {}
         eng = ServingEngine(net, num_slots=4, max_length=32, page_size=8,
                             attn_impl="xla", **kw)
         reqs = [Request(prompt, 2, do_sample=True, temperature=1.2,

@@ -54,7 +54,7 @@ def run_serving():
     # w8 weights on the demo engine so the --cost weight headline and
     # the serving_weight_bytes gauges carry real quantized values
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla", prefix_cache=True,
+                        attn_impl="xla", prefix_cache=True,
                         weight_dtype="int8")
     rng = np.random.default_rng(0)
     # half the prompts extend one shared prefix so the prefix-cache
@@ -96,7 +96,7 @@ def run_shedding():
     mx.rng.seed(0)
     net.initialize(mx.init.Normal(0.05))
     eng = ServingEngine(
-        net, num_slots=1, max_length=32, page_size=8, decode_block=2,
+        net, num_slots=1, max_length=32, page_size=8,
         attn_impl="xla",
         policy=SheddingPolicy(queue_low=1, queue_high=2,
                               degrade_after=2, recover_after=2))
@@ -135,7 +135,7 @@ def run_router():
     mx.rng.seed(0)
     net.initialize(mx.init.Normal(0.05))
     engines = [ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                             decode_block=2, attn_impl="xla")
+                             attn_impl="xla")
                for _ in range(2)]
     router = ServingRouter(engines, hedge_after_s=0.0)
     rng = np.random.default_rng(0)
@@ -175,7 +175,7 @@ def run_http():
     mx.rng.seed(0)
     net.initialize(mx.init.Normal(0.05))
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla")
+                        attn_impl="xla")
     fe = ServingFrontend(eng, keepalive_s=0.05, step_idle_s=0.005)
     try:
         for i in range(2):          # well-behaved streaming clients
@@ -288,7 +288,7 @@ def run_tenants():
         pool.register(name, random_lora(cfg, rank=2, seed=20 + i,
                                         scale=0.05))
     eng = ServingEngine(
-        net, num_slots=2, max_length=32, page_size=8, decode_block=2,
+        net, num_slots=2, max_length=32, page_size=8,
         attn_impl="xla", adapter_pool=pool,
         tenant_quotas={"hog": TenantQuota(max_active=1, max_queue=2),
                        "calm": TenantQuota(weight=2.0)})
@@ -365,7 +365,7 @@ def run_slo():
     mx.rng.seed(0)
     net.initialize(mx.init.Normal(0.05))
     eng = ServingEngine(net, num_slots=2, max_length=32, page_size=8,
-                        decode_block=2, attn_impl="xla")
+                        attn_impl="xla")
     rng = np.random.default_rng(0)
     reqs = [Request(rng.integers(1, cfg.vocab_size, 5).tolist(), 3,
                     seed=i, request_id=800 + i) for i in range(4)]
@@ -686,8 +686,7 @@ def main():
                               for q in eng._w8_plan)
                 print(f"#   w8 slab: {slab_w8 / 1e6:.2f} MB codes+scales"
                       f" vs {slab_fp / 1e6:.2f} MB fp32 "
-                      f"({slab_fp / slab_w8:.1f}x smaller — bench.py "
-                      f"gpt2_serving_w8)")
+                      f"({slab_fp / slab_w8:.1f}x smaller)")
         led = telemetry.ledger.snapshot()
         live = led.get("live_array_bytes")
         unattr = led.get("unattributed_bytes")

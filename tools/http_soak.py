@@ -196,7 +196,7 @@ def _main_fleet(args):
                                          spawn_fleet)
     from mxnet_tpu.serving.fleet.worker import build_engine
 
-    max_len, page, slots, block = 64, 8, 2, 4
+    max_len, page, slots = 64, 8, 2
     kv = None if args.kv_dtype == "float32" else args.kv_dtype
     # ONE spec builds the workers AND the offline reference: the init
     # seed pins the weights, so bit-identity across the process
@@ -209,8 +209,8 @@ def _main_fleet(args):
                        attention_dropout=0.0),
         "seed": 3, "init_std": 0.05,
         "engine": dict(num_slots=slots, max_length=max_len,
-                       page_size=page, decode_block=block,
-                       attn_impl="xla", max_queue=4, kv_dtype=kv,
+                       page_size=page, attn_impl="xla",
+                       max_queue=4, kv_dtype=kv,
                        prefill_chunk_budget=slots * page if kv
                        else None),
     }
@@ -708,7 +708,7 @@ def main(argv=None):
     mx.rng.seed(3)
     net = GPT2ForCausalLM(cfg)
     net.initialize(mx.init.Normal(0.05))
-    max_len, page, slots, block = 64, 8, 2, 4
+    max_len, page, slots = 64, 8, 2
     rng = np.random.default_rng(args.seed)
 
     # seeded client behaviors: ~50% read everything, ~30% hang up at
@@ -747,7 +747,7 @@ def main(argv=None):
                 "max_new_tokens": int(rng.integers(6, 17)),
                 "request_id": f"soak-{i}"}
         if behaviors[i] == "slow":
-            body["stream_buffer"] = 2       # < decode_block
+            body["stream_buffer"] = 2
         bodies.append(body)
 
     def new_engine(max_queue=None, tp=1, spill=False):
@@ -762,8 +762,8 @@ def main(argv=None):
         # prefix cache, so the ONLY thing the bit-identity bar varies
         # is the host tier itself (docs/SERVING.md "Tiered KV cache")
         eng = ServingEngine(net, num_slots=slots, max_length=max_len,
-                            page_size=page, decode_block=block,
-                            attn_impl="xla", max_queue=max_queue,
+                            page_size=page, attn_impl="xla",
+                            max_queue=max_queue,
                             kv_dtype=kv, prefill_chunk_budget=budget,
                             prefix_cache=tiered,
                             hbm_budget_bytes=(args.hbm_budget_bytes
