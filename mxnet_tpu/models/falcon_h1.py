@@ -361,7 +361,9 @@ class FalconH1Block(HybridBlock):
 class FalconH1ForCausalLM(HybridBlock):
     """Falcon-H1 with its untied LM head, behind the GPT-2 cache contract
     (`forward(ids, cache) -> (logits, cache)`), so serving.ServingEngine
-    takes it with nothing model-specific passed in."""
+    takes it with nothing model-specific passed in. `forward` is
+    `head(hidden(ids, cache))`: the engine calls the two apart, so only
+    the rows it samples pass the head."""
 
     def __init__(self, config: FalconH1Config, **kwargs):
         super().__init__(**kwargs)
@@ -370,8 +372,11 @@ class FalconH1ForCausalLM(HybridBlock):
         for i in range(c.num_layers):
             self.register_child(FalconH1Block(c), name=f"layer{i}")
         self.final_norm = RMSNorm(c.units)
-        self.head = Dense(c.vocab_size, use_bias=False, flatten=False,
-                          in_units=c.units)
+        # registered like the layers, with no attribute: `head` is the
+        # method below, the parameter stays "head.weight"
+        self.register_child(Dense(c.vocab_size, use_bias=False,
+                                  flatten=False, in_units=c.units),
+                            name="head")
 
     def blocks(self):
         return [child for name, child in self._children.items()
@@ -406,7 +411,9 @@ class FalconH1ForCausalLM(HybridBlock):
             else lengths, attn_impl=attn_impl,
             num_kv_heads=c.num_kv_heads, recurrent=rec)
 
-    def forward(self, inputs, cache=None):
+    def hidden(self, inputs, cache=None):
+        """Everything up to and including the final norm: (B, T) ids ->
+        ((B, T, C) hidden states, advanced cache)."""
         c = self.config
         ids = inputs._data if isinstance(inputs, NDArray) else inputs
         b, t = ids.shape
@@ -429,7 +436,17 @@ class FalconH1ForCausalLM(HybridBlock):
         for i, block in enumerate(self.blocks()):
             h, cache = block.forward(h, cache, i, positions, fresh)
         h = _rms(h, _raw(self.final_norm.weight), c.rms_norm_eps)
-        logits = NDArray(_linear(h, self.head) * c.lm_head_multiplier)
+        return NDArray(h), None if cache is None else cache.advance(t)
+
+    def head(self, h):
+        """(..., C) final hidden states -> (..., V) logits, row by row."""
+        h = h._data if isinstance(h, NDArray) else h
+        return NDArray(_linear(h, self._children["head"])
+                       * self.config.lm_head_multiplier)
+
+    def forward(self, inputs, cache=None):
+        h, cache = self.hidden(inputs, cache)
+        logits = self.head(h)
         if cache is None:
             return logits
-        return logits, cache.advance(t)
+        return logits, cache

@@ -394,18 +394,29 @@ class GPT2Model(HybridBlock):
 
 
 class GPT2ForCausalLM(HybridBlock):
-    """GPT-2 with the weight-tied LM head + static-cache generate()."""
+    """GPT-2 with the weight-tied LM head + static-cache generate().
+    `forward` is `head(hidden(ids, cache))`: serving.ServingEngine calls
+    the two apart, so only the rows it samples pass the head."""
 
     def __init__(self, config: GPT2Config, **kwargs):
         super().__init__(**kwargs)
         self.config = config
         self.backbone = GPT2Model(config)
 
-    def forward(self, inputs, cache=None):
-        h, cache = self.backbone(inputs, cache)
+    def hidden(self, inputs, cache=None):
+        """Everything up to and including the final LayerNorm:
+        (B, T) ids -> ((B, T, C) hidden states, advanced cache)."""
+        return self.backbone(inputs, cache)
+
+    def head(self, h):
+        """(..., C) final hidden states -> (..., V) logits, row by row."""
         w = self.backbone.word_embed.weight.data()   # (V, C) tied
-        logits = _opnn.FullyConnected(h, w, None, no_bias=True,
-                                      flatten=False)
+        return _opnn.FullyConnected(h, w, None, no_bias=True,
+                                    flatten=False)
+
+    def forward(self, inputs, cache=None):
+        h, cache = self.hidden(inputs, cache)
+        logits = self.head(h)
         if cache is None:
             return logits
         return logits, cache

@@ -325,6 +325,11 @@ def _engine_metrics(eid):
             "slots that began from zero recurrent state: admissions and "
             "re-prefills with no context, which the program zeroes from "
             "the slot's length (no host-side clearing)", _E),
+        "head_rows": c(
+            "serving_head_rows_total",
+            "rows put through the LM head: slots x 1 a dispatch, slots "
+            "x spec_tokens with speculation, of the slots x W rows the "
+            "body computes", _E),
         "kv_spill_seconds": h(
             "serving_kv_spill_seconds",
             "wall time of one spill batch (device page gather + host "
@@ -495,7 +500,10 @@ def _tenant_families():
 
 class ServingEngine:
     """Continuous-batching generation over a model with the GPT-2 cache
-    contract (forward(ids, cache) -> (logits, cache), make_cache()).
+    contract (hidden(ids, cache) -> (h, cache) and head(h) -> logits,
+    whose composition is the model's forward; make_cache()). The
+    dispatch calls them apart: it picks the rows it samples from the
+    final hidden states and only those pass the head.
 
     num_slots: concurrent decode sequences (the compiled batch).
     max_length: per-slot KV capacity (prompt + generated), rounded down
@@ -572,6 +580,13 @@ class ServingEngine:
         self._tp = int(tp or 1)
         if self._tp < 1:
             raise MXNetError(f"tp must be >= 1, got {tp}")
+        for part in ("hidden", "head"):
+            if not callable(getattr(model, part, None)):
+                raise MXNetError(
+                    f"{type(model).__name__} has no `{part}`: "
+                    "ServingEngine calls model.hidden(ids, cache) -> "
+                    "(h, cache) and model.head(h) -> logits apart "
+                    "(models/gpt2.py, models/falcon_h1.py)")
         # what a slot holds is the model's to declare (state_spec()):
         # KV pages of so many KV heads, and `recurrent` leaves of fixed
         # size a slot (a state-space layer's convolution tail and SSM
@@ -1153,6 +1168,7 @@ class ServingEngine:
                                    in self._tick_children.items()},
             "recurrent_state_bytes": self._rec_bytes,
             "state_resets": int(m["state_resets"].value),
+            "head_rows": int(m["head_rows"].value),
             "kernel_paths": {f"{kernel}/{path}": int(c.value)
                              for (kernel, path), c
                              in self._path_children.items()},
@@ -2367,8 +2383,10 @@ class ServingEngine:
             self._vs = self._vs.at[:, idx].set(zs)
 
     def _on_bad_slots(self, bad, exc_msg):
-        """Slots whose dispatch produced non-finite logits (the
-        in-program finite guard): this dispatch's tokens for them are
+        """Slots whose dispatch produced non-finite final hidden
+        states at a live position or non-finite logits in a sampled row
+        (the in-program finite guard; the logits of rows nobody samples
+        are not computed): this dispatch's tokens for them are
         already discarded by the caller; scrub their exclusive pages,
         roll them back blamed, and latch a dump. Co-batched finite
         slots keep their tokens — their state never mixed with the
@@ -3313,24 +3331,36 @@ class ServingEngine:
                     page_lock=lock, spans=qn, k_scale=state.get("ks"),
                     v_scale=state.get("vs"), attn_impl=impl,
                     recurrent=state.get("rec"))
-                logits, cache = model.forward(NDArray(toks_in), cache)
-                lg = logits._data
-                pos = jnp.arange(W)[None, :]
-                live = pos < qn[:, None]
-                # in-program finite guard over LIVE positions only: a
-                # slot whose logits went non-finite (corrupted KV,
-                # numeric blowup) is flagged; the host discards its
-                # tokens from this dispatch and re-prefills the request
-                ok = jnp.isfinite(
-                    jnp.where(live[:, :, None], lg, 0.0)
-                ).all(axis=(1, 2)) | ~(active | prefilling)
-                # the token every non-verify row samples: a decode row
-                # reads position 0, a finishing prefill reads its last
-                # live position — the distribution of the token after
-                # the full prompt
-                sel = jnp.take_along_axis(
-                    lg, jnp.maximum(chunk_len - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
+                h, cache = model.hidden(NDArray(toks_in), cache)
+                h = h._data
+                # the rows somebody reads, picked BEFORE the head, R a
+                # slot: a decoding slot's positions 0..R-1 (the one
+                # decode row; with speculation the S verify rows), a
+                # prefilling slot's last live position in every place
+                # (a slot is one or the other in a dispatch) — the
+                # distribution of the token after the full prompt.
+                # The head never sees the other W - R rows of a slot.
+                rows = jnp.where(prefilling[:, None],
+                                 (chunk_len - 1)[:, None],
+                                 jnp.arange(S if spec else 1)[None, :])
+                lg = model.head(NDArray(jnp.take_along_axis(
+                    h, rows[:, :, None], axis=1)))._data
+                # in-program finite guard: a slot whose state went
+                # non-finite (corrupted KV, numeric blowup) is flagged;
+                # the host discards its tokens from this dispatch and
+                # re-prefills the request. It reads the final hidden
+                # states at LIVE positions (a non-finite value in any
+                # K/V a live row read, or in a row that writes K/V, is
+                # in that row's residual stream) and the logits of the
+                # live picked rows. Not seen: finite hidden states whose
+                # logits overflow in a row nobody samples.
+                live = jnp.arange(W)[None, :] < qn[:, None]
+                ok = (jnp.isfinite(jnp.where(live[:, :, None], h, 0.0))
+                      .all(axis=(1, 2))
+                      & jnp.isfinite(jnp.where(
+                          (rows < qn[:, None])[:, :, None], lg, 0.0))
+                      .all(axis=(1, 2))) | ~(active | prefilling)
+                sel = lg[:, 0]
                 if greedy_only:
                     nxt = jnp.argmax(sel, axis=-1).astype(jnp.int32)
                 else:
@@ -3339,7 +3369,7 @@ class ServingEngine:
                                         top_k, top_p)
                 if spec:
                     emitted, n_acc = verify_tokens(
-                        lg[:, :S], drafts, nd, seeds, counters,
+                        lg, drafts, nd, seeds, counters,
                         do_sample, temp, top_k, top_p,
                         greedy_only=greedy_only)
                     vpos = jnp.arange(S)[None, :]
@@ -3573,6 +3603,7 @@ class ServingEngine:
             m = self._metrics
             m["decode_dispatches"].inc()
             m["decode_steps"].inc()
+            m["head_rows"].inc(B * S)
             n_chunks = int((chunk_len > 0).sum())
             if n_chunks:
                 m["prefill_chunks"].inc(n_chunks)

@@ -35,9 +35,11 @@ def hand_decode_flops(B, C, L, V, T, steps=1):
                     + 2 * B * C * V)
 
 
-def hand_verify_flops(B, S, C, L, V, T):
-    return L * (24 * B * S * C * C + 4 * B * S * C * T) \
-        + 2 * B * S * C * V
+def hand_unified_flops(B, W, R, C, L, V, T):
+    """The body over all B x W rows, the head over the B x R rows the
+    program samples (R = 1, or spec_tokens with speculation)."""
+    return L * (24 * B * W * C * C + 4 * B * W * C * T) \
+        + 2 * B * R * C * V
 
 
 def hand_prefill_flops(Tb, C, L, V, T):
@@ -161,15 +163,16 @@ def test_gpt2_program_flops_agree_with_hand_math(gpt2_engines):
 
     # ONE unified program per engine: a fixed B x W forward serving
     # prefill chunks, decode steps, and verify rows alike — its FLOPs
-    # are the verify model's with S = dispatch width
+    # are the verify model's with S = dispatch width, but for the head,
+    # which sees the sampled rows alone
     W = eng._width
     uni = progs[f"engine{eng._eid}/unified/W{W}/greedy"]
-    hand = hand_verify_flops(B, W, C, L, V, T)
+    hand = hand_unified_flops(B, W, 1, C, L, V, T)
     assert abs(uni["flops"] / hand - 1) < 0.05
 
     Ws = spec._width
     ver = progs[f"engine{spec._eid}/unified/W{Ws}/S{SPEC_S}/greedy"]
-    hand = hand_verify_flops(B, Ws, C, L, V, T)
+    hand = hand_unified_flops(B, Ws, SPEC_S, C, L, V, T)
     assert abs(ver["flops"] / hand - 1) < 0.05
 
     # every program compiled exactly once across the whole serve —

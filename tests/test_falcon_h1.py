@@ -53,6 +53,34 @@ def _err(got, want):
 TIGHT = 2e-4
 
 
+@pytest.mark.parametrize("cached", [False, True])
+def test_forward_is_head_of_hidden_and_rows_commute(cached):
+    """`forward` is `head(hidden(...))` bit for bit, and the head of picked
+    rows is the picked rows of the whole head (the multiplier is per row;
+    the matmul may accumulate in another order: float tolerance)."""
+    net, _, _ = _model(lm_head_multiplier=0.0078125)
+    ids = _ids(2, 2, 16)
+
+    def cache():
+        if not cached:
+            return None
+        c = net.make_cache(2, 64, page_size=16, attn_impl="xla")
+        c.spans = jnp.asarray([16, 9], jnp.int32)
+        return c
+
+    out = net.forward(ids, cache())
+    h, new = net.hidden(ids, cache())
+    whole = net.head(h)._data
+    assert (new is None) == (not cached)
+    assert jnp.array_equal(whole, (out[0] if cached else out)._data)
+    rows = jnp.asarray([[15], [8]])
+    picked = net.head(jnp.take_along_axis(h._data, rows[:, :, None], 1))
+    np.testing.assert_allclose(
+        np.asarray(picked._data),
+        np.asarray(jnp.take_along_axis(whole, rows[:, :, None], 1)),
+        rtol=1e-5, atol=1e-7)
+
+
 def test_full_forward_matches_the_reference():
     net, kw, params = _model()
     ids = _ids(0, 2, 37)        # not a multiple of the 16-row chunk
@@ -102,25 +130,27 @@ def test_each_branch_matches_the_reference_on_its_own(unit):
 
 def _serve_and_capture(net, requests, **engine_kw):
     """Serve `requests` and return, per request, the logits the unified
-    program computed at each of its positions (captured at the model's
-    forward, inside the engine's program), keyed by request id."""
+    program's hidden states give at each of its positions (captured at
+    the model's `hidden`, inside the engine's program, and put through the
+    model's `head` whole: the engine itself heads only the rows it
+    samples), keyed by request id."""
     seen = []
-    forward = net.forward
+    hidden = net.hidden
 
     def spy(inputs, cache=None):
-        logits, new = forward(inputs, cache)
+        h, new = hidden(inputs, cache)
         jax.debug.callback(
             lambda *a: seen.append([np.asarray(x) for x in a]),
-            inputs._data, logits._data, cache.spans, cache.length)
-        return logits, new
+            inputs._data, net.head(h)._data, cache.spans, cache.length)
+        return h, new
 
-    net.forward = spy
+    net.hidden = spy
     try:
         eng = ServingEngine(net, **engine_kw)
         done = eng.serve(requests)
         jax.effects_barrier()
     finally:
-        del net.forward
+        del net.hidden
     assert all(r.status == "finished" for r in done)
     rows = {r.id: {} for r in requests}
     owner = {}

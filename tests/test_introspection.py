@@ -397,6 +397,12 @@ def test_flight_stall_trigger_dumps_once(tmp_path):
 
     telemetry.request_log.clear()
     eng, cfg = _tiny_engine()
+    # the watchdog sees this engine alone: one that an earlier test of
+    # this worker left busy (a request in flight, never stepped again)
+    # is a stalled engine too, and trips a second dump
+    for name in list(flight._watches):
+        if name != f"engine{eng._eid}":
+            flight.unwatch(name)
     rec = flight.install(out_dir=str(tmp_path / "fd"),
                          stall_timeout=0.25, poll_interval=0.05)
     release = threading.Event()

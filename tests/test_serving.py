@@ -499,6 +499,244 @@ def test_program_registry_is_flat():
 
 
 # ---------------------------------------------------------------------------
+# the head over the rows that are sampled, and no others (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+def _tiny_falcon():
+    from benchmarks.weights import seed_weights
+    from mxnet_tpu import models
+    cfg = models.falcon_h1_34b_config(
+        vocab_size=512, units=128, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=32, hidden_size=256, ssm_heads=4,
+        ssm_head_dim=32, ssm_state=16, ssm_groups=2, conv_kernel=4,
+        chunk_size=16, max_length=256, dtype="float32")
+    net = models.FalconH1ForCausalLM(cfg)
+    net.collect_params().setattr("grad_req", "null")
+    seed_weights(net, 3, cfg.dtype, std=0.02)
+    return net, cfg
+
+
+# (model, engine arguments, R: the rows a slot puts through the head)
+_HEAD_CASES = {
+    "gpt2": (_tiny, dict(page_size=8), 1),
+    "gpt2-speculative": (_tiny, dict(page_size=8, speculative=True,
+                                     spec_tokens=4), 4),
+    "falcon_h1": (_tiny_falcon, dict(page_size=16), 1),
+}
+
+
+def _head_engine(case):
+    make, engine_kw, rows = _HEAD_CASES[case]
+    net, cfg = make()
+    eng = ServingEngine(net, num_slots=3, max_length=64, attn_impl="xla",
+                        **engine_kw)
+    return eng, cfg, rows
+
+
+def _sub_jaxprs(params):
+    for v in params.values():
+        for x in (v if isinstance(v, (list, tuple)) else (v,)):
+            x = getattr(x, "jaxpr", x)
+            if hasattr(x, "eqns"):
+                yield x
+
+
+def _shapes(jaxpr):
+    """The shape of every array a jaxpr computes, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in _sub_jaxprs(eqn.params):
+            yield from _shapes(sub)
+
+
+@pytest.mark.parametrize("case", list(_HEAD_CASES))
+def test_unified_program_holds_no_logits_of_unsampled_rows(case):
+    """Walk the jaxpr of the unified program the engine built: the widest
+    array that ends in the vocabulary has slots x R rows (R = 1, or
+    spec_tokens with speculation), never slots x W. The head's weight,
+    transposed to (units, vocab) for the matmul, is the one other array
+    that ends so."""
+    eng, cfg, rows = _head_engine(case)
+    jaxprs = []
+    build = eng._build_unified
+
+    class Traced:
+        """Stands where the jitted program stood, for CostedFunction's
+        one `lower(*args)`, and keeps the jaxpr it lowers."""
+        def __init__(self, fn):
+            self.fn = fn
+
+        def lower(self, *args):
+            traced = self.fn.trace(*args)
+            jaxprs.append(traced.jaxpr)
+            return traced.lower()
+
+    eng._build_unified = lambda greedy_only=False: Traced(
+        build(greedy_only))
+    eng.serve([Request([1, 2, 3, 4, 5], 3)])
+    (jaxpr,) = jaxprs
+    ending = [sh for sh in _shapes(jaxpr.jaxpr)
+              if sh and sh[-1] == cfg.vocab_size
+              and sh != (cfg.units, cfg.vocab_size)]
+    widest = max(ending, key=lambda sh: int(np.prod(sh[:-1])))
+    assert widest == (3, rows, cfg.vocab_size)
+    assert eng._width > rows
+
+
+@pytest.mark.parametrize("case", list(_HEAD_CASES))
+def test_head_rows_counts_slots_times_r_a_dispatch(case):
+    eng, cfg, rows = _head_engine(case)
+    rng = np.random.default_rng(4)
+    eng.serve([Request(rng.integers(0, cfg.vocab_size, n).tolist(), 5)
+               for n in (20, 3, 9, 17)])
+    st = eng.stats
+    assert st["decode_dispatches"] > 5
+    assert st["head_rows"] == st["decode_dispatches"] * 3 * rows
+    assert (f'serving_head_rows_total{{engine="{eng._eid}"}} '
+            f'{st["head_rows"]}') in mx.telemetry.render_prometheus()
+
+
+def test_gpt2_forward_is_head_of_hidden_and_rows_commute():
+    """`forward` is `head(hidden(...))` bit for bit, with and without a
+    cache, and the head of picked rows is the picked rows of the whole
+    head (the matmul may accumulate in another order: float tolerance)."""
+    net, cfg = _tiny()
+    ids = mx.nd.array(np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (3, 11)), dtype="int32")
+    h, none = net.hidden(ids)
+    assert none is None
+    whole = net.head(h).asnumpy()
+    assert np.array_equal(whole, net.forward(ids).asnumpy())
+    logits, _ = net.forward(ids, net.make_cache(3, 32))
+    hc, cache = net.hidden(ids, net.make_cache(3, 32))
+    assert int(cache.length) == 11
+    assert np.array_equal(net.head(hc).asnumpy(), logits.asnumpy())
+    rows = jnp.asarray([[10], [0], [4]])
+    picked = net.head(mx.nd.NDArray(jnp.take_along_axis(
+        h._data, rows[:, :, None], axis=1))).asnumpy()
+    np.testing.assert_allclose(
+        picked, np.take_along_axis(whole, np.asarray(rows)[:, :, None], 1),
+        rtol=1e-5, atol=1e-6)
+
+
+def test_engine_refuses_a_model_without_hidden_and_head():
+    net, _ = _tiny()
+
+    class ForwardOnly:
+        config = net.config
+        forward = net.forward
+        collect_params = net.collect_params
+
+    with pytest.raises(mx.MXNetError, match="ForwardOnly has no `hidden`"):
+        ServingEngine(ForwardOnly(), num_slots=1, max_length=32,
+                      page_size=8, attn_impl="xla")
+
+
+def _mid_prefill(eng, req):
+    """Step `eng` until `req` has fed one chunk of its prompt and its next
+    chunk is not its last; returns its slot."""
+    for _ in range(20):
+        eng.step()
+        slot = next((s for s in eng.scheduler.active_slots
+                     if eng.scheduler.request_at(s) is req), None)
+        if slot is not None and eng._lengths[slot] > 0:
+            assert eng._pending[slot].size > eng.chunk_tokens
+            return slot
+    raise AssertionError("the request never began its prefill")
+
+
+def _record_bad_slots(eng):
+    flagged = []
+    on_bad = eng._on_bad_slots
+
+    def spy(bad, msg):
+        flagged.append([eng.scheduler.request_at(s).id for s in bad])
+        return on_bad(bad, msg)
+
+    eng._on_bad_slots = spy
+    return flagged
+
+
+@pytest.mark.parametrize("pool", ["_kp", "_vp"])
+def test_finite_guard_reaches_rows_nobody_samples(pool):
+    """A NaN in a K/V page that only rows nobody samples read, the rows of
+    a NON-final prefill chunk, still flags that slot, and no other: the
+    guard reads the final hidden states of every live row. The flagged
+    request re-prefills and every stream is what it is without the fault."""
+    net, cfg = _tiny()
+
+    def requests():
+        r = np.random.default_rng(11)
+        return [Request(r.integers(1, cfg.vocab_size, n).tolist(), 6,
+                        request_id=f"r{i}")
+                for i, n in enumerate((5, 30, 4))]
+
+    def engine():
+        return ServingEngine(net, num_slots=2, max_length=64, page_size=8,
+                             attn_impl="xla", max_retries=4,
+                             retry_backoff_s=0.0)
+
+    want = {r.id: r.output_tokens for r in engine().serve(requests())}
+    eng = engine()
+    flagged = _record_bad_slots(eng)
+    reqs = requests()
+    for r in reqs:
+        eng.submit(r)
+    slot = _mid_prefill(eng, reqs[1])
+    page = int(eng._table_host[slot][0])
+    arr = getattr(eng, pool)
+    setattr(eng, pool, arr.at[:, page].set(jnp.asarray(np.nan, arr.dtype)))
+    done = []
+    while eng.has_work:
+        done.extend(eng.step())
+    assert flagged == [["r1"]]
+    assert {r.id: r.output_tokens for r in done} == want
+    assert eng.stats["requests_failed"] == 0
+    assert eng.audit_pages() == []
+
+
+@pytest.mark.parametrize("row,flags", [(1, True), (6, False)])
+def test_finite_guard_reads_live_hidden_rows_only(row, flags):
+    """The guard's own reach, apart from what attention carries: a
+    non-finite final hidden state in a live row that is NOT the one the
+    head sees flags its slot; in a dead row it flags nothing."""
+    net, cfg = _tiny()
+    hidden = net.hidden
+
+    def poisoned(inputs, cache=None):
+        h, new = hidden(inputs, cache)
+        # slot 0, position `row`, in dispatches where slot 0 feeds
+        # exactly 5 rows (its one prompt chunk): row 1 is live and not
+        # the last, row 6 is dead
+        hit = (cache.spans[0] == 5) & (jnp.arange(h.shape[0]) == 0)
+        bad = hit[:, None, None] \
+            & (jnp.arange(h.shape[1]) == row)[None, :, None]
+        return mx.nd.NDArray(jnp.where(bad, jnp.nan, h._data)), new
+
+    net.hidden = poisoned
+    try:
+        eng = ServingEngine(net, num_slots=2, max_length=64, page_size=8,
+                            attn_impl="xla", max_retries=2,
+                            retry_backoff_s=0.0)
+        flagged = _record_bad_slots(eng)
+        done = eng.serve([Request([5, 4, 3, 2, 1], 4, request_id="a"),
+                          Request([7, 8, 9], 4, request_id="b")])
+    finally:
+        del net.hidden
+    by_id = {r.id: r for r in done}
+    assert by_id["b"].status == "finished"
+    assert by_id["b"].output_tokens == _greedy_full(net, [7, 8, 9], 4)
+    if flags:
+        assert flagged and all(f == ["a"] for f in flagged)
+        assert by_id["a"].status == "failed"
+    else:
+        assert flagged == []
+        assert by_id["a"].output_tokens == _greedy_full(
+            net, [5, 4, 3, 2, 1], 4)
+
+
+# ---------------------------------------------------------------------------
 # long soak (slow lane)
 # ---------------------------------------------------------------------------
 

@@ -163,6 +163,9 @@ def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
     hlo = compiled.as_text()
     layers = kw["num_layers"]
     assert hlo.count("tpu_custom_call") == 2 * layers    # span + ssd each
+    # the head sees the one row a slot samples: no logits of all the rows
+    assert f"[{slots},{kw['vocab_size']}]" in hlo
+    assert f"[{slots},{width},{kw['vocab_size']}]" not in hlo
     # 5 query heads a KV head stack 320 rows: 512 KiB of float32 scores
     # a head leave room for four pages, 256 keys, a grid step (P = 10)
     assert _tiles_since(tiles) == {"pages=4,keys=256,rows=320": layers}
