@@ -1,15 +1,25 @@
-"""Mixture-of-Experts FFN layer (expert-parallel over the "ep" mesh axis).
+"""Mixture-of-Experts FFN layers: the holders of a router and of expert
+weights STACKED along a leading (E, ...) axis. The math lives in
+parallel/moe.py.
 
 Reference parity: none — SURVEY.md §2.4 records EP as absent from the
-reference; first-class here per the brief. The math lives in
-parallel/moe.py (GShard/Switch capacity-bounded dispatch); this layer
-owns the parameters: a gate Dense plus expert weights STACKED along a
-leading (E, ...) axis so `ep_rules()` shards dim 0 over "ep" and XLA
-partitions the expert einsums + inserts the dispatch/combine collectives.
+reference; first-class here per the brief.
+
+  * `MoEFFN`, the TRAINING form, DROPS tokens: GShard/Switch
+    capacity-bounded dispatch with softmax gates, static shapes;
+    `ep_rules()` shards dim 0 of the stacked weights over "ep" and XLA
+    partitions the expert einsums + inserts the dispatch/combine
+    collectives.
+  * `DroplessMoE`, the SERVING form, drops none of the pairs it holds: it
+    routes over all `num_experts`, is told which of them it holds
+    (`held=(first, count)`; its stacked weights have `count` rows), and
+    computes exactly the (row, expert) pairs of live rows that fall to
+    those, in one grouped feed-forward.
 """
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from ...base import MXNetError
 from ...ops import nn as _opnn
@@ -18,7 +28,7 @@ from ..block import HybridBlock
 from ..parameter import Parameter
 from .basic_layers import Dense
 
-__all__ = ["MoEFFN"]
+__all__ = ["MoEFFN", "DroplessMoE"]
 
 _ACTS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu, "silu": jax.nn.silu,
          "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True)}
@@ -79,3 +89,61 @@ class MoEFFN(HybridBlock):
         if return_aux:
             return y, aux
         return y
+
+
+class DroplessMoE(HybridBlock):
+    """The routed experts of a serving mixture-of-experts layer, (R, units)
+    rows -> the weighted sum over each row's HELD chosen experts.
+
+    The router scores all `num_experts` in float32 (independent sigmoids;
+    `gate_bias` is added for the choice only, DeepSeek-V3's correction
+    bias), the `top_k` best are chosen, and their weights are renormalised
+    over ALL the chosen (held or not) to sum to `scale`. Expert e is
+    relu(x W1[e])^2 W2[e], no bias. With `held=(first, count)` only
+    experts first .. first + count - 1 live here: a row's other pairs are
+    somebody else's, and the output is this holder's share of the sum.
+
+    forward(x, live, route_on=None, impl=, interpret=) -> (y, counts):
+    `route_on` (R, units_r) is what the router reads where that is not `x`
+    itself (a latent layer routes on the full-width row and feeds the
+    experts its projection); `counts` as parallel.moe.dropless_moe."""
+
+    def __init__(self, units, hidden_size, num_experts, top_k, held=None,
+                 router_units=None, scale=1.0, **kwargs):
+        super().__init__(**kwargs)
+        first, count = held if held is not None else (0, num_experts)
+        if top_k > num_experts:
+            raise MXNetError(f"top_k {top_k} > num_experts {num_experts}")
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise MXNetError(f"held experts {first}..{first + count - 1} "
+                             f"are not among the {num_experts}")
+        self._top_k, self._first, self._scale = top_k, first, scale
+        self.gate = Dense(num_experts, flatten=False, use_bias=False,
+                          in_units=router_units or units)
+        self.gate_bias = Parameter("gate_bias", shape=(num_experts,),
+                                   init="zeros")
+        self.expert_w1 = Parameter("expert_w1",
+                                   shape=(count, units, hidden_size))
+        self.expert_w2 = Parameter("expert_w2",
+                                   shape=(count, hidden_size, units))
+
+    def route(self, u):
+        """(weights (R, k) float32, experts (R, k)) of rows `u`."""
+        from ...parallel.moe import top_k_weights
+        logits = jnp.matmul(
+            u, self.gate.weight.data()._data.T,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+        bias = self.gate_bias.data()._data.astype(jnp.float32)
+        return top_k_weights(jax.nn.sigmoid(logits), self._top_k, bias=bias,
+                             scale=self._scale)
+
+    def forward(self, x, live, route_on=None, impl="auto", interpret=False):
+        from ...parallel.moe import dropless_moe
+        with jax.named_scope("moe.route"):
+            weights, experts = self.route(x if route_on is None
+                                          else route_on)
+        return dropless_moe(
+            x, weights, experts, live, self.expert_w1.data()._data,
+            self.expert_w2.data()._data, first=self._first, impl=impl,
+            interpret=interpret)

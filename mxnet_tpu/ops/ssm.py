@@ -51,9 +51,12 @@ def _unsupported_reason(x, B, state):
     can; interpret mode runs any shape)."""
     W, H, P = x.shape[1:]
     N = B.shape[-1]
-    if P % 128 or N % 128:
-        return (f"head_dim {P} and state size {N} must be multiples of "
-                "128 lanes")
+    if (P % 128 and 128 % P) or N % 128:
+        return (f"head_dim {P} must divide or be a multiple of 128 lanes, "
+                f"and state size {N} a multiple")
+    if P < 128 and (H // B.shape[2]) % (128 // P):
+        return (f"{H // B.shape[2]} heads a group do not fill tiles of "
+                f"{128 // P} heads of {P}")
     if W % 8:
         return f"{W} rows break the sublane rule (multiple of 8)"
     if x.dtype not in (jnp.float32, jnp.bfloat16):
@@ -120,17 +123,27 @@ def _ssd_chunk_xla(x, dt, A, B, C, D, s0, q_counts):
 
 
 def _ssd_kernel(qc_ref, fresh_ref, dec_ref, d_ref, rows_ref, x_ref, b_ref,
-                c_ref, s_ref, y_ref, so_ref, *, W, hb, P):
+                c_ref, s_ref, y_ref, so_ref, *, W, hb, P, q):
     """One (slot, block of hb heads of one group). rows_ref holds, per
     head of the block and along the W lanes, its running log-decay c
     (rows 0..hb-1), its masked dt (hb..2hb-1) and the rows' weights in
     the state's update w (2hb..3hb-1); dec_ref (slots, H) the chunk's
     whole decay exp(c_W) and d_ref (H,) D, as scalars: a (1, 1) value
-    does not broadcast to a tile."""
+    does not broadcast to a tile.
+
+    Heads narrower than the 128 lanes of a tile are taken q at a time
+    (128 // P on the chip): their inputs are one (W, 128) tile side by
+    side, their states (q, P, N) stacked are one (128, N) matrix, so the
+    state's read-out and update are ONE full-width product for all q, and
+    what differs by head (decay, dt, D) is laid over lanes or rows with a
+    select. Only the intra-chunk product is made once a head, each as
+    wide as the tile, and the head's own lanes kept. With P a multiple
+    of 128, q is 1 and no select is emitted."""
     b = pl.program_id(0)
     h0 = pl.program_id(1) * hb
     qn = qc_ref[b]
     f32 = jnp.float32
+    PW = q * P
 
     @pl.when(qn == 0)
     def _idle():
@@ -140,9 +153,11 @@ def _ssd_kernel(qc_ref, fresh_ref, dec_ref, d_ref, rows_ref, x_ref, b_ref,
     @pl.when(qn > 0)
     def _work():
         cd = x_ref.dtype
+        N = s_ref.shape[-1]
         # all False for a fresh slot (fresh is 0 or 1), else all True
-        kept = lax.broadcasted_iota(jnp.int32, s_ref.shape[2:], 0) \
-            >= fresh_ref[b] * s_ref.shape[2]
+        s_row = lax.broadcasted_iota(jnp.int32, (PW, N), 0)
+        kept = s_row >= fresh_ref[b] * PW
+        lane = lax.broadcasted_iota(jnp.int32, (W, PW), 1)
         live = lax.broadcasted_iota(jnp.int32, (W, 1), 0) < qn
         Bm = jnp.where(live, b_ref[0], jnp.zeros_like(b_ref[0]))  # (W, N)
         Cm = c_ref[0]
@@ -154,39 +169,67 @@ def _ssd_kernel(qc_ref, fresh_ref, dec_ref, d_ref, rows_ref, x_ref, b_ref,
         # a (1, W) row as a (W, 1) column, without a transpose
         col = lambda r: jnp.sum(jnp.where(eye, r, 0.0), axis=1,
                                 keepdims=True)
-        for i in range(hb):
-            cs_r = rows_ref[0, 0, i:i + 1, :]                   # (1, W)
-            dt_r = rows_ref[0, 0, hb + i:hb + i + 1, :]
-            w_r = rows_ref[0, 0, 2 * hb + i:2 * hb + i + 1, :]
-            cs_c = col(cs_r)
-            L = jnp.exp(jnp.where(tri, cs_c - cs_r, NEG_INF)) * dt_r
-            x = x_ref[0, :, i * P:(i + 1) * P]                  # (W, P)
+
+        def over(index, values):
+            """values[k] where index // P == k: one value a head, laid
+            over the heads' lanes (or the stacked states' rows)."""
+            out = values[-1]
+            for k in range(q - 2, -1, -1):
+                out = jnp.where(index < (k + 1) * P, values[k], out)
+            return out
+
+        for i in range(hb // q):
+            heads = range(i * q, (i + 1) * q)
+            cs_r = [rows_ref[0, 0, j:j + 1, :] for j in heads]   # (1, W)
+            dt_r = [rows_ref[0, 0, hb + j:hb + j + 1, :] for j in heads]
+            w_c = [col(rows_ref[0, 0, 2 * hb + j:2 * hb + j + 1, :])
+                   for j in heads]
+            cs_c = [col(r) for r in cs_r]
+            x = x_ref[0, :, i * PW:(i + 1) * PW]                # (W, PW)
             x = jnp.where(live, x, jnp.zeros_like(x))
             # a fresh slot reads zeros whatever the pool holds, a NaN
             # from the slot's last owner included
-            s0 = jnp.where(kept, s_ref[0, i], 0.0)              # (P, N)
-            y = lax.dot_general((cb * L).astype(cd), x,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
-            y = y + jnp.exp(cs_c) * lax.dot_general(
-                Cm, s0.astype(cd), (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)
-            y = y + d_ref[h0 + i] * x.astype(f32)
-            y_ref[0, :, i * P:(i + 1) * P] = jnp.where(
+            s0 = s_ref[0, i * q] if q == 1 \
+                else s_ref[0, i * q:(i + 1) * q].reshape(PW, N)
+            s0 = jnp.where(kept, s0, 0.0)                       # (PW, N)
+            y = over(lane, [
+                lax.dot_general(
+                    (cb * jnp.exp(jnp.where(tri, c - r, NEG_INF)) * d)
+                    .astype(cd), x, (((1,), (0,)), ((), ())),
+                    preferred_element_type=f32)
+                for c, r, d in zip(cs_c, cs_r, dt_r)])
+            y = y + over(lane, [jnp.exp(c) for c in cs_c]) \
+                * lax.dot_general(Cm, s0.astype(cd),
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=f32)
+            y = y + over(lane, [d_ref[h0 + j] for j in heads]) \
+                * x.astype(f32)
+            y_ref[0, :, i * PW:(i + 1) * PW] = jnp.where(
                 live, y, 0.0).astype(y_ref.dtype)
             upd = lax.dot_general(
-                (x.astype(f32) * col(w_r)).astype(cd), Bm,
+                (x.astype(f32) * over(lane, w_c)).astype(cd), Bm,
                 (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)                     # (P, N)
-            so_ref[0, i] = dec_ref[b, h0 + i] * s0 + upd
+                preferred_element_type=f32)                     # (PW, N)
+            s1 = over(s_row, [dec_ref[b, h0 + j] for j in heads]) * s0 \
+                + upd
+            if q == 1:
+                so_ref[0, i] = s1
+            else:
+                so_ref[0, i * q:(i + 1) * q] = s1.reshape(q, P, N)
 
 
 def _heads_per_block(hpg, P, N):
-    """The most heads of one group whose float32 state fits the block."""
+    """(hb, q): the most heads of one group whose float32 state fits the
+    block, and how many of them share a tile of 128 lanes (1 where a head
+    fills it; as many as divide the group where it is narrower)."""
+    q = max(1, 128 // P)
+    while hpg % q:
+        q -= 1
     hb = hpg
-    while hb > 1 and (hb * P * N * 4 > _STATE_BLOCK_BYTES or hpg % hb):
+    while hb > q and (hb * P * N * 4 > _STATE_BLOCK_BYTES or hpg % hb
+                      or hb % q):
         hb -= 1
-    return hb
+    return hb, q
 
 
 def _ssd_chunk_pallas(x, dt, A, B, C, D, state, q_counts, fresh, layer,
@@ -194,7 +237,7 @@ def _ssd_chunk_pallas(x, dt, A, B, C, D, state, q_counts, fresh, layer,
     Bt, W, H, P = x.shape
     G, N = B.shape[2:]
     hpg = H // G
-    hb = _heads_per_block(hpg, P, N)
+    hb, q = _heads_per_block(hpg, P, N)
     nb = H // hb
     _, dt, cs = _masked(dt, A, q_counts)
     by_block = lambda a: a.transpose(0, 2, 1).reshape(Bt, nb, hb, W)
@@ -228,7 +271,7 @@ def _ssd_chunk_pallas(x, dt, A, B, C, D, state, q_counts, fresh, layer,
         ],
     )
     y, state = pl.pallas_call(
-        functools.partial(_ssd_kernel, W=W, hb=hb, P=P),
+        functools.partial(_ssd_kernel, W=W, hb=hb, P=P, q=q),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((Bt, W, H * P), x.dtype),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],

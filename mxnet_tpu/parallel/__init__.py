@@ -18,5 +18,6 @@ from .pp import (PPTrainStep, gpipe, pipeline_grads,  # noqa: F401
                  pipeline_loss, pipeline_loss_and_grads,
                  stack_stage_params)
 from .moe import (  # noqa: F401
-    all_to_all_tokens, moe_dispatch_combine, top_k_gating)
+    all_to_all_tokens, dropless_moe, moe_dispatch_combine, top_k_gating,
+    top_k_weights)
 from .step import EvalStep, TrainStep  # noqa: F401

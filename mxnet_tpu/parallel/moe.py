@@ -1,9 +1,25 @@
-"""Mixture-of-Experts FFN with expert parallelism over the "ep" mesh axis.
+"""Mixture-of-Experts FFN: two forms over one choice of experts.
 
 Reference parity: none — the reference has no MoE (SURVEY.md §2.4
 presence matrix: EP absent); the brief makes it first-class here.
 
-TPU-native design (GShard/Switch formulation): top-k gating with a
+Both forms pick each token's experts and their weights with
+`top_k_weights`. They differ in what happens to a token whose expert is
+full:
+
+  * `moe_dispatch_combine` (training; gluon.nn.MoEFFN) DROPS it. Every
+    expert has `capacity` slots; a token over capacity gets weight 0 from
+    that expert. All shapes are static and the experts shard over "ep".
+  * `dropless_moe` (serving; gluon.nn.DroplessMoE) drops NOTHING it holds:
+    every (token, expert) pair whose expert this chip holds is computed,
+    however unevenly the pairs fall, in one grouped feed-forward over the
+    pairs sorted by expert (ops/moe.expert_ffn). Its work a dispatch
+    depends on the routing. It is told WHICH experts it holds (`first`,
+    and as many as its stacked weights have rows); pairs whose expert lives
+    elsewhere are left to whoever holds it, and nothing here stands in for
+    that chip or for the exchange with it.
+
+The training form (GShard/Switch formulation): top-k gating with a
 capacity-bounded one-hot dispatch, so every shape is static —
 
     dispatch:  (S, E, Cap) one-hot   tokens → expert slots
@@ -27,7 +43,19 @@ from jax import lax
 from ..base import MXNetError
 from .mesh import AXIS_EP, PartitionSpec, current_mesh, shard_map_compat
 
-__all__ = ["top_k_gating", "moe_dispatch_combine", "all_to_all_tokens"]
+__all__ = ["top_k_weights", "top_k_gating", "moe_dispatch_combine",
+           "dropless_moe", "all_to_all_tokens"]
+
+
+def top_k_weights(scores, top_k, bias=None, scale=1.0):
+    """Each token's experts and their weights. scores: (S, E) float32,
+    non-negative (softmax probabilities or sigmoids). The `top_k` largest
+    of `scores + bias` are chosen (`bias` steers the choice only); the
+    weights are the chosen experts' own scores, renormalised to sum to
+    `scale`. Returns (weights (S, k) float32, experts (S, k) int32)."""
+    _, idx = lax.top_k(scores if bias is None else scores + bias, top_k)
+    vals = jnp.take_along_axis(scores, idx, axis=-1)
+    return scale * vals / (vals.sum(-1, keepdims=True) + 1e-20), idx
 
 
 def top_k_gating(logits, top_k, capacity):
@@ -38,10 +66,8 @@ def top_k_gating(logits, top_k, capacity):
     mean(router_prob_e) * mean(tokens_routed_e)."""
     S, E = logits.shape
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, top_k)          # (S, k)
-    # renormalize the kept gates (standard top-k MoE)
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    # the kept gates renormalized (standard top-k MoE)
+    gate_vals, gate_idx = top_k_weights(probs, top_k)      # (S, k)
 
     dispatch = jnp.zeros((S, E, capacity), bool)
     combine = jnp.zeros((S, E, capacity), jnp.float32)
@@ -89,6 +115,50 @@ def moe_dispatch_combine(x, gate_logits, w1, b1, w2, b2, top_k=2,
     # combine all-to-all back to tokens
     y = jnp.einsum("sec,ecm->sm", combine, expert_out)
     return y.astype(x.dtype), aux.astype(x.dtype)
+
+
+def dropless_moe(x, weights, experts, live, w1, w2, first=0, impl="auto",
+                 interpret=False):
+    """The dropless form on flat rows, for the experts this chip holds.
+
+    x:        (R, D) rows.
+    weights:  (R, k) float32 and
+    experts:  (R, k) int32, from `top_k_weights` over ALL the experts.
+    live:     (R,) bool; a dead row (padding of a fixed-shape dispatch)
+              costs no expert work and gets zeros.
+    w1, w2:   (G, D, F), (G, F, D): experts first .. first + G - 1, stacked.
+    Pairs whose expert is not held, and every pair of a dead row, are
+    dropped BEFORE any expert work; the rest are sorted by expert and go
+    through one grouped feed-forward (ops/moe.expert_ffn, relu(.)^2).
+
+    Returns (y (R, D) in x's dtype: the weighted sum over the HELD chosen
+    experts, a partial sum where first/G cover a share of the experts;
+    counts (5,) int32: 1, live rows, pairs computed, experts touched, the
+    largest group)."""
+    from ..ops.moe import expert_ffn
+    R, K = experts.shape
+    G = w1.shape[0]
+    with jax.named_scope("moe.sort"):
+        local = experts - first
+        held = (local >= 0) & (local < G) & live[:, None]
+        # dropped pairs sort behind every held expert's
+        key = jnp.where(held, local, G).reshape(-1).astype(jnp.int32)
+        at = jnp.arange(R * K, dtype=jnp.int32)
+        key, order = lax.sort_key_val(key, at)
+        sizes = jnp.diff(jnp.searchsorted(
+            key, jnp.arange(G + 1, dtype=jnp.int32))).astype(jnp.int32)
+        rows = jnp.take(x, order // K, axis=0)
+        back = jnp.zeros_like(order).at[order].set(at, unique_indices=True)
+    with jax.named_scope("moe.experts"):
+        y = expert_ffn(rows, w1, w2, sizes, impl=impl, interpret=interpret)
+        # back to (row, choice); what the kernel never visited is undefined
+        y = jnp.take(y, back, axis=0).reshape(R, K, -1)
+        y = jnp.sum(jnp.where(held[:, :, None],
+                              y.astype(jnp.float32) * weights[:, :, None],
+                              0.0), axis=1).astype(x.dtype)
+    counts = jnp.stack([jnp.int32(1), live.sum(dtype=jnp.int32), sizes.sum(),
+                        (sizes > 0).sum(dtype=jnp.int32), sizes.max()])
+    return y, counts
 
 
 def all_to_all_tokens(x, mesh=None, axis=AXIS_EP, split_dim=1, concat_dim=0):

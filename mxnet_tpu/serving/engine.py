@@ -119,6 +119,9 @@ _engine_ids = itertools.count()
 # engine's children, the registry/prometheus view aggregates across
 # engines. docs/OBSERVABILITY.md catalogs each one.
 _E = ("engine",)
+# how often a dispatch folds the model's device counters into the host's
+# totals when nobody reads stats (ServingEngine._fold_model_counts)
+_FOLD_EVERY = 4096
 
 
 def _engine_metrics(eid):
@@ -320,6 +323,20 @@ def _engine_metrics(eid):
             "device bytes of the slots' recurrent state (fixed-size "
             "per-slot leaves the model declares beside its KV pages; 0 "
             "for a model with pages only)", _E),
+        "kv_layers": g(
+            "serving_kv_layers",
+            "layers that hold KV pages (state_spec()['kv_layers']; every "
+            "layer for a model that says nothing)", _E),
+        "recurrent_layers": g(
+            "serving_recurrent_layers",
+            "layers that hold the slots' recurrent leaves "
+            "(state_spec()['recurrent_layers']; 0 for a model with pages "
+            "only)", _E),
+        "expert_weight_bytes": g(
+            "serving_expert_weight_bytes",
+            "device bytes of the routed experts this engine's model holds "
+            "(state_spec()['expert_weight_bytes']; 0 for a model without "
+            "experts)", _E),
         "state_resets": c(
             "serving_state_resets_total",
             "slots that began from zero recurrent state: admissions and "
@@ -594,6 +611,17 @@ class ServingEngine:
         slot_state = model.state_spec() if hasattr(model, "state_spec") else {
             "num_layers": cfg.num_layers, "num_kv_heads": cfg.num_heads,
             "head_dim": cfg.units // cfg.num_heads, "recurrent": {}}
+        # state by layer kind: `kv_layers` of the layers hold pages and
+        # `recurrent_layers` hold the recurrent leaves; a model whose
+        # layers all hold both says neither. The model maps its layer
+        # index to its page layer and its state layer.
+        self._kv_layers = int(slot_state.get("kv_layers",
+                                             slot_state["num_layers"]))
+        self._rec_layers = int(slot_state.get(
+            "recurrent_layers", slot_state["num_layers"])) \
+            if slot_state["recurrent"] else 0
+        self._expert_weight_bytes = int(
+            slot_state.get("expert_weight_bytes", 0))
         if slot_state["recurrent"]:
             # recurrent state is not paged and cannot be shared, copied
             # page by page, split over heads or rebuilt from pages: what
@@ -788,7 +816,7 @@ class ServingEngine:
         store = jnp.dtype(jnp.int8) if self._quant else jnp.dtype(dt)
         # the pools hold the KV heads: fewer than the query heads
         # under grouped-query attention
-        L, H, Dh = (slot_state["num_layers"], slot_state["num_kv_heads"],
+        L, H, Dh = (self._kv_layers, slot_state["num_kv_heads"],
                     slot_state["head_dim"])
         page_bytes = 2 * L * page_size * H * Dh * store.itemsize
         if self._quant:
@@ -796,10 +824,10 @@ class ServingEngine:
         self._hbm_budget = None if hbm_budget_bytes is None \
             else int(hbm_budget_bytes)
         self._hbm_includes_weights = bool(hbm_budget_includes_weights)
-        # recurrent state: one whole leaf (L, slots, ...) per declared
-        # name, resident like the pages and donated with them
+        # recurrent state: one whole leaf (state layers, slots, ...) per
+        # declared name, resident like the pages and donated with them
         rec_shapes = {
-            name: ((L, B) + tuple(shape), jnp.dtype(rdt))
+            name: ((self._rec_layers, B) + tuple(shape), jnp.dtype(rdt))
             for name, (shape, rdt) in slot_state["recurrent"].items()}
         self._rec_bytes = sum(int(np.prod(shape)) * rdt.itemsize
                               for shape, rdt in rec_shapes.values())
@@ -841,6 +869,17 @@ class ServingEngine:
             self._ks = self._vs = None
         self._rec = {name: jnp.zeros(shape, rdt)
                      for name, (shape, rdt) in rec_shapes.items()}
+        # the model's own cumulative counters (state_spec()["counters"]:
+        # whole leaves, no slot's): they ride the donated state beside
+        # the recurrent leaves, the program adds to them, and the host
+        # folds them into `_model_totals` when stats are read, never a
+        # tick (`_fold_model_counts`)
+        self._model_counts = {
+            name: jnp.zeros(tuple(shape), jnp.dtype(cdt))
+            for name, (shape, cdt) in
+            (slot_state.get("counters") or {}).items()}
+        self._model_totals = {name: np.zeros(a.shape, np.int64)
+                              for name, a in self._model_counts.items()}
         if self._mesh is not None:
             # the pools LIVE sharded (global shape above, the packed
             # axis split over the mesh in whole-head blocks): every
@@ -1101,6 +1140,7 @@ class ServingEngine:
         """This engine's counters/gauges as a plain dict (a live read of
         the telemetry children — the PR-1 bare-dict keys kept intact)."""
         m = self._metrics
+        self._fold_model_counts()
         return {
             "prefills": int(m["prefills"].value),
             "prefill_tokens": int(m["prefill_tokens"].value),
@@ -1167,6 +1207,11 @@ class ServingEngine:
             "tick_phase_seconds": {ph: c.value for ph, c
                                    in self._tick_children.items()},
             "recurrent_state_bytes": self._rec_bytes,
+            "kv_layers": self._kv_layers,
+            "recurrent_layers": self._rec_layers,
+            "expert_weight_bytes": self._expert_weight_bytes,
+            "model_counters": {name: total.tolist() for name, total
+                               in self._model_totals.items()},
             "state_resets": int(m["state_resets"].value),
             "head_rows": int(m["head_rows"].value),
             "kernel_paths": {f"{kernel}/{path}": int(c.value)
@@ -1197,6 +1242,9 @@ class ServingEngine:
         self._metrics["tp_shards"].set(self._tp)
         self._metrics["weight_quant_enabled"].set(int(self._w8))
         self._metrics["recurrent_state_bytes"].set(self._rec_bytes)
+        self._metrics["kv_layers"].set(self._kv_layers)
+        self._metrics["recurrent_layers"].set(self._rec_layers)
+        self._metrics["expert_weight_bytes"].set(self._expert_weight_bytes)
         for wd, nb in self._weight_bytes.items():
             self._wbytes_fam.labels(self._eid, wd).set(nb)
 
@@ -1211,6 +1259,9 @@ class ServingEngine:
             child.reset()
         self._metrics["num_slots"].set(self.num_slots)
         self._set_static_gauges()
+        self._fold_model_counts()
+        for total in self._model_totals.values():
+            total[...] = 0
         self._shed_counts = {}
         for child in self._tenant_children.values():
             child.reset()
@@ -3207,16 +3258,27 @@ class ServingEngine:
         state = {"k": self._kp, "v": self._vp}
         if self._quant:
             state.update(ks=self._ks, vs=self._vs)
-        if self._rec:
-            state["rec"] = self._rec
+        if self._rec or self._model_counts:
+            state["rec"] = {**self._rec, **self._model_counts}
         return state
 
     def _take_device_state(self, state):
         self._kp, self._vp = state["k"], state["v"]
         if self._quant:
             self._ks, self._vs = state["ks"], state["vs"]
-        if self._rec:
-            self._rec = state["rec"]
+        rec = state.get("rec")
+        if rec is not None:
+            self._rec = {k: rec[k] for k in self._rec}
+            self._model_counts = {k: rec[k] for k in self._model_counts}
+
+    def _fold_model_counts(self):
+        """The model's device counters added to the host's totals and
+        zeroed: when stats are read, and every `_FOLD_EVERY` dispatches so
+        that an int32 counter that grows by less than 2**31 / _FOLD_EVERY
+        a dispatch never wraps."""
+        for name, dev in self._model_counts.items():
+            self._model_totals[name] += np.asarray(dev)
+            self._model_counts[name] = jnp.zeros_like(dev)
 
     def _count_kernel_paths(self, before):
         """serving_kernel_path_total and serving_kernel_tile_total: which
@@ -3276,7 +3338,7 @@ class ServingEngine:
         spec = self.speculative
         S = self.spec_tokens
         quant = self._quant
-        recurrent = bool(self._rec)
+        recurrent = bool(self._rec or self._model_counts)
         tp = self._tp
         # w8: positions whose param_arrays entry is an int8 code array;
         # the per-out-tile dequant scales arrive as the operands right
@@ -3602,6 +3664,9 @@ class ServingEngine:
         with self._tick_span("fanout"):
             m = self._metrics
             m["decode_dispatches"].inc()
+            if self._model_counts and \
+                    not int(m["decode_dispatches"].value) % _FOLD_EVERY:
+                self._fold_model_counts()
             m["decode_steps"].inc()
             m["head_rows"].inc(B * S)
             n_chunks = int((chunk_len > 0).sum())

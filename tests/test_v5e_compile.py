@@ -191,3 +191,77 @@ def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
     # the allocator's limit on the chip is 16.91e9 bytes (PERF.md)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
         < 16.91e9
+
+
+def test_nemotron_h_unified_program_holds_state_by_layer_kind(one_chip):
+    """nemotron3_super_120b.doc_backlog's whole unified greedy program at
+    the cell's own engine block and model kwargs, weights described: pages
+    for the ONE attention layer, recurrent leaves for the FIVE mixers, a
+    row of counters for each of the five expert layers, all one donated
+    pytree aliased to the outputs; eleven Mosaic calls (five chunk updates
+    at head size 64, five grouped expert feed-forwards, one span kernel at
+    16 query heads a KV head), none refused by the chip's compiler; and it
+    leaves the 1 GB spare that `assumed.num_slots` asks for. Prints the
+    arguments and temporaries that text cites."""
+    import json
+    from mxnet_tpu import models
+    from mxnet_tpu.serving import ServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "nemotron3_super_120b.json")) as f:
+        cfg = json.load(f)
+    kw, ekw = cfg["model"]["kwargs"], cfg["engine"]
+    net = models.NemotronHForCausalLM(
+        models.nemotron3_super_120b_config(**kw))
+    for p in net.collect_params().values():
+        p._data = _described(p.shape, kw["dtype"])
+    eng = ServingEngine(net, attn_impl="pallas", **ekw)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=one_chip)
+    slots, width = ekw["num_slots"], ekw["chunk_tokens"]
+    row = lambda dtype: jax.ShapeDtypeStruct((slots,), jnp.dtype(dtype),
+                                             sharding=one_chip)
+    state = eng._device_state()
+    pages = slots * ekw["max_length"] // ekw["page_size"]
+    assert state["k"].shape == (1, pages, 64, 2 * 128)
+    assert {k: v.shape for k, v in state["rec"].items()} == {
+        "conv": (5, slots, 3, 8192 + 2 * 8 * 128),
+        "ssm": (5, slots, 128, 64, 128), "moe": (5, 5)}
+    st = eng.stats
+    assert st["recurrent_state_bytes"] == 5 * slots * (
+        128 * 64 * 128 * 4 + 3 * 10240 * 2)
+    assert st["kv_page_bytes"] == 2 * 1 * 64 * 256 * 2
+    assert st["expert_weight_bytes"] == 5 * 128 * 2 * 1024 * 2688 * 2
+    before = (dict(kernel_paths.PATHS), dict(kernel_paths.TILES))
+    compiled = eng._build_unified(greedy_only=True).lower(
+        tuple(sds(p.data()._data) for p in eng._params),
+        jax.tree_util.tree_map(sds, state), sds(eng._dstate[-1]),
+        sds(eng._d_lock), *[sds(a) for a in eng._dstate[:11]],
+        jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip),
+        row("int32"), row("bool"), row("bool")).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 11
+    since = lambda now, was: {k: n - was.get(k, 0) for k, n in now.items()
+                              if n != was.get(k, 0)}
+    assert since(kernel_paths.PATHS, before[0]) == {
+        ("ssd_chunk_update", "pallas"): 5, ("expert_ffn", "pallas"): 5,
+        ("ragged_span_attention", "pallas"): 1}
+    # an expert's two matrices whole in a grid step (2 x 5.5 MB, double
+    # buffered): consecutive visits by one expert fetch them once
+    assert since(kernel_paths.TILES, before[1]) == {
+        ("expert_ffn", "rows=128,hidden=2688"): 5,
+        ("ragged_span_attention", "pages=2,keys=128,rows=1024"): 1}
+    assert f"[{slots},{kw['vocab_size']}]" in hlo
+    mem = compiled.memory_analysis()
+    leaves = jax.tree_util.tree_leaves(state)
+    # every leaf aliased; the 100 bytes of counters pad to a 4 KiB tile
+    assert 0 <= mem.alias_size_in_bytes - sum(a.nbytes for a in leaves) \
+        < 4096
+    print(f"nemotron3_super_120b unified greedy program, {slots} slots, "
+          f"{len(kw['pattern'])} layers: "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB aliased")
+    assert mem.argument_size_in_bytes > 10e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
+        < 16.91e9
