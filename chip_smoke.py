@@ -529,6 +529,86 @@ def _kernel_ssd():
     return out
 
 
+def _kernel_kda():
+    """kda_chunk_update at Kimi Linear's mixer: heads of 128 keys by 128
+    values, 64 rows (a prefill chunk, a decode row, a dead slot, a fresh
+    slot), one head at the published initialisation's strongest decay (the
+    running log-decay falls by ~100 over the chunk), against the dense
+    form; the pool's other layer and the dead slot's state must come back
+    untouched."""
+    from mxnet_tpu.ops.kda import kda_chunk_update
+    rng = np.random.default_rng(SEED + 3)
+    B, W, H, D = 8, 64, 8, 128
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q = jnp.asarray(unit(rng.standard_normal((B, W, H, D))) * D ** -0.5,
+                    jnp.bfloat16)
+    k = jnp.asarray(unit(rng.standard_normal((B, W, H, D))), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((B, W, H, D)), jnp.bfloat16)
+    g = -rng.uniform(1, 16, (1, 1, H, 1)) * np.exp(
+        rng.uniform(np.log(1e-3), np.log(0.1), (B, W, H, D)))
+    g[:, :, 0, :64] = -1.7
+    g = jnp.asarray(g, jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (B, W, H)), jnp.float32)
+    state = jnp.asarray(0.5 * rng.standard_normal((2, B, H, D, D)),
+                        jnp.float32)
+    counts = jnp.asarray([W, 1, 0, W // 2, 1, W, 3, 1], jnp.int32)
+    fresh = jnp.asarray([1, 0, 0, 0, 1, 0, 0, 0], bool)
+    call = lambda impl: jax.jit(lambda q, k, v, g, beta, st: kda_chunk_update(
+        q, k, v, g, beta, st, counts, 1, impl=impl, fresh=fresh))
+    check(MOSAIC in call("auto").lower(q, k, v, g, beta, state).as_text(),
+          "kda: impl='auto' took the dense path")
+    want_o, want_s = call("xla")(q, k, v, g, beta, state)
+    o, new = call("auto")(q, k, v, g, beta, state)
+    out = {"rows": _close(o, want_o, 1e-2, "kda rows"),
+           "state": _close(new, want_s, 1e-2, "kda state")}
+    check(bool(jnp.all(new[0] == state[0])),
+          "kda: the layer not asked for changed")
+    check(bool(jnp.all(new[1, 2] == state[1, 2])),
+          "kda: a slot with no live row changed its state")
+    return out
+
+
+def _kernel_latent():
+    """latent_span_attention and the one-pool page write at Kimi Linear's
+    latent layer: 32 query heads against ONE row of 640 (512 latent + 64
+    shared + padding) a token, values the row's leading 512 columns; a
+    64-row chunk across a page boundary, a decode row, an idle slot;
+    layer 1 of 2, against the dense form and the row scatter."""
+    from mxnet_tpu.models import PagedKVCache
+    rng = np.random.default_rng(SEED + 4)
+    B, Sq, H, Wd, Vw, S, P = 4, 64, 32, 640, 512, 64, 12
+    N = B * P
+    table = jnp.asarray(rng.permutation(N).reshape(B, P), jnp.int32)
+    pool = jnp.asarray(0.3 * rng.standard_normal((2, N, S, Wd)),
+                       jnp.bfloat16)
+    rows = jnp.asarray(0.3 * rng.standard_normal((B, 1, Sq, Wd)),
+                       jnp.bfloat16)
+    q = jnp.asarray(0.3 * rng.standard_normal((B, Sq, H, Wd)), jnp.bfloat16)
+    lengths = jnp.asarray([300, 0, 700, 100], jnp.int32)
+    spans = jnp.asarray([Sq, 5, 0, 1], jnp.int32)
+
+    def attend(impl):
+        def fn(pool, rows, q):
+            cache = PagedKVCache(pool, None, table, lengths, spans=spans,
+                                 attn_impl=impl).write_decode(1, rows, None)
+            return cache.k_pages, pa.latent_span_attention(
+                q, cache.k_pages, table, lengths + 1, spans,
+                value_width=Vw, scale=192 ** -0.5, impl=impl, layer=1)
+        return jax.jit(fn)
+
+    check(attend("auto").lower(pool, rows, q).as_text().count(MOSAIC) == 2,
+          "latent: impl='auto' left a kernel on its dense path")
+    (got_pool, got), (want_pool, want) = (attend(impl)(pool, rows, q)
+                                          for impl in ("auto", "xla"))
+    check(bool((np.asarray(got_pool).view(np.uint8)
+                == np.asarray(want_pool).view(np.uint8)).all()),
+          "latent: the one-pool page write left other bytes than the "
+          "scatter")
+    check(not np.asarray(got[2].astype(jnp.float32)).any(),
+          "latent: an idle slot's rows are not zero")
+    return {"rows": _close(got, want, 2e-2, "latent span attention")}
+
+
 def _kernel_flash():
     """flash_attention_data at T=8192: jax's Pallas kernel (not the
     lax.scan) must have produced it, and it must match dense attention."""
@@ -553,6 +633,7 @@ def phase_kernels():
     return {"fused": _kernel_fused(), "span": _kernel_span(),
             "page_write": _kernel_page_write(),
             "span_gqa": _kernel_span_gqa(), "ssd": _kernel_ssd(),
+            "kda": _kernel_kda(), "latent": _kernel_latent(),
             "flash": _kernel_flash()}
 
 

@@ -3,4 +3,4 @@ from ..block import Block, HybridBlock, SymbolBlock  # noqa: F401
 from .activations import *  # noqa: F401,F403
 from .basic_layers import *  # noqa: F401,F403
 from .conv_layers import *  # noqa: F401,F403
-from .moe import DroplessMoE, MoEFFN  # noqa: F401
+from .moe import MOE_COUNTERS, DroplessMoE, MoEFFN  # noqa: F401

@@ -28,7 +28,12 @@ from ..block import HybridBlock
 from ..parameter import Parameter
 from .basic_layers import Dense
 
-__all__ = ["MoEFFN", "DroplessMoE"]
+__all__ = ["MoEFFN", "DroplessMoE", "MOE_COUNTERS"]
+
+# what DroplessMoE.forward counts a call (parallel.moe.dropless_moe), in
+# the order of its `counts`; a model keeps them cumulatively, a row a layer
+MOE_COUNTERS = ("dispatches", "rows", "pairs", "experts_touched",
+                "largest_group")
 
 _ACTS = {"gelu": jax.nn.gelu, "relu": jax.nn.relu, "silu": jax.nn.silu,
          "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True)}
@@ -99,7 +104,9 @@ class DroplessMoE(HybridBlock):
     `gate_bias` is added for the choice only, DeepSeek-V3's correction
     bias), the `top_k` best are chosen, and their weights are renormalised
     over ALL the chosen (held or not) to sum to `scale`. Expert e is
-    relu(x W1[e])^2 W2[e], no bias. With `held=(first, count)` only
+    act(x W1[e]) W2[e], no bias: `activation` "relu2" (relu(.)^2) or
+    "swiglu" (silu(x Wg) * (x Wu), `expert_w1` then holds [Wg | Wu], twice
+    `hidden_size` wide). With `held=(first, count)` only
     experts first .. first + count - 1 live here: a row's other pairs are
     somebody else's, and the output is this holder's share of the sum.
 
@@ -109,8 +116,12 @@ class DroplessMoE(HybridBlock):
     experts its projection); `counts` as parallel.moe.dropless_moe."""
 
     def __init__(self, units, hidden_size, num_experts, top_k, held=None,
-                 router_units=None, scale=1.0, **kwargs):
+                 router_units=None, scale=1.0, activation="relu2", **kwargs):
         super().__init__(**kwargs)
+        from ...ops.moe import ACTIVATIONS
+        if activation not in ACTIVATIONS:
+            raise MXNetError(f"unsupported expert activation {activation!r}:"
+                             f" {sorted(ACTIVATIONS)}")
         first, count = held if held is not None else (0, num_experts)
         if top_k > num_experts:
             raise MXNetError(f"top_k {top_k} > num_experts {num_experts}")
@@ -118,12 +129,14 @@ class DroplessMoE(HybridBlock):
             raise MXNetError(f"held experts {first}..{first + count - 1} "
                              f"are not among the {num_experts}")
         self._top_k, self._first, self._scale = top_k, first, scale
+        self._activation = activation
         self.gate = Dense(num_experts, flatten=False, use_bias=False,
                           in_units=router_units or units)
         self.gate_bias = Parameter("gate_bias", shape=(num_experts,),
                                    init="zeros")
-        self.expert_w1 = Parameter("expert_w1",
-                                   shape=(count, units, hidden_size))
+        self.expert_w1 = Parameter(
+            "expert_w1",
+            shape=(count, units, ACTIVATIONS[activation][1] * hidden_size))
         self.expert_w2 = Parameter("expert_w2",
                                    shape=(count, hidden_size, units))
 
@@ -146,4 +159,4 @@ class DroplessMoE(HybridBlock):
         return dropless_moe(
             x, weights, experts, live, self.expert_w1.data()._data,
             self.expert_w2.data()._data, first=self._first, impl=impl,
-            interpret=interpret)
+            interpret=interpret, activation=self._activation)

@@ -20,6 +20,8 @@ from .falcon_h1 import (  # noqa: F401,E402
     FalconH1Config, FalconH1ForCausalLM, falcon_h1_34b_config)
 from .nemotron_h import (  # noqa: F401,E402
     NemotronHConfig, NemotronHForCausalLM, nemotron3_super_120b_config)
+from .kimi_linear import (  # noqa: F401,E402
+    KimiLinearConfig, KimiLinearForCausalLM, kimi_linear_48b_config)
 from .kv_cache import KVCache, PagedKVCache  # noqa: F401,E402
 from .nmt import NMTConfig, TransformerNMT, nmt_base_config  # noqa: F401,E402
 from . import vision  # noqa: F401,E402

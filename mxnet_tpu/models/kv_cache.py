@@ -167,6 +167,14 @@ class PagedKVCache:
     The head count comes back from the (B, H, t, D) operand of each
     write wherever a method needs (H, D) again.
 
+    ONE pool (`create(row_width=...)`, `v_pages` None): a layer whose
+    token keeps a single row with no head axis (latent attention: the
+    value is a column slice of the row the scores read). `write_decode`
+    takes the rows as (B, 1, t, row_width) and no values, the page write
+    and the scatter carry one pool, and the lockstep views (`write`,
+    `write_prompt`), int8 pages and the head split of tp serving do not
+    apply: there is no head to scale or split by.
+
     QUANTIZED page mode (``kv_dtype="int8"``): pools are stored int8
     with per-page-per-head f32 scale leaves ``k_scale``/``v_scale`` of
     shape (L, num_pages, H) riding in the pytree. Writes quantize
@@ -229,10 +237,16 @@ class PagedKVCache:
     def create(cls, num_layers, batch, num_heads, max_length, head_dim,
                dtype=jnp.float32, page_size=64, num_pages=None,
                page_table=None, lengths=None, attn_impl="auto",
-               kv_dtype=None, num_kv_heads=None, recurrent=None):
+               kv_dtype=None, num_kv_heads=None, recurrent=None,
+               row_width=None):
         """`num_heads` query heads over `num_kv_heads` KV heads (None:
-        as many): the pools hold the KV heads only."""
+        as many): the pools hold the KV heads only. With `row_width`,
+        ONE pool of rows that wide and no V pool (no head axis:
+        `num_heads` and `head_dim` are not read)."""
         num_heads = int(num_kv_heads or num_heads)
+        if row_width is not None and kv_dtype is not None:
+            raise MXNetError("kv_dtype needs a head axis to scale by: a "
+                             "pool of whole rows has none")
         if max_length % page_size:
             raise MXNetError(
                 f"max_length {max_length} not a multiple of page_size "
@@ -263,7 +277,8 @@ class PagedKVCache:
             raise MXNetError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
         store = jnp.int8 if kv_dtype is not None else dtype
-        shape = (num_layers, num_pages, page_size, num_heads * head_dim)
+        shape = (num_layers, num_pages, page_size,
+                 int(row_width or num_heads * head_dim))
         length = jnp.zeros((), jnp.int32) if lengths is None \
             else jnp.asarray(lengths, jnp.int32)
         scales = (None, None)
@@ -271,7 +286,8 @@ class PagedKVCache:
             sshape = (num_layers, num_pages, num_heads)
             scales = (jnp.zeros(sshape, jnp.float32),
                       jnp.zeros(sshape, jnp.float32))
-        return cls(jnp.zeros(shape, store), jnp.zeros(shape, store),
+        return cls(jnp.zeros(shape, store),
+                   None if row_width is not None else jnp.zeros(shape, store),
                    jnp.asarray(page_table, jnp.int32), length,
                    k_scale=scales[0], v_scale=scales[1],
                    attn_impl=attn_impl, recurrent=recurrent)
@@ -345,6 +361,10 @@ class PagedKVCache:
         Quantized caches route through the write_decode scatter (which
         owns the scale bookkeeping) and return DEQUANTIZED f32 views."""
         B, H = k_new.shape[:2]
+        if self.v_pages is None:
+            raise MXNetError("a one-pool cache has no gathered (H, D) "
+                             "view: its rows are read by "
+                             "latent_span_attention")
         if self.quantized:
             new = self.write_decode(layer, k_new, v_new)
             return (new._gather(new.k_pages, layer, H, new.k_scale),
@@ -408,8 +428,14 @@ class PagedKVCache:
         write there.
         Rejected speculative drafts rely on the same discipline: their
         KV stays behind `length`, invisible to attention, and the next
-        accepted write overwrites it in place."""
+        accepted write overwrites it in place.
+        A one-pool cache takes its rows as k_new (B, 1, t, row_width) and
+        v_new None."""
         B, _, t, _ = k_new.shape
+        one = self.v_pages is None
+        if one != (v_new is None):
+            raise MXNetError("a one-pool cache is written rows and no "
+                             "values; a K/V cache both")
         S = self.page_size
         P = self.page_table.shape[1]
         impl = self._page_write_impl(t)
@@ -421,7 +447,8 @@ class PagedKVCache:
             return self._with_pages(*kv_page_write(
                 self.k_pages, self.v_pages,
                 k_new.transpose(0, 2, 1, 3).reshape(B, t, -1),
-                v_new.transpose(0, 2, 1, 3).reshape(B, t, -1),
+                None if one
+                else v_new.transpose(0, 2, 1, 3).reshape(B, t, -1),
                 layer, self.page_table, self.length, self.spans,
                 self.page_lock, interpret=impl == "pallas_interpret"))
         length = self.length if self.ragged \
@@ -446,6 +473,10 @@ class PagedKVCache:
                 & (pages < num_pages)
             pages = jnp.where(locked, num_pages, pages)
         k_t = k_new.transpose(0, 2, 1, 3)             # (B, t, H, D)
+        if one:
+            return self._with_pages(self.k_pages.at[layer, pages, slot].set(
+                k_t.reshape(B, t, -1).astype(self.k_pages.dtype),
+                mode="drop"), None)
         v_t = v_new.transpose(0, 2, 1, 3)
         if self.quantized:
             qk, sk = self._quant_encode(k_t, pages, page_idx,

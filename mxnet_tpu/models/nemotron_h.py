@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Dense, DroplessMoE, Embedding
+from ..gluon.nn import MOE_COUNTERS, Dense, DroplessMoE, Embedding
 from ..ndarray.ndarray import NDArray
 from .hybrid import (Attention, Mixer, RMSNorm, kernel_impl, linear, raw,
                      require_recurrent_cache, rms_norm)
@@ -38,10 +38,6 @@ from .kv_cache import PagedKVCache
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM",
            "nemotron3_super_120b_config"]
-
-# what an expert layer counts, cumulatively (parallel.moe.dropless_moe)
-MOE_COUNTERS = ("dispatches", "rows", "pairs", "experts_touched",
-                "largest_group")
 
 
 class NemotronHConfig:

@@ -865,6 +865,236 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
 
 
 # ---------------------------------------------------------------------------
+# latent_span_attention: the span kernel for a cache row with no head axis.
+#
+# A latent attention layer (multi-head latent attention in its absorbed
+# form) keeps ONE row a token, shared by every head: the query heads carry
+# the up-projection, scores come from the whole row and values are the row's
+# leading `value_width` columns. That is grouped-query attention with one KV
+# head whose value is a column slice of its key: the H query heads' rows are
+# stacked along the kernel's row axis (row g*Sq + j is head g at position j)
+# against the one page, a page is read ONCE for scores and values, and there
+# is no V pool. The grid, the block table and the causal offset are the span
+# kernel's. The stacked rows (H*Sq: 2048 at 32 heads of a 64-row dispatch)
+# are walked in tiles of _LATENT_ROW_TILE, so that a step's scores are
+# (tile, keys) and not (H*Sq, keys); at that many rows a grid step's
+# products outweigh its fixed cost several times over at any block of keys
+# (4.7 MFLOP a key at 576 + 512 columns), and the block is chosen by what
+# the chip measured at 32 slots of 64 rows x 32 heads and a mean context of
+# 8.4k rows (my chip run, PR 34): 256 keys a step 14.40 ms a call, 512
+# 10.37, 1024 9.58, 2048 9.69.
+# ---------------------------------------------------------------------------
+
+_LATENT_ROW_TILE = 256
+_LATENT_BLOCK_KEYS = 1024
+
+
+def _latent_unsupported_reason(q, pages, value_width):
+    Sq, Wd = q.shape[1], q.shape[-1]
+    S = pages.shape[2]
+    if Wd % 128 or value_width % 128:
+        return (f"row width {Wd} and value width {value_width} must be "
+                "multiples of 128 lanes (pad the stored row with zeros)")
+    if S % 8 or Sq % 8:
+        return (f"page size {S} and {Sq} rows break the sublane rule "
+                "(multiples of 8)")
+    if q.dtype not in (jnp.float32, jnp.bfloat16) or pages.dtype != q.dtype:
+        return f"query {q.dtype} over {pages.dtype} pages"
+    return None
+
+
+def _latent_row_heads(H, Sq):
+    """Heads a row tile holds: whole heads, as many as keep the tile within
+    _LATENT_ROW_TILE rows and divide H."""
+    heads = max(1, min(H, _LATENT_ROW_TILE // Sq))
+    while H % heads:
+        heads -= 1
+    return heads
+
+
+def _latent_span_kernel(pages_ref, len_ref, qc_ref, q_ref, *refs, scale, S,
+                        Sq, H, Vw, KB, TH):
+    """One (slot, block of KB pages): every stacked query row against the
+    block's rows, a tile of TH heads (TH*Sq rows) at a time. refs: the KB
+    pages, out, and the running max, denominator and numerator."""
+    page_refs, (o_ref, m_ref, l_ref, acc_ref) = refs[:KB], refs[KB:]
+    del pages_ref                               # the index maps read it
+    b = pl.program_id(0)
+    p = pl.program_id(1)
+    length = len_ref[b]
+    qn = qc_ref[b]
+    n_live = _span_live_pages(length, qn, S)
+    TR = TH * Sq
+
+    def each_tile(body):
+        if H == TH:
+            body(pl.ds(0, TR))
+        else:
+            lax.fori_loop(0, H // TH, lambda r, _: body(
+                pl.ds(pl.multiple_of(r * TR, TR), TR)), None)
+
+    @pl.when(p == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(p * KB < n_live)
+    def _accumulate():
+        parts = [r[0] for r in page_refs]
+        ks = parts[0] if KB == 1 else jnp.concatenate(parts, axis=0)
+        vs = ks[:, :Vw]                                     # (KB*S, Vw)
+        # a row's query position is its index within its own head's stack
+        pos = lax.broadcasted_iota(jnp.int32, (TR, 1), 0)
+        for _ in range(1, TH):
+            pos = pos - jnp.where(pos >= Sq, Sq, 0)
+        cols = p * (KB * S) \
+            + lax.broadcasted_iota(jnp.int32, (1, KB * S), 1)
+        # a page of the block past the live extent holds another page's
+        # rows: its positions are >= length + qn - 1, so the mask drops them
+        valid = (cols < length + pos) & (pos < qn)          # (TR, KB*S)
+
+        def attend(rows):
+            s = lax.dot_general(q_ref[0, rows, :], ks,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s * scale, NEG_INF)
+            m_prev = m_ref[rows, :1]
+            l_prev = l_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+            alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
+                              jnp.exp(m_prev - m_new))
+            pv = lax.dot_general(e.astype(vs.dtype), vs,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
+            l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
+            l_ref[rows, :] = jnp.broadcast_to(l_new, (TR, l_ref.shape[1]))
+            m_ref[rows, :] = jnp.broadcast_to(m_new, (TR, m_ref.shape[1]))
+
+        each_tile(attend)
+
+    @pl.when(p == pl.num_programs(1) - 1)
+    def _emit():
+        def emit(rows):
+            o_ref[0, rows, :] = (
+                acc_ref[rows, :] / jnp.maximum(l_ref[rows, :1], 1e-30)
+            ).astype(o_ref.dtype)
+
+        each_tile(emit)
+
+
+def _latent_span_reference(q, pages, page_table, lengths, q_counts,
+                           value_width, scale, layer):
+    """Dense oracle and fallback: the slot's pages gathered whole, scores
+    from the whole row, values from its leading columns; query j of slot b
+    attends positions < lengths[b] + j, rows at or past q_counts[b] emit
+    exact zeros."""
+    B, Sq = q.shape[:2]
+    P, S = page_table.shape[1], pages.shape[2]
+    rows = jnp.take(pages[layer], page_table, axis=0) \
+        .reshape(B, P * S, -1).astype(jnp.float32)
+    s = jnp.einsum("bjhw,btw->bjht", q.astype(jnp.float32), rows) * scale
+    limit = lengths[:, None] + jnp.arange(Sq)[None, :]
+    mask = (jnp.arange(P * S)[None, None, :] < limit[:, :, None]) \
+        & (jnp.arange(Sq)[None, :] < q_counts[:, None])[:, :, None]
+    s = jnp.where(mask[:, :, None, :], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(s - m))
+    w = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bjht,btw->bjhw", w,
+                      rows[..., :value_width]).astype(q.dtype)
+
+
+def latent_span_attention(q, pages, page_table, lengths, q_counts=None, *,
+                          value_width, scale, impl="auto", interpret=False,
+                          layer=0):
+    """Span attention over a page pool with ONE row a token and no head
+    axis, for every kind of work a slot can carry (ragged_span_attention
+    has the contract of lengths, q_counts and the dead rows).
+
+    q:           (B, Sq, H, Wd) the heads' queries against the stored row
+                 (latent attention absorbed: [q_nope W_uk | q_rope], zero
+                 in the row's padding), already written at positions
+                 lengths-1 .. lengths+q_counts-2.
+    pages:       (L, num_pages, S, Wd), the WHOLE pool; `layer` (a static
+                 int) picks the layer in the page BlockSpec. A row's
+                 leading `value_width` columns are its value.
+    scale:       the scores' scale (the unabsorbed head size's, not Wd's).
+    Returns (B, Sq, H, value_width) in q's dtype: the weighted sum of the
+    rows' values, before the heads' own up-projection.
+    """
+    B, Sq, H, Wd = q.shape
+    S, P = pages.shape[2], page_table.shape[1]
+    if pages.shape[3] != Wd or value_width > Wd:
+        raise ValueError(f"queries {Wd} wide and values {value_width} wide "
+                         f"over pages {pages.shape[3]} wide")
+    if q_counts is None:
+        q_counts = jnp.full((B,), Sq, jnp.int32)
+    if impl == "auto":
+        impl = "pallas" if interpret else "xla"
+        if not interpret and jax.default_backend() == "tpu":
+            why = _latent_unsupported_reason(q, pages, value_width)
+            if why is None:
+                impl = "pallas"
+            else:
+                warnings.warn(
+                    "latent_span_attention: impl='auto' on TPU is using the "
+                    f"dense form instead of the Pallas kernel because {why}",
+                    stacklevel=2)
+    note_path("latent_span_attention", impl)
+    lengths = lengths.astype(jnp.int32)
+    q_counts = q_counts.astype(jnp.int32)
+    if impl == "xla":
+        return _latent_span_reference(q, pages, page_table, lengths,
+                                      q_counts, value_width, float(scale),
+                                      layer)
+    if impl != "pallas":
+        raise ValueError(f"unknown latent attention impl {impl!r}")
+    Sr = H * Sq
+    TH = _latent_row_heads(H, Sq)
+    KB = 1
+    while 2 * KB * S <= _LATENT_BLOCK_KEYS and 2 * KB <= P:
+        KB *= 2
+    note_tile("latent_span_attention", pages=KB, keys=KB * S, rows=Sr,
+              tile=TH * Sq)
+    qp = q.transpose(0, 2, 1, 3).reshape(B, Sr, Wd)
+    table = _span_block_table(page_table.astype(jnp.int32), lengths,
+                              q_counts, S, KB)
+
+    def page_index(i):
+        return lambda b, p, table, *_: (layer, table[b, p * KB + i], 0, 0)
+
+    def q_index(b, p, *_prefetched):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, pl.cdiv(P, KB)),
+        in_specs=[pl.BlockSpec((1, Sr, Wd), q_index)]
+        + [pl.BlockSpec((None, 1, S, Wd), page_index(i)) for i in range(KB)],
+        out_specs=pl.BlockSpec((1, Sr, value_width), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((Sr, 128), jnp.float32),          # running max
+            pltpu.VMEM((Sr, 128), jnp.float32),          # running denominator
+            pltpu.VMEM((Sr, value_width), jnp.float32),  # running numerator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_span_kernel, scale=float(scale), S=S,
+                          Sq=Sq, H=H, Vw=value_width, KB=KB, TH=TH),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sr, value_width), q.dtype),
+        interpret=interpret,
+        name="latent_span_attention",
+        compiler_params=_compiler_params(
+            interpret, dimension_semantics=("parallel", "arbitrary")),
+    )(table, lengths, q_counts, qp, *([pages] * KB))
+    return out.reshape(B, H, Sq, value_width).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
 # kv_page_write: a dispatch's new K/V rows reach the pools a page at a time.
 #
 # Slot b writes t <= S consecutive positions from lengths[b], the first
@@ -930,11 +1160,13 @@ def _page_write_steps(page_table, lengths, q_counts, page_lock, t, S):
 
 
 def _kv_page_write_kernel(layer_ref, pages_ref, off_ref, cnt_ref, work_ref,
-                          kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref):
-    """Step (slot b, page p): the K page and the V page with rows
-    [start, start + count) replaced, start the slot's offset in its first
-    page and 0 in its second."""
+                          *refs):
+    """Step (slot b, page p): each pool's page with rows [start, start +
+    count) replaced, start the slot's offset in its first page and 0 in its
+    second. refs: the pools' blocks of new rows, their pages, their pages
+    out (K and V; one pool where a token's row is one)."""
     del layer_ref, pages_ref                     # the index maps read them
+    n = len(refs) // 3
     b = pl.program_id(0)
     p = pl.program_id(1)
     step = 2 * b + p
@@ -943,10 +1175,10 @@ def _kv_page_write_kernel(layer_ref, pages_ref, off_ref, cnt_ref, work_ref,
     def _write():
         off = off_ref[b]
         start = jnp.where(p == 0, off, 0)
-        row = lax.broadcasted_iota(jnp.int32, k_ref.shape[1:], 0)
+        row = lax.broadcasted_iota(jnp.int32, refs[n].shape[1:], 0)
         mine = (row >= start) & (row < start + cnt_ref[step])
-        for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
-                                          (vn_ref, v_ref, vo_ref)):
+        for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                             refs[2 * n:]):
             new = pltpu.roll(new_ref[0].astype(jnp.float32), off, 0)
             out_ref[0] = jnp.where(
                 mine, new, old_ref[0].astype(jnp.float32)
@@ -960,7 +1192,9 @@ def kv_page_write(k_pages, v_pages, k_rows, v_rows, layer, page_table,
     at a time, in place.
 
     k_pages/v_pages: (L, num_pages, S, H_kv*D), the WHOLE pools, aliased
-                     to the results.
+                     to the results. v_pages and v_rows None: ONE pool,
+                     whose row is all a token keeps (a latent attention
+                     layer's); the same kernel with one page a step.
     k_rows/v_rows:   (B, t, H_kv*D), t <= S, packed as the pool's rows
                      (any float type: cast to the pool's here).
     layer:           int32 scalar, traced: a model's layers share this
@@ -969,7 +1203,8 @@ def kv_page_write(k_pages, v_pages, k_rows, v_rows, layer, page_table,
                      lands at position lengths[b] + j.
     q_counts:        (B,) int32 live rows a slot (None: all t);
     page_lock:       (num_pages,) bool, pages no write may touch.
-    Returns the updated (k_pages, v_pages): byte for byte what
+    Returns the updated (k_pages, v_pages), v_pages None as it came: byte
+    for byte what
     `pool.at[layer, pages, rows].set(..., mode="drop")` leaves.
     PagedKVCache._page_write_impl says which calls come here: on the chip
     a packed row of whole 128-lane tiles and a page of whole sublane
@@ -1003,20 +1238,22 @@ def kv_page_write(k_pages, v_pages, k_rows, v_rows, layer, page_table,
 
     page_spec = pl.BlockSpec((None, 1, S, HD), page_index)
     rows_spec = pl.BlockSpec((1, S, HD), rows_index)
+    n = 1 if v_pages is None else 2
+    pools, new = [k_pages, v_pages][:n], [k_rows, v_rows][:n]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, 2),
-        in_specs=[rows_spec, rows_spec, page_spec, page_spec],
-        out_specs=[page_spec, page_spec],
+        in_specs=[rows_spec] * n + [page_spec] * n,
+        out_specs=[page_spec] * n,
     )
     pool = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kv_page_write_kernel,
         grid_spec=grid_spec,
-        out_shape=[pool, pool],
-        # operands 7 and 8 (after the five prefetched scalars and the two
-        # blocks of new rows) are the pools
-        input_output_aliases={7: 0, 8: 1},
+        out_shape=[pool] * n,
+        # the pools follow the five prefetched scalars and the blocks of
+        # new rows: operands 7 and 8 of K and V
+        input_output_aliases={5 + n + i: i for i in range(n)},
         interpret=interpret,
         name="kv_page_write",
         # a run of equal block indices crosses slots: no step is
@@ -1028,7 +1265,8 @@ def kv_page_write(k_pages, v_pages, k_rows, v_rows, layer, page_table,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(jnp.asarray(layer, jnp.int32).reshape(1), pages, offsets, counts, work,
-      rows(k_rows), rows(v_rows), k_pages, v_pages)
+      *map(rows, new), *pools)
+    return (out[0], None) if v_pages is None else tuple(out)
 
 
 def _ragged_reference(q, k_pages, v_pages, page_table, lengths, scale):

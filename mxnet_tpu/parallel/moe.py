@@ -118,7 +118,7 @@ def moe_dispatch_combine(x, gate_logits, w1, b1, w2, b2, top_k=2,
 
 
 def dropless_moe(x, weights, experts, live, w1, w2, first=0, impl="auto",
-                 interpret=False):
+                 interpret=False, activation="relu2"):
     """The dropless form on flat rows, for the experts this chip holds.
 
     x:        (R, D) rows.
@@ -126,10 +126,11 @@ def dropless_moe(x, weights, experts, live, w1, w2, first=0, impl="auto",
     experts:  (R, k) int32, from `top_k_weights` over ALL the experts.
     live:     (R,) bool; a dead row (padding of a fixed-shape dispatch)
               costs no expert work and gets zeros.
-    w1, w2:   (G, D, F), (G, F, D): experts first .. first + G - 1, stacked.
+    w1, w2:   (G, D, F), (G, F, D): experts first .. first + G - 1, stacked
+              (w1 (G, D, 2F), gate beside up, under `activation="swiglu"`).
     Pairs whose expert is not held, and every pair of a dead row, are
     dropped BEFORE any expert work; the rest are sorted by expert and go
-    through one grouped feed-forward (ops/moe.expert_ffn, relu(.)^2).
+    through one grouped feed-forward (ops/moe.expert_ffn, `activation`).
 
     Returns (y (R, D) in x's dtype: the weighted sum over the HELD chosen
     experts, a partial sum where first/G cover a share of the experts;
@@ -137,7 +138,7 @@ def dropless_moe(x, weights, experts, live, w1, w2, first=0, impl="auto",
     largest group)."""
     from ..ops.moe import expert_ffn
     R, K = experts.shape
-    G = w1.shape[0]
+    G = w2.shape[0]
     with jax.named_scope("moe.sort"):
         local = experts - first
         held = (local >= 0) & (local < G) & live[:, None]
@@ -150,7 +151,8 @@ def dropless_moe(x, weights, experts, live, w1, w2, first=0, impl="auto",
         rows = jnp.take(x, order // K, axis=0)
         back = jnp.zeros_like(order).at[order].set(at, unique_indices=True)
     with jax.named_scope("moe.experts"):
-        y = expert_ffn(rows, w1, w2, sizes, impl=impl, interpret=interpret)
+        y = expert_ffn(rows, w1, w2, sizes, impl=impl, interpret=interpret,
+                       activation=activation)
         # back to (row, choice); what the kernel never visited is undefined
         y = jnp.take(y, back, axis=0).reshape(R, K, -1)
         y = jnp.sum(jnp.where(held[:, :, None],

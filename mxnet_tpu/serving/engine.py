@@ -323,6 +323,12 @@ def _engine_metrics(eid):
             "device bytes of the slots' recurrent state (fixed-size "
             "per-slot leaves the model declares beside its KV pages; 0 "
             "for a model with pages only)", _E),
+        "kv_pool_bytes": g(
+            "serving_kv_pool_bytes",
+            "device bytes of the page pools as allocated: K and V, or the "
+            "one pool of a model whose page row has no head axis "
+            "(state_spec()['row_width']); int8 scale leaves not counted",
+            _E),
         "kv_layers": g(
             "serving_kv_layers",
             "layers that hold KV pages (state_spec()['kv_layers']; every "
@@ -622,6 +628,24 @@ class ServingEngine:
             if slot_state["recurrent"] else 0
         self._expert_weight_bytes = int(
             slot_state.get("expert_weight_bytes", 0))
+        # a model whose token keeps ONE row with no head axis (latent
+        # attention) says `row_width`: one page pool that wide, no V pool
+        row_width = slot_state.get("row_width")
+        self._one_pool = row_width is not None
+        if self._one_pool:
+            # what splits a page by head, scales it by head or ships it as
+            # a K and a V page has nothing to hold on to
+            # (docs/SERVING.md "A pool with no head axis")
+            for feature, on in (
+                    ("tp", self._tp > 1),
+                    ("kv_dtype", kv_dtype is not None),
+                    ("host_kv_bytes", host_kv_bytes is not None)):
+                if on:
+                    raise MXNetError(
+                        f"{feature} is not supported for a model whose "
+                        f"page row has no head axis (row_width {row_width})"
+                        ": the tp split and the int8 scale leaves are per "
+                        "head, and the spill tier ships a K and a V page")
         if slot_state["recurrent"]:
             # recurrent state is not paged and cannot be shared, copied
             # page by page, split over heads or rebuilt from pages: what
@@ -816,9 +840,13 @@ class ServingEngine:
         store = jnp.dtype(jnp.int8) if self._quant else jnp.dtype(dt)
         # the pools hold the KV heads: fewer than the query heads
         # under grouped-query attention
-        L, H, Dh = (self._kv_layers, slot_state["num_kv_heads"],
-                    slot_state["head_dim"])
-        page_bytes = 2 * L * page_size * H * Dh * store.itemsize
+        L = self._kv_layers
+        if self._one_pool:
+            H, width, pools = 0, int(row_width), 1
+        else:
+            H = slot_state["num_kv_heads"]
+            width, pools = H * slot_state["head_dim"], 2
+        page_bytes = pools * L * page_size * width * store.itemsize
         if self._quant:
             page_bytes += 2 * L * H * 4    # f32 scales ride each page
         self._hbm_budget = None if hbm_budget_bytes is None \
@@ -859,9 +887,11 @@ class ServingEngine:
         # heads packed into the last axis (column h*Dh + d): the layout
         # the span kernel reads, so every dispatch works on the donated
         # pools in place (models/kv_cache.py)
-        pool_shape = (L, total_pages, page_size, H * Dh)
+        pool_shape = (L, total_pages, page_size, width)
         self._kp = jnp.zeros(pool_shape, store)
-        self._vp = jnp.zeros(pool_shape, store)
+        self._vp = None if self._one_pool else jnp.zeros(pool_shape, store)
+        self._kv_pool_bytes = pools * int(np.prod(pool_shape)) \
+            * store.itemsize
         if self._quant:
             self._ks = jnp.zeros((L, total_pages, H), jnp.float32)
             self._vs = jnp.zeros((L, total_pages, H), jnp.float32)
@@ -1044,8 +1074,10 @@ class ServingEngine:
         else:
             def _copy_page(kp, vp, src, dst):
                 # CoW split: clone one physical page's (L, S, H*D) slab
-                return (kp.at[:, dst].set(kp[:, src]),
-                        vp.at[:, dst].set(vp[:, src]))
+                # (vp None for a one-pool model: an empty pytree)
+                return jax.tree_util.tree_map(
+                    lambda pool: pool.at[:, dst].set(pool[:, src]),
+                    (kp, vp))
 
             self._copy_page_fn = jax.jit(_copy_page,
                                          donate_argnums=(0, 1))
@@ -1207,6 +1239,7 @@ class ServingEngine:
             "tick_phase_seconds": {ph: c.value for ph, c
                                    in self._tick_children.items()},
             "recurrent_state_bytes": self._rec_bytes,
+            "kv_pool_bytes": self._kv_pool_bytes,
             "kv_layers": self._kv_layers,
             "recurrent_layers": self._rec_layers,
             "expert_weight_bytes": self._expert_weight_bytes,
@@ -1242,6 +1275,7 @@ class ServingEngine:
         self._metrics["tp_shards"].set(self._tp)
         self._metrics["weight_quant_enabled"].set(int(self._w8))
         self._metrics["recurrent_state_bytes"].set(self._rec_bytes)
+        self._metrics["kv_pool_bytes"].set(self._kv_pool_bytes)
         self._metrics["kv_layers"].set(self._kv_layers)
         self._metrics["recurrent_layers"].set(self._rec_layers)
         self._metrics["expert_weight_bytes"].set(self._expert_weight_bytes)
@@ -1581,7 +1615,7 @@ class ServingEngine:
         Weights are shared arrays (the ledger dedupes them across
         engines); the prefix-cache figure is a Detail — those pages
         live inside the kv_pages slab already counted above."""
-        kv = [self._kp, self._vp]
+        kv = [self._kp] if self._one_pool else [self._kp, self._vp]
         if self._quant:
             kv += [self._ks, self._vs]   # dequant scales live with KV
         # w8: the slab the engine SERVES is int8 codes + dequant scales
@@ -1908,6 +1942,10 @@ class ServingEngine:
                 f"{feature} is not supported for a model that declares "
                 f"recurrent state ({sorted(self._rec)}): a request's "
                 "state beside its KV pages is not exported")
+        if self._one_pool:
+            raise MXNetError(
+                f"{feature} is not supported for a model whose page row "
+                "has no head axis: a payload ships a K and a V page")
 
     @loop_only
     def export_requests(self):
@@ -2425,7 +2463,8 @@ class ServingEngine:
         idx = jnp.asarray(pages, jnp.int32)
         zero = jnp.zeros((), self._kp.dtype)
         self._kp = self._kp.at[:, idx].set(zero)
-        self._vp = self._vp.at[:, idx].set(zero)
+        if not self._one_pool:
+            self._vp = self._vp.at[:, idx].set(zero)
         if self._quant:
             # a poisoned slot may have bumped these pages' scales with
             # NaN/inf absmaxes — scrub them with the codes
@@ -2917,8 +2956,10 @@ class ServingEngine:
         payload cannot land here (geometry/dtype mismatch, page-pool
         pressure): the caller falls back to the replay restart, which
         reaches the same tokens by recomputing."""
-        if self._rec:
-            return False    # a payload carries pages, not recurrent state
+        if self._rec or self._one_pool:
+            # a payload carries a K and a V page, not recurrent state and
+            # not a pool of whole rows
+            return False
         kvp = req.kv_payload
         pages = kvp.get("pages") or []
         length = int(kvp.get("length", -1))
@@ -3255,7 +3296,9 @@ class ServingEngine:
         unified program takes and donates: the page pools, the int8 scale
         pools where pages are quantized, the recurrent state where the
         model declares some."""
-        state = {"k": self._kp, "v": self._vp}
+        state = {"k": self._kp}
+        if not self._one_pool:
+            state["v"] = self._vp
         if self._quant:
             state.update(ks=self._ks, vs=self._vs)
         if self._rec or self._model_counts:
@@ -3263,7 +3306,7 @@ class ServingEngine:
         return state
 
     def _take_device_state(self, state):
-        self._kp, self._vp = state["k"], state["v"]
+        self._kp, self._vp = state["k"], state.get("v")
         if self._quant:
             self._ks, self._vs = state["ks"], state["vs"]
         rec = state.get("rec")
@@ -3389,7 +3432,7 @@ class ServingEngine:
                     qn = jnp.where(prefilling, chunk_len,
                                    jnp.where(active, 1, 0))
                 cache = PagedKVCache(
-                    state["k"], state["v"], table, lengths,
+                    state["k"], state.get("v"), table, lengths,
                     page_lock=lock, spans=qn, k_scale=state.get("ks"),
                     v_scale=state.get("vs"), attn_impl=impl,
                     recurrent=state.get("rec"))
@@ -3491,7 +3534,9 @@ class ServingEngine:
                 _trace_channel.pop_frame()
                 for p, d in zip(params, saved):
                     p._data = d
-            new_state = {"k": cache.k_pages, "v": cache.v_pages}
+            new_state = {"k": cache.k_pages}
+            if cache.v_pages is not None:
+                new_state["v"] = cache.v_pages
             if quant:
                 new_state.update(ks=cache.k_scale, vs=cache.v_scale)
             if recurrent:

@@ -278,3 +278,80 @@ def test_nemotron_h_unified_program_holds_state_by_layer_kind(one_chip):
     assert mem.argument_size_in_bytes > 10e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
         < 16.91e9
+
+
+def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
+    """kimi_linear_48b.longdoc_backlog's whole unified greedy program at
+    the cell's own engine block and model kwargs, weights described: ONE
+    page pool 640 wide for the two latent layers (no V pool), recurrent
+    leaves for the seven KDA layers, a row of counters for each of the
+    eight expert layers, all one donated pytree aliased to the outputs;
+    nineteen Mosaic calls (seven chunk updates of the delta rule, eight
+    gated expert feed-forwards, two one-pool page writes, two latent span
+    kernels at 2048 stacked rows), none refused by the chip's compiler;
+    and it leaves more than the 1 GB spare. Prints the arguments and
+    temporaries that `PERF.md` cites."""
+    import json
+    from mxnet_tpu import models
+    from mxnet_tpu.serving import ServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "kimi_linear_48b.json")) as f:
+        cfg = json.load(f)
+    kw, ekw = cfg["model"]["kwargs"], cfg["engine"]
+    net = models.KimiLinearForCausalLM(models.kimi_linear_48b_config(**kw))
+    for p in net.collect_params().values():
+        p._data = _described(p.shape, kw["dtype"])
+    eng = ServingEngine(net, attn_impl="pallas", **ekw)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=one_chip)
+    slots, width = ekw["num_slots"], ekw["chunk_tokens"]
+    row = lambda dtype: jax.ShapeDtypeStruct((slots,), jnp.dtype(dtype),
+                                             sharding=one_chip)
+    state = eng._device_state()
+    pages = slots * ekw["max_length"] // ekw["page_size"]
+    assert sorted(state) == ["k", "rec"]                # no "v"
+    assert state["k"].shape == (2, pages, 64, 640)
+    assert {k: v.shape for k, v in state["rec"].items()} == {
+        "conv": (7, slots, 3, 3 * 4096), "kda": (7, slots, 32, 128, 128),
+        "moe": (8, 5)}
+    st = eng.stats
+    assert st["recurrent_state_bytes"] == 7 * slots * (
+        32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    assert st["kv_page_bytes"] == 1 * 2 * 64 * 640 * 2    # one pool
+    assert st["kv_pool_bytes"] == 2 * pages * 64 * 640 * 2
+    assert st["expert_weight_bytes"] == 8 * 64 * 3 * 2304 * 1024 * 2
+    before = (dict(kernel_paths.PATHS), dict(kernel_paths.TILES))
+    compiled = eng._build_unified(greedy_only=True).lower(
+        tuple(sds(p.data()._data) for p in eng._params),
+        jax.tree_util.tree_map(sds, state), sds(eng._dstate[-1]),
+        sds(eng._d_lock), *[sds(a) for a in eng._dstate[:11]],
+        jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=one_chip),
+        row("int32"), row("bool"), row("bool")).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 19
+    assert _since(kernel_paths.PATHS, before[0]) == {
+        ("kda_chunk_update", "pallas"): 7, ("expert_ffn", "pallas"): 8,
+        ("kv_page_write", "pallas"): 2,
+        ("latent_span_attention", "pallas"): 2}
+    # an expert's three matrices whole in a grid step (14.2 MB, double
+    # buffered); 1024 keys a grid step of the latent kernel (PERF.md, PR 34)
+    assert _since(kernel_paths.TILES, before[1]) == {
+        ("kda_chunk_update", "heads=16,rows=64,block=16"): 7,
+        ("expert_ffn", "rows=128,hidden=1024"): 8,
+        ("latent_span_attention",
+         "pages=16,keys=1024,rows=2048,tile=256"): 2}
+    assert f"[{slots},{kw['vocab_size']}]" in hlo
+    mem = compiled.memory_analysis()
+    leaves = jax.tree_util.tree_leaves(state)
+    # every leaf aliased; the 160 bytes of counters pad to a 4 KiB tile
+    assert 0 <= mem.alias_size_in_bytes - sum(a.nbytes for a in leaves) \
+        < 4096
+    print(f"kimi_linear_48b unified greedy program, {slots} slots, "
+          f"{len(kw['pattern'])} layers: "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB aliased")
+    assert mem.argument_size_in_bytes > 11e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
+        < 16.91e9
