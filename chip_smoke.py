@@ -535,7 +535,10 @@ def _kernel_kda():
     slot), one head at the published initialisation's strongest decay (the
     running log-decay falls by ~100 over the chunk), against the dense
     form; the pool's other layer and the dead slot's state must come back
-    untouched."""
+    untouched. Then the rows the triangular inverse fears: every 16-row
+    block one repeated unit key, decay weak and beta near one, where the
+    entries of a block's inverse are differences of like terms and HIGHEST
+    is the MXU's six bfloat16 passes, not a CPU's float32."""
     from mxnet_tpu.ops.kda import kda_chunk_update
     rng = np.random.default_rng(SEED + 3)
     B, W, H, D = 8, 64, 8, 128
@@ -565,6 +568,19 @@ def _kernel_kda():
           "kda: the layer not asked for changed")
     check(bool(jnp.all(new[1, 2] == state[1, 2])),
           "kda: a slot with no live row changed its state")
+    k = np.repeat(unit(rng.standard_normal((B, W // 16, 1, H, D))), 16, 2)
+    k = jnp.asarray(k.reshape(B, W, H, D), jnp.bfloat16)
+    g = jnp.asarray(-1e-3 * rng.uniform(0.5, 1.5, (B, W, H, D)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.9, 0.99, (B, W, H)), jnp.float32)
+    want_o, want_s = call("xla")(q, k, v, g, beta, state)
+    o, new = call("auto")(q, k, v, g, beta, state)
+    out["rows_repeated_keys"] = _close(o, want_o, 1e-2,
+                                       "kda rows, repeated keys")
+    # the state's product takes U and k in bfloat16 (the rows' dtype), and
+    # sixteen equal keys add that rounding in step: 0.016 with the finite
+    # product and with the merged blocks alike (my chip runs, PR 36)
+    out["state_repeated_keys"] = _close(new, want_s, 3e-2,
+                                        "kda state, repeated keys")
     return out
 
 

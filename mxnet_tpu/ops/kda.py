@@ -25,11 +25,28 @@ are the same as
     S_W = Diag(exp G_W) S_0 + (k exp(G_W - G))^T U
 
 The (I - b k k^T) term is what Mamba-2's form does not have: a chunk's rows
-depend on each other through the unit lower triangular (I + A). Its inverse
-is built from 16-row blocks: each diagonal block by the finite product
-(I - N)(I + N^2)(I + N^4)(I + N^8) (N strictly lower, N^16 = 0), then
-pairs of blocks merged, [[P, 0], [C, Q]]^-1 = [[P^-1, 0], [-Q^-1 C P^-1,
-Q^-1]], up to the chunk: products of (W, W) matrices alone, in float32.
+depend on each other through the unit lower triangular (I + A). The kernel
+never forms its inverse whole. It inverts the 16-row diagonal blocks: a
+pair of rows exactly ([[1, 0], [x, 1]]^-1 = [[1, 0], [-x, 1]]), then pairs of
+sub-blocks merged, [[P, 0], [X, Q]]^-1 = [[P^-1, 0], [-Q^-1 X P^-1, Q^-1]],
+from 2 rows up to 16: six products, a head's W/16 blocks side by side in
+ONE (16, W) matrix whose right operand is the (W, W) block diagonal of the
+same blocks. (The finite product (I - N)(I + N^2)(I + N^4)(I + N^8) costs
+the same six and is exact in exact arithmetic, but where a block's keys
+repeat its terms reach C(15, 7) = 6435 before they cancel: 5e-4 of error in
+float32 on such rows, 1e-6 merged; tests/test_kimi_linear.py.) U then
+comes by forward substitution over the row blocks,
+U_i = T_ii (rhs_i - sum_{j<i} A_ij U_j). Every one of these products is
+float32 at HIGHEST. A row block's scores are formed against the columns
+s < r0 + 16 alone, all that a mask keeps.
+
+A head is a chain of some twenty small products in which nearly each waits
+for the last, and Mosaic keeps MXU work in program order. So a pass of the
+kernel's loop takes up to 8 heads in LOCKSTEP, each stage written with the
+loop over those heads innermost: a product is followed by the other heads'
+independent ones, not by its own successor (3109 scheduled cycles a head
+at the parent, 676 now; in the cell, 32 slots x 32 heads, 2.18 -> 0.48 ms a
+call: PERF.md, PR 36).
 
 The pairwise decay exp(G_t - G_s) is a VECTOR over the key channels, so A is
 not (k k^T) times a matrix of scalars. And G falls by up to ~100 over 64
@@ -67,12 +84,18 @@ _SUB = 16
 _MAX_EXPONENT = 80.0
 # the state block of one grid step: as many heads as fit
 _STATE_BLOCK_BYTES = 1024 * 1024
-# the kernel's float32 products (A, Aq, the inverse of (I + A), U) keep
-# float32 on the MXU. At the default precision they are bfloat16 passes: on
-# the chip 1.90 ms a call in place of 2.68 at the cell's shape with the same
-# error on random keys (PERF.md, PR 34), but the finite product's terms
-# cancel by up to C(15, 7) = 6435 where keys repeat, which bfloat16 cannot
-# carry
+# heads a pass of the kernel's loop takes in lockstep, at most. Scheduled
+# cycles a head at 1 / 2 / 4 / 8: 2781 / 1543 / 933 / 684, and on the chip
+# 2.25 / 1.40 / 0.99 / 0.82 ms a call at the cell's shape (PERF.md, PR 36).
+# At 8 a thirtieth of the cycles still wait for a product; the traced body
+# and its compile time grow with every head
+_PASS = 8
+# the kernel's float32 products (A, Aq, the diagonal blocks' inverses, U)
+# keep float32 on the MXU. At the default precision they are bfloat16
+# passes: on the chip 1.90 ms a call in place of 2.68 at the cell's shape
+# with the same error on random keys (PERF.md, PR 34), but where keys repeat
+# the entries of a block's inverse are differences of like terms, which
+# bfloat16 cannot carry
 _HI = lax.Precision.HIGHEST
 
 
@@ -153,11 +176,15 @@ def _kda_chunk_xla(q, k, kb, vb, G, s0):
     return lax.map(one, (q, k, kb, vb, G, s0))
 
 
-def _kda_kernel(qc_ref, fresh_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
-                s_ref, o_ref, so_ref, *, W, hb, K, V, C):
+def _kda_kernel(qc_ref, fresh_ref, layer_ref, q_ref, k_ref, kb_ref, vb_ref,
+                g_ref, s_ref, o_ref, so_ref, *, W, hb, K, V, C, P):
     """One (slot, block of hb heads). q, k, b*k, b*v and G arrive as
-    (W, hb*K) column blocks, the state as (hb, V, K): S transposed. A head
-    is one pass of a rolled loop over the block's heads."""
+    (W, hb*K) column blocks, the state as (hb, V, K): S transposed. A pass
+    of the rolled loop takes P heads in LOCKSTEP: each stage below loops
+    over the pass's heads innermost, so that in program order, which is the
+    order Mosaic keeps MXU work in, a product is followed by the other
+    heads' independent ones and not by the one that waits for it."""
+    del layer_ref                                # the pool's index map reads it
     b = pl.program_id(0)
     qn = qc_ref[b]
     f32 = jnp.float32
@@ -175,118 +202,164 @@ def _kda_kernel(qc_ref, fresh_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
             precision=precision)
         hi = _HI
         nt, nn, tn = ((1,), (1,)), ((1,), (0,)), ((0,), (0,))
-        t_i = lax.broadcasted_iota(jnp.int32, (W, W), 0)
-        s_i = lax.broadcasted_iota(jnp.int32, (W, W), 1)
-        eye = (s_i == t_i).astype(f32)
-        below = s_i < t_i
-        # block sizes are powers of two wherever Mosaic compiles this
-        block_of = lambda i, size: i // size if size & (size - 1) \
-            else lax.shift_right_logical(i, size.bit_length() - 1)
+        blocks = list(enumerate(range(0, W, C)))
+        iota = lambda shape, d: lax.broadcasted_iota(jnp.int32, shape, d)
+        # what no head changes, once a grid step. A row block's scores are
+        # (C, r0 + C): the columns s < r0 + C are all a mask keeps.
+        # Queries see s <= t; the block's own strictly lower part is N
+        upto, own = [], []
+        for _, r0 in blocks:
+            t, s = iota((C, r0 + C), 0) + r0, iota((C, r0 + C), 1)
+            upto.append(s <= t)
+            own.append((s >= r0) & (s < t))
+        # a head's W // C diagonal blocks side by side, (C, W): the identity
+        # in that form, and the (W, W) block diagonal a product takes as its
+        # right operand
+        row, col = iota((C, W), 0), iota((C, W), 1) % C
+        eye = (col == row).astype(f32)
+        # by size: the entries that couple two neighbouring sub-blocks of
+        # `size` rows, which merge into one of 2 * size
+        halves, size = {}, 1
+        while size < C:
+            halves[size] = (row // (2 * size) == col // (2 * size)) \
+                & (row // size != col // size)
+            size *= 2
+        diagonal = iota((W, W), 0) // C == iota((W, W), 1) // C
+        spread = lambda m: jnp.where(
+            diagonal, jnp.concatenate([m] * (W // C), axis=0), 0.0)
         # all False for a fresh slot (fresh is 0 or 1), else all True
-        kept = lax.broadcasted_iota(jnp.int32, (V, K), 0) \
-            >= fresh_ref[b] * V
-        live = lax.broadcasted_iota(jnp.int32, (W, 1), 0) < qn
+        kept = iota((V, K), 0) >= fresh_ref[b] * V
+        live = iota((W, 1), 0) < qn
 
-        def head(i, _):
-            kc = pl.ds(pl.multiple_of(i * K, K), K)
-            vc = pl.ds(pl.multiple_of(i * V, V), V)
-            G = g_ref[0, :, kc]                                 # (W, K)
-            q, k = q_ref[0, :, kc].astype(f32), k_ref[0, :, kc].astype(f32)
-            kb, vb = kb_ref[0, :, kc].astype(f32), \
-                vb_ref[0, :, vc].astype(f32)
+        def heads(u, _):
+            at = [u * P + p for p in range(P)]
+            kc = [pl.ds(pl.multiple_of(i * K, K), K) for i in at]
+            vc = [pl.ds(pl.multiple_of(i * V, V), V) for i in at]
+            G = [g_ref[0, :, c] for c in kc]                    # (W, K)
+            q = [q_ref[0, :, c].astype(f32) for c in kc]
+            k = [k_ref[0, :, c].astype(f32) for c in kc]
+            kb = [kb_ref[0, :, c].astype(f32) for c in kc]
+            vb = [vb_ref[0, :, c].astype(f32) for c in vc]
             # a fresh slot reads zeros whatever the pool holds, a NaN
             # from the slot's last owner included
-            s0 = jnp.where(kept, s_ref[0, i], 0.0)              # (V, K)
-            eG = jnp.exp(G)
-            last = G[W - 1:W]                                   # (1, K)
+            s0 = [jnp.where(kept, s_ref[0, i], 0.0) for i in at]  # (V, K)
             # Aq and A, a block of C rows at a time against its own
             # reference: no factor passes exp(_MAX_EXPONENT)
-            parts = []
-            for r0 in range(0, W, C):
-                ref = G[r0:r0 + 1]
-                own = jnp.exp(G[r0:r0 + C] - ref)               # (C, K)
-                rows = jnp.concatenate(
-                    [q[r0:r0 + C] * own, kb[r0:r0 + C] * own], axis=0)
-                cols = k * jnp.exp(jnp.minimum(ref - G, _MAX_EXPONENT))
-                parts.append(dot(rows, cols, nt, hi))           # (2C, W)
-            Aq = jnp.concatenate([p[:C] for p in parts], axis=0)
-            A = jnp.concatenate([p[C:] for p in parts], axis=0)
-            Aq = jnp.where(s_i <= t_i, Aq, 0.0)
-            A = jnp.where(below, A, 0.0)
-            # (I + A)^-1: the diagonal blocks by the finite product, then
-            # pairs of blocks merged up to the chunk
-            same = block_of(t_i, C) == block_of(s_i, C)
-            M = jnp.where(same, -A, 0.0)
-            T, size = eye + M, 2
+            Aq, A, N = ([[] for _ in at] for _ in range(3))
+            for j, r0 in blocks:
+                n = r0 + C
+                for p in range(P):
+                    ref = G[p][r0:r0 + 1]
+                    decay = jnp.exp(G[p][r0:n] - ref)           # (C, K)
+                    rows = jnp.concatenate(
+                        [q[p][r0:n] * decay, kb[p][r0:n] * decay], axis=0)
+                    cols = k[p][:n] * jnp.exp(
+                        jnp.minimum(ref - G[p][:n], _MAX_EXPONENT))
+                    both = dot(rows, cols, nt, hi)              # (2C, n)
+                    Aq[p].append(jnp.where(upto[j], both[:C], 0.0))
+                    A[p].append(both[C:, :r0] if r0 else None)
+                    block = jnp.where(own[j], both[C:], 0.0)
+                    if n < W:
+                        block = jnp.concatenate(
+                            [block, jnp.zeros((C, W - n), f32)], axis=1)
+                    N[p].append(block)
+            # the products with the carried state: (q exp G) S_0 over
+            # (b k exp G) S_0
+            carried = []
+            for p in range(P):
+                eG = jnp.exp(G[p])
+                carried.append(dot(
+                    jnp.concatenate([q[p] * eG, kb[p] * eG], axis=0)
+                    .astype(cd), s0[p].astype(cd), nt))         # (2W, V)
+            # the diagonal blocks' inverses, every matrix a (C, W) row of
+            # blocks: exact for pairs of rows, then pairs of sub-blocks
+            # merged, [[P, 0], [X, Q]]^-1 = [[P^-1, 0], [-Q^-1 X P^-1,
+            # Q^-1]], up to the block
+            N = [sum(n[1:], n[0]) for n in N]
+            T, size = [eye - jnp.where(halves[1], n, 0.0) for n in N], 2
             while size < C:
-                M = dot(M, M, nn, hi)
-                T = T + dot(T, M, nn, hi)
+                X = [dot(t, spread(jnp.where(halves[size], n, 0.0)), nn, hi)
+                     for t, n in zip(T, N)]
+                T = [t - dot(x, spread(t), nn, hi) for t, x in zip(T, X)]
                 size *= 2
-            size = C
-            while size < W:
-                merged = (block_of(t_i, 2 * size) == block_of(s_i, 2 * size)) \
-                    & (block_of(t_i, size) != block_of(s_i, size))
-                T = T - dot(dot(T, jnp.where(merged, A, 0.0), nn, hi), T,
-                            nn, hi)
-                size *= 2
-            s0c = s0.astype(cd)
-            rhs = vb - dot((kb * eG).astype(cd), s0c, nt)       # (W, V)
-            U = dot(T, rhs, nn, hi)
-            o = dot((q * eG).astype(cd), s0c, nt) \
-                + dot(Aq.astype(cd), U.astype(cd), nn)
-            o_ref[0, :, vc] = jnp.where(live, o, 0.0).astype(o_ref.dtype)
-            so_ref[0, i] = s0 * jnp.exp(last) + dot(
-                U.astype(cd), (k * jnp.exp(last - G)).astype(cd), tn)
+            # U = (I + A)^-1 rhs by forward substitution over the row blocks
+            U = [[] for _ in at]
+            for j, r0 in blocks:
+                rhs = [vb[p][r0:r0 + C] - carried[p][W + r0:W + r0 + C]
+                       for p in range(P)]
+                if r0:
+                    rhs = [rhs[p] - dot(A[p][j], jnp.concatenate(
+                        U[p], axis=0), nn, hi) for p in range(P)]
+                for p in range(P):
+                    U[p].append(dot(T[p][:, r0:r0 + C], rhs[p], nn, hi))
+            for p, i in enumerate(at):
+                Uc = jnp.concatenate(U[p], axis=0).astype(cd)   # (W, V)
+                o = carried[p][:W] + jnp.concatenate(
+                    [dot(Aq[p][j].astype(cd), Uc[:r0 + C], nn)
+                     for j, r0 in blocks], axis=0)
+                last = G[p][W - 1:W]                            # (1, K)
+                o_ref[0, :, vc[p]] = jnp.where(live, o, 0.0).astype(
+                    o_ref.dtype)
+                so_ref[0, i] = s0[p] * jnp.exp(last) + dot(
+                    Uc, (k[p] * jnp.exp(last - G[p])).astype(cd), tn)
             return _
 
-        lax.fori_loop(0, hb, head, None)
+        lax.fori_loop(0, hb // P, heads, None)
 
 
-def _heads_per_block(H, K, V):
+def _largest_divisor(n, most):
+    return max(d for d in range(1, most + 1) if n % d == 0)
+
+
+def _tile(W, H, K, V):
+    """(heads a grid step: as many as fit the state block; rows of a
+    diagonal block; heads a pass of the kernel's loop), from the shapes."""
     hb = H
     while hb > 1 and (hb * K * V * 4 > _STATE_BLOCK_BYTES or H % hb):
         hb -= 1
-    return hb
+    return hb, _largest_divisor(W, _SUB), _largest_divisor(hb, _PASS)
 
 
+# one jitted function, `layer` a traced scalar: a model's layers, and every
+# program of a process that calls it at the same shapes, share ONE trace of
+# the kernel (its body is eight heads long)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _kda_chunk_pallas(q, k, kb, vb, G, state, q_counts, fresh, layer,
                       interpret):
     Bt, W, H, K = q.shape
     V = vb.shape[-1]
-    hb = _heads_per_block(H, K, V)
-    C = min(_SUB, W)
-    note_tile("kda_chunk_update", heads=hb, rows=W, block=C)
+    hb, C, P = _tile(W, H, K, V)
 
     def rows_index(b, j, *_):
         return (b, 0, j)
 
-    def state_index(b, j, *_):
-        return (layer, b, j, 0, 0)
+    def state_index(b, j, counts, fresh, layer):
+        return (layer[0], b, j, 0, 0)
 
     keys = pl.BlockSpec((1, W, hb * K), rows_index)
     values = pl.BlockSpec((1, W, hb * V), rows_index)
     # the layer axis is squeezed: the kernel sees (1, hb, V, K)
     pool = pl.BlockSpec((None, 1, hb, V, K), state_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(Bt, H // hb),
         in_specs=[keys, keys, keys, values, keys, pool],
         out_specs=[values, pool],
     )
     flat = lambda a: a.reshape(Bt, W, -1)
     o, state = pl.pallas_call(
-        functools.partial(_kda_kernel, W=W, hb=hb, K=K, V=V, C=C),
+        functools.partial(_kda_kernel, W=W, hb=hb, K=K, V=V, C=C, P=P),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((Bt, W, H * V), vb.dtype),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operand 7 (after the two prefetched scalars) is the pool
-        input_output_aliases={7: 1},
+        # operand 8 (after the three prefetched scalars) is the pool
+        input_output_aliases={8: 1},
         interpret=interpret,
         name="kda_chunk_update",
         compiler_params=_compiler_params(
             interpret, dimension_semantics=("parallel", "parallel")),
-    )(q_counts.astype(jnp.int32), fresh.astype(jnp.int32), flat(q), flat(k),
-      flat(kb), flat(vb), flat(G), state)
+    )(q_counts.astype(jnp.int32), fresh.astype(jnp.int32),
+      layer.reshape(1), flat(q), flat(k), flat(kb), flat(vb), flat(G), state)
     return o.reshape(Bt, W, H, V), state
 
 
@@ -300,8 +373,8 @@ def kda_chunk_update(q, k, v, g, beta, state, q_counts, layer, impl="auto",
     g:        (Bt, W, H, K) float32 log-decay a key channel, <= 0.
     beta:     (Bt, W, H) the rule's step, in (0, 1).
     state:    (L, Bt, H, V, K) float32, the WHOLE pool, S transposed;
-              `layer` (a static int) picks the layer, in the kernel's
-              BlockSpec.
+              `layer` (an int, or a traced int32 scalar) picks the layer,
+              in the kernel's BlockSpec.
     q_counts: (Bt,) live rows per slot; rows past the count leave the
               state alone and emit zeros.
     fresh:    (Bt,) bool, or None: slots that read zeros for their state.
@@ -317,8 +390,12 @@ def kda_chunk_update(q, k, v, g, beta, state, q_counts, layer, impl="auto",
     note_path("kda_chunk_update", impl)
     rows = _masked(q, k, v, g, beta, q_counts)
     if impl == "pallas":
-        return _kda_chunk_pallas(*rows, state, q_counts, fresh, layer,
-                                 interpret)
+        hb, C, P = _tile(q.shape[1], q.shape[2], q.shape[3], v.shape[-1])
+        note_tile("kda_chunk_update",
+                  **{"heads": hb, "rows": q.shape[1], "block": C, "pass": P})
+        return _kda_chunk_pallas(*rows, state, q_counts, fresh,
+                                 jnp.asarray(layer, jnp.int32),
+                                 interpret=interpret)
     if impl != "xla":
         raise ValueError(f"unknown kda_chunk_update impl {impl!r}")
     s0 = jnp.where(fresh[:, None, None, None], 0.0, state[layer])

@@ -119,7 +119,7 @@ def test_serving_engine_logits_match_the_reference(impl):
                                   f"kv_page_write/{path}": 2}
     assert st["kernel_tiles"] == ({} if impl == "xla" else {
         "latent_span_attention/pages=4,keys=64,rows=64,tile=64": 2,
-        "kda_chunk_update/heads=4,rows=16,block=16": 3,
+        "kda_chunk_update/heads=4,rows=16,block=16,pass=4": 3,
         "expert_ffn/rows=128,hidden=64": 4})
     moe = [dict(zip(MOE_COUNTERS, row)) for row in
            st["model_counters"]["moe"]]
@@ -163,15 +163,17 @@ def _token_rule(q, k, v, g, beta, s0, counts):
 
 @pytest.mark.parametrize("impl, interpret", [("xla", False),
                                              ("pallas", True)])
+@pytest.mark.parametrize("H", [1, 2, 3])
 @pytest.mark.parametrize("W", [16, 64])
-def test_kda_chunk_update_is_the_token_recurrence(impl, interpret, W):
+def test_kda_chunk_update_is_the_token_recurrence(impl, interpret, W, H):
     """At the strongest published decay the running log-decay falls by
     ~100 over 64 rows, where exp(-G) alone overflows float32: both forms
     stay finite and equal to the token recurrence, with ragged spans, dead
     rows, an idle slot and a `fresh` slot whose pool holds a NaN, in the
-    one layer of the pool they are told."""
+    one layer of the pool they are told; with one head, a pass of two and
+    a pass of three (the kernel walks a grid step's heads in lockstep)."""
     rng = np.random.default_rng(W)
-    Bt, H, D = 4, 2, 16
+    Bt, D = 4, 16
     rows = _kda_rows(rng, Bt, W, H, D)
     assert float(jnp.cumsum(rows[3], 1).min()) < (-25 if W == 16 else -100)
     pool = rng.standard_normal((2, Bt, H, D, D)).astype(np.float32)
@@ -194,6 +196,44 @@ def test_kda_chunk_update_is_the_token_recurrence(impl, interpret, W):
         np.testing.assert_allclose(got_s[b], want_s[b], atol=3e-5)
     np.testing.assert_array_equal(new[1, 2], pool[1, 2])    # the idle slot
     np.testing.assert_array_equal(new[0], pool[0])          # the other layer
+
+
+def _repeated_keys(rng, Bt, W, H, D, keys_a_block):
+    """`_kda_rows` with every 16-row block's keys its first one or two,
+    over and over, a weak decay and beta near one: the block's strictly
+    lower N has rank one or two and nothing damps its powers, whose terms
+    in (I - N)(I + N^2)(I + N^4)(I + N^8) reach C(15, 7) = 6435 before
+    they cancel."""
+    q, k, v, _, _ = _kda_rows(rng, Bt, W, H, D, strongest=False)
+    k = k.reshape(Bt, W // 16, 16, H, D)[:, :, np.arange(16) % keys_a_block]
+    g = -1e-3 * rng.uniform(0.5, 1.5, (Bt, W, H, D))
+    beta = rng.uniform(0.9, 0.99, (Bt, W, H))
+    return [q, k.reshape(Bt, W, H, D), v, jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32)]
+
+
+@pytest.mark.parametrize("impl, interpret", [("xla", False),
+                                             ("pallas", True)])
+@pytest.mark.parametrize("keys_a_block", [1, 2])
+def test_kda_chunk_update_holds_where_keys_repeat(impl, interpret,
+                                                  keys_a_block):
+    """Every row of a 16-row block the same unit key, or two keys
+    alternating. With one key the finite product of the block's powers, the
+    kernel's first form, misses by 5e-4 in exact float32; sub-blocks merged
+    from pairs of rows up, and the dense form's substitution, hold."""
+    rng = np.random.default_rng(keys_a_block)
+    Bt, W, H, D = 2, 64, 2, 16
+    rows = _repeated_keys(rng, Bt, W, H, D, keys_a_block)
+    pool = rng.standard_normal((1, Bt, H, D, D)).astype(np.float32)
+    counts = np.array([W, W - 5], np.int32)
+    want_o, want_s = _token_rule(*rows, pool[0].transpose(0, 1, 3, 2),
+                                 counts)
+    o, new = kda_chunk_update(*rows, jnp.asarray(pool), jnp.asarray(counts),
+                              0, impl=impl, interpret=interpret)
+    live = (np.arange(W)[None, :] < counts[:, None])[:, :, None, None]
+    np.testing.assert_allclose(np.asarray(o), want_o * live, atol=3e-5)
+    np.testing.assert_allclose(np.asarray(new)[0].transpose(0, 1, 3, 2),
+                               want_s, atol=3e-5)
 
 
 def test_kda_decay_is_one_a_channel_and_the_rule_corrects():

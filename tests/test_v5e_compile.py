@@ -337,7 +337,7 @@ def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
     # an expert's three matrices whole in a grid step (14.2 MB, double
     # buffered); 1024 keys a grid step of the latent kernel (PERF.md, PR 34)
     assert _since(kernel_paths.TILES, before[1]) == {
-        ("kda_chunk_update", "heads=16,rows=64,block=16"): 7,
+        ("kda_chunk_update", "heads=16,rows=64,block=16,pass=8"): 7,
         ("expert_ffn", "rows=128,hidden=1024"): 8,
         ("latent_span_attention",
          "pages=16,keys=1024,rows=2048,tile=256"): 2}
@@ -355,3 +355,15 @@ def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
     assert mem.argument_size_in_bytes > 11e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
         < 16.91e9
+    # the cell's own check runs the one-slot paged path: the delta rule's
+    # kernel once more at Bt = 1, a grid of (1, 2)
+    from mxnet_tpu.ops.kda import kda_chunk_update
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=one_chip)
+    rows = one((1, width, 32, 128), kw["dtype"])
+    alone = jax.jit(lambda q, k, v, g, beta, pool, counts: kda_chunk_update(
+        q, k, v, g, beta, pool, counts, 3, impl="pallas")).lower(
+        rows, rows, rows, one(rows.shape, "float32"),
+        one((1, width, 32), "float32"), one((7, 1, 32, 128, 128), "float32"),
+        one((1,), "int32")).compile()
+    assert alone.as_text().count("tpu_custom_call") == 1
