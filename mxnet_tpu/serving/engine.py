@@ -70,6 +70,8 @@ restarted outputs are bit-identical to an uninterrupted run.
 """
 from __future__ import annotations
 
+import copy
+import heapq
 import inspect
 import itertools
 import time
@@ -93,7 +95,7 @@ from ..models.kv_cache import (PagedKVCache, gather_kv_pages,
                                scatter_kv_pages)
 from ..ndarray.ndarray import NDArray
 from ..telemetry import server as _tserver
-from ..telemetry import span
+from ..telemetry import gc_totals, span
 from ..models.gpt2 import set_adapter_ctx as _set_adapter_ctx
 from ..models.gpt2 import set_tp_ctx as _set_tp_ctx
 from ..parallel.mesh import (AXIS_TP, PartitionSpec, named_sharding,
@@ -122,6 +124,10 @@ _E = ("engine",)
 # how often a dispatch folds the model's device counters into the host's
 # totals when nobody reads stats (ServingEngine._fold_model_counts)
 _FOLD_EVERY = 4096
+# serving_tick_seconds: 1 ms to 16.4 s, four buckets an octave
+_TICK_BUCKETS = telemetry.exponential_buckets(1e-3, 2 ** 0.25, 57)
+# how many of its slowest ticks an engine keeps whole
+SLOWEST_TICKS_KEPT = 8
 
 
 def _engine_metrics(eid):
@@ -221,6 +227,22 @@ def _engine_metrics(eid):
             "(dispatch wall / tokens the slot emitted, weighted)", _E),
         "drain_seconds": h("serving_drain_seconds",
                            "serve(): last submit -> queue+slots empty", _E),
+        "tick_seconds": h(
+            "serving_tick_seconds",
+            "wall of one scheduling tick (the serving.step span), in "
+            "buckets a factor 2**0.25 apart from 1 ms to 16 s; the exact "
+            "maximum is kept beside them", _E, _TICK_BUCKETS),
+        "tick_cpu_seconds": c(
+            "serving_tick_cpu_seconds_total",
+            "CPU time of the serving thread (time.thread_time) between "
+            "the two ends of the serving.step spans: the part of "
+            "serving_tick_seconds it spent computing; the rest of it the "
+            "thread was blocked (on the device, a copy, an upload, a "
+            "lock) or descheduled", _E),
+        "tick_gc_seconds": c(
+            "serving_tick_gc_seconds_total",
+            "seconds the garbage collector ran inside scheduling ticks "
+            "(a part of their wall, whatever phase it interrupted)", _E),
         "dispatch_errors": c(
             "serving_dispatch_errors_total",
             "dispatch faults the engine supervisor caught (batch rolled "
@@ -473,15 +495,39 @@ def _kernel_tile_family():
 
 
 class _TickSpan(span):
-    """A telemetry span that adds its self time to one child of
-    serving_tick_phase_seconds_total when it closes: the counter is the
-    spans' sum, not a second clock."""
+    """A telemetry span of one engine's scheduling tick. When it closes
+    the engine books its self time under the span's phase
+    (`ServingEngine._book_tick_span`): the counters are the spans' sums,
+    not a second clock. The span of the whole tick, `serving.step`, also
+    reads the thread's CPU clock and the garbage collector's totals at
+    its two ends and carries the differences, `cpu_s` and `gc_s`, on its
+    event. Only that span: where system calls are served in user space
+    (gVisor) a reading of `time.thread_time()` costs 5 us in a tight
+    loop and several times that between other work, and the value moves
+    in steps of 10 ms, so a phase's CPU time is not worth its price; the
+    tick's is, summed over a window."""
 
-    __slots__ = ("_counter",)
+    __slots__ = ("cpu_s", "gc_delta", "_eng", "_phase", "_c0", "_gc0")
+
+    def __enter__(self):
+        super().__enter__()
+        if self._phase == "step":
+            self._eng._tick_acc.clear()     # a new tick's record begins
+            self._gc0 = gc_totals()
+            # the CPU clock inside the wall clock, so cpu_s <= dur
+            self._c0 = time.thread_time()
+        return self
 
     def __exit__(self, exc_type, exc_val, exc_tb):
+        if self._phase == "step":
+            self.cpu_s = time.thread_time() - self._c0
+            # (seconds, collections of generation 0, 1, 2) in this tick
+            self.gc_delta = tuple(b - a for a, b
+                                  in zip(self._gc0, gc_totals()))
+            self.attrs["cpu_s"] = self.cpu_s
+            self.attrs["gc_s"] = self.gc_delta[0]
         super().__exit__(exc_type, exc_val, exc_tb)
-        self._counter.inc(self.self_s)
+        self._eng._book_tick_span(self)
         return False
 
 
@@ -1105,6 +1151,13 @@ class ServingEngine:
         fam = _tick_phase_family()
         self._tick_children = {ph: fam.labels(self._eid, ph)
                                for ph in TICK_PHASES}
+        # the tick that is running: phase -> [self s, spans closed];
+        # cleared when serving.step opens
+        self._tick_acc = {}
+        # the slowest ticks so far, a heap of (wall, tick, record) with
+        # the fastest of them on top
+        self._slowest = []
+        telemetry.install_gc_hook()
         self._path_children = {}   # (kernel, path) -> labeled child
         self._tile_children = {}   # (kernel, tile) -> labeled child
         # (PATHS, TILES) when a program was last built
@@ -1238,6 +1291,11 @@ class ServingEngine:
             "preempt_restarted": int(m["preempt_restarted"].value),
             "tick_phase_seconds": {ph: c.value for ph, c
                                    in self._tick_children.items()},
+            "tick_seconds": self._tick_seconds(),
+            "tick_cpu_seconds": m["tick_cpu_seconds"].value,
+            "tick_gc_seconds": m["tick_gc_seconds"].value,
+            "slowest_ticks": [copy.deepcopy(rec) for _, _, rec
+                              in sorted(self._slowest, reverse=True)],
             "recurrent_state_bytes": self._rec_bytes,
             "kv_pool_bytes": self._kv_pool_bytes,
             "kv_layers": self._kv_layers,
@@ -1291,7 +1349,7 @@ class ServingEngine:
             child.reset()
         for child in self._tick_children.values():
             child.reset()
-        self._metrics["num_slots"].set(self.num_slots)
+        self._slowest = []
         self._set_static_gauges()
         self._fold_model_counts()
         for total in self._model_totals.values():
@@ -1310,10 +1368,59 @@ class ServingEngine:
     def _tick_span(self, phase, **attrs):
         """The span `serving.<phase>` of one scheduling tick; on exit
         its self time lands in serving_tick_phase_seconds_total
-        (`stats["tick_phase_seconds"][phase]`)."""
+        (`stats["tick_phase_seconds"][phase]`) and in the record of the
+        tick that is running."""
         sp = _TickSpan("serving." + phase, engine=self._eid, **attrs)
-        sp._counter = self._tick_children[phase]
+        sp._eng, sp._phase = self, phase
         return sp
+
+    def _book_tick_span(self, sp):
+        phase = sp._phase
+        self._tick_children[phase].inc(sp.self_s)
+        acc = self._tick_acc.get(phase)
+        if acc is None:
+            self._tick_acc[phase] = [sp.self_s, 1]
+        else:
+            acc[0] += sp.self_s
+            acc[1] += 1
+        if phase == "step":
+            self._close_tick(sp)
+
+    def _close_tick(self, sp):
+        """`serving.step` closed: the tick's wall into its histogram,
+        its CPU and the collector's seconds into their counters, and the
+        tick's record among the slowest kept if it is one of them. A
+        record is made only when it is kept."""
+        wall = sp.dur
+        m = self._metrics
+        m["tick_seconds"].observe(wall)
+        m["tick_cpu_seconds"].inc(sp.cpu_s)
+        m["tick_gc_seconds"].inc(sp.gc_delta[0])
+        kept = self._slowest
+        full = len(kept) >= SLOWEST_TICKS_KEPT
+        if full and wall <= kept[0][0]:
+            return
+        acc = self._tick_acc
+        none = (0.0, 0)
+        rec = {"tick": sp.attrs["tick"], "wall_s": wall, "cpu_s": sp.cpu_s,
+               "gc_s": sp.gc_delta[0],
+               "gc_collections": list(sp.gc_delta[1:]),
+               "phases": {ph: acc.get(ph, none)[0] for ph in TICK_PHASES},
+               "spans": {ph: acc.get(ph, none)[1] for ph in TICK_PHASES},
+               "queued": sp.attrs["queued"], "active": sp.attrs["active"]}
+        (heapq.heapreplace if full else heapq.heappush)(
+            kept, (wall, rec["tick"], rec))
+
+    def _tick_seconds(self):
+        """`stats["tick_seconds"]`: the ticks' wall since the last
+        reset. Zeros while there is none, so the dict is always JSON."""
+        h = self._metrics["tick_seconds"]
+        n = h.count
+        if not n:
+            return {"count": 0, "sum": 0.0, "max": 0.0, "p50": 0.0,
+                    "p99": 0.0}
+        return {"count": n, "sum": h.sum, "max": h.max,
+                "p50": h.percentile(50), "p99": h.percentile(99)}
 
     def _shed_inc(self, reason, priority, tenant=None):
         key = (reason, int(priority))

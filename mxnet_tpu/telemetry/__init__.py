@@ -56,7 +56,7 @@ from .instruments import (  # noqa: F401
 )
 from .tracing import (  # noqa: F401
     span, events, clear_events, enable_jsonl, disable_jsonl,
-    add_event_hook, remove_event_hook,
+    add_event_hook, remove_event_hook, install_gc_hook, gc_totals,
 )
 from .request_trace import (  # noqa: F401
     RequestTrace, RequestTraceLog, request_log, chrome_trace,
@@ -82,6 +82,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "Registry",
            "snapshot", "render_prometheus", "dump", "reset",
            "span", "events", "clear_events", "enable_jsonl",
            "disable_jsonl", "add_event_hook", "remove_event_hook",
+           "install_gc_hook", "gc_totals",
            "RequestTrace", "RequestTraceLog", "request_log",
            "chrome_trace", "PHASES", "new_trace_id", "new_span_id",
            "parse_traceparent", "format_traceparent", "now",

@@ -261,6 +261,12 @@ class Histogram(_Instrument):
         with self._lock:
             return self._sum
 
+    @property
+    def max(self):
+        """The largest observation, exact; -inf while there is none."""
+        with self._lock:
+            return self._max
+
     def percentile(self, q):
         """Estimate the q-th percentile (0..100) by linear interpolation
         inside the covering bucket (histogram_quantile estimator). The

@@ -27,9 +27,17 @@ exception type under `"error"` — the exception itself propagates
 untouched (`__exit__` returns False). Observers can subscribe to every
 finished span with `add_event_hook(fn)` (the flight recorder's feed);
 hook exceptions are swallowed, an observer must never break the host.
+
+The garbage collector is counted where it runs: `install_gc_hook()` puts
+ONE callback into `gc.callbacks` (idempotent; the serving engine calls it
+when it is built) that keeps the running totals `gc_totals()` returns. A
+span that takes them at its two ends knows what the collector took out
+of it. A fault inside the callback is swallowed: it never reaches whoever
+allocated the object that began the collection.
 """
 from __future__ import annotations
 
+import gc
 import json
 import sys
 import threading
@@ -37,7 +45,8 @@ import time
 from collections import deque
 
 __all__ = ["span", "events", "clear_events", "enable_jsonl",
-           "disable_jsonl", "add_event_hook", "remove_event_hook"]
+           "disable_jsonl", "add_event_hook", "remove_event_hook",
+           "install_gc_hook", "gc_totals"]
 
 _tls = threading.local()
 _events_lock = threading.Lock()
@@ -174,3 +183,40 @@ def remove_event_hook(fn):
     with _events_lock:
         if fn in _event_hooks:
             _event_hooks.remove(fn)
+
+
+# -- the garbage collector ----------------------------------------------------
+
+# seconds inside collections so far, then collections of generation 0, 1, 2
+_gc_totals = [0.0, 0, 0, 0]
+# when the collection that is running began; the collector holds the
+# interpreter lock from "start" to "stop", so one pending start is enough
+_gc_began = [None]
+
+
+def _on_gc(phase, info):
+    try:
+        if phase == "start":
+            _gc_began[0] = time.perf_counter()
+            return
+        t0, _gc_began[0] = _gc_began[0], None
+        if t0 is None:              # installed in the middle of one
+            return
+        _gc_totals[0] += time.perf_counter() - t0
+        _gc_totals[1 + info["generation"]] += 1
+    except Exception:
+        pass        # never into the caller whose allocation began this
+
+
+def install_gc_hook():
+    """Count the garbage collector's runs and seconds from now on. Safe
+    to call any number of times: one callback a process."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_totals():
+    """(seconds inside collections, collections of generation 0, 1, 2)
+    since the hook was installed, never reset: read it at a span's two
+    ends."""
+    return tuple(_gc_totals)
