@@ -30,8 +30,9 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon.nn import Dense, Embedding
 from ..ndarray.ndarray import NDArray
-from .hybrid import (Attention, Mixer, RMSNorm, linear as _linear,
-                     raw as _raw, require_recurrent_cache,
+from .hybrid import (LIVE_ROWS_COUNTER, Attention, Mixer, RMSNorm,
+                     count_live_rows, linear as _linear, over_live_rows,
+                     pick_live_rows, raw as _raw, require_recurrent_cache,
                      rms_norm as _rms)
 from .kv_cache import PagedKVCache
 
@@ -159,16 +160,21 @@ class FalconH1Block(HybridBlock):
     def _mixer(self, u, cache, layer, fresh):
         return self.mamba.forward(u, cache, layer, fresh)
 
-    def forward(self, h, cache, layer, positions, fresh):
+    def _feed_forward(self, rows):
+        """(rows, C) residual rows -> the MLP's branch, row by row."""
+        c = self._c
+        v = _rms(rows, _raw(self.ff_norm.weight), c.rms_norm_eps)
+        f = jax.nn.silu(_linear(v, self.gate) * c.mlp_multipliers[0]) \
+            * _linear(v, self.up)
+        return _linear(f, self.down) * c.mlp_multipliers[1]
+
+    def forward(self, h, cache, layer, positions, fresh, live, pick):
         c = self._c
         u = _rms(h, _raw(self.input_norm.weight), c.rms_norm_eps)
         a, cache = self._attention(u, cache, layer, positions)
         m, cache = self._mixer(u, cache, layer, fresh)
         h = h + a + m
-        v = _rms(h, _raw(self.ff_norm.weight), c.rms_norm_eps)
-        f = jax.nn.silu(_linear(v, self.gate) * c.mlp_multipliers[0]) \
-            * _linear(v, self.up)
-        return h + _linear(f, self.down) * c.mlp_multipliers[1], cache
+        return h + over_live_rows(self._feed_forward, h, live, pick), cache
 
 
 class FalconH1ForCausalLM(HybridBlock):
@@ -204,7 +210,8 @@ class FalconH1ForCausalLM(HybridBlock):
         c = self.config
         return {"num_layers": c.num_layers,
                 "num_kv_heads": c.num_kv_heads, "head_dim": c.head_dim,
-                "recurrent": self.blocks()[0].mamba.state_leaves(c.dtype)}
+                "recurrent": self.blocks()[0].mamba.state_leaves(c.dtype),
+                "counters": dict(LIVE_ROWS_COUNTER)}
 
     def make_cache(self, batch, max_length, page_size=64, dtype=None,
                    page_table=None, lengths=None, attn_impl="auto"):
@@ -213,6 +220,8 @@ class FalconH1ForCausalLM(HybridBlock):
         c, spec = self.config, self.state_spec()
         rec = {k: jnp.zeros((c.num_layers, batch) + shape, dt)
                for k, (shape, dt) in spec["recurrent"].items()}
+        rec.update({k: jnp.zeros(shape, dt)
+                    for k, (shape, dt) in spec["counters"].items()})
         return PagedKVCache.create(
             c.num_layers, batch, c.num_heads, max_length, c.head_dim,
             dtype=dtype or jnp.dtype(c.dtype), page_size=page_size,
@@ -227,19 +236,24 @@ class FalconH1ForCausalLM(HybridBlock):
         c = self.config
         ids = inputs._data if isinstance(inputs, NDArray) else inputs
         b, t = ids.shape
-        fresh = None
+        steps = jnp.arange(t)[None, :]
+        fresh = live = pick = None
         if cache is None:
-            positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+            positions = jnp.broadcast_to(steps, (b, t))
         else:
             require_recurrent_cache(self, cache)
-            positions = cache.length[:, None] + jnp.arange(t)[None, :]
+            positions = cache.length[:, None] + steps
             # a slot with no context yet starts from zero state, whoever
             # held the slot before
             fresh = cache.length == 0
+            live = steps < cache.spans[:, None]
+            pick = pick_live_rows(live)
+            cache = count_live_rows(cache, pick)
         h = jnp.take(_raw(self.embed.weight), ids, axis=0) \
             * c.embedding_multiplier
         for i, block in enumerate(self.blocks()):
-            h, cache = block.forward(h, cache, i, positions, fresh)
+            h, cache = block.forward(h, cache, i, positions, fresh, live,
+                                     pick)
         h = _rms(h, _raw(self.final_norm.weight), c.rms_norm_eps)
         return NDArray(h), None if cache is None else cache.advance(t)
 

@@ -37,9 +37,10 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon.nn import MOE_COUNTERS, Dense, DroplessMoE, Embedding
 from ..ndarray.ndarray import NDArray
-from .hybrid import (GatedMLP, KDAMixer, LatentAttention, RMSNorm,
-                     kernel_impl, linear, raw, require_recurrent_cache,
-                     rms_norm)
+from .hybrid import (LIVE_ROWS_COUNTER, GatedMLP, KDAMixer, LatentAttention,
+                     RMSNorm, count_live_rows, kernel_impl, linear,
+                     over_live_rows, pick_live_rows, raw,
+                     require_recurrent_cache, rms_norm)
 from .kv_cache import PagedKVCache
 
 __all__ = ["KimiLinearConfig", "KimiLinearForCausalLM",
@@ -138,6 +139,10 @@ class KimiMoE(HybridBlock):
         b, t, c = u.shape
         rows = u.reshape(b * t, c)
         y, counts = self.experts.forward(rows, live.reshape(-1), **impl)
+        # over every row, live or not: a 1024-wide expert over 2048 rows is
+        # 0.15 ms at the chip's peak, less than the conditional and the two
+        # gathers of over_live_rows cost on either side of its choice
+        # (PERF.md, PR 38)
         with jax.named_scope("moe.shared"):
             shared = self.shared.forward(rows)
         return (y + shared).reshape(b, t, c), counts
@@ -168,7 +173,7 @@ class KimiLinearBlock(HybridBlock):
         self.ffn = GatedMLP(c.units, c.dense_hidden_size) \
             if expert_index is None else KimiMoE(c)
 
-    def forward(self, h, cache, fresh, live):
+    def forward(self, h, cache, fresh, live, pick):
         eps = self._c.rms_norm_eps
         u = rms_norm(h, raw(self.norm.weight), eps)
         if self.kind == "K":
@@ -178,7 +183,7 @@ class KimiLinearBlock(HybridBlock):
         h = h + f
         u = rms_norm(h, raw(self.ffn_norm.weight), eps)
         if self.expert_index is None:
-            f = self.ffn.forward(u)
+            f = over_live_rows(self.ffn.forward, u, live, pick)
         elif cache is None:
             f, _ = self.ffn.forward(u, live)
         else:
@@ -225,7 +230,9 @@ class KimiLinearForCausalLM(HybridBlock):
         (the value is the row's leading `value_width` columns; there is no
         V pool), for the `recurrent_layers` KDA layers their fixed-size
         leaves. `counters` are not a slot's: whole cumulative leaves kept
-        with the state (a row an expert layer, MOE_COUNTERS).
+        with the state (a row an expert layer, MOE_COUNTERS; where a layer
+        has a dense feed-forward, the dispatches and how many of them ran
+        it over their live rows alone, LIVE_ROWS_COUNTER).
         `expert_weight_bytes`: the held routed experts'."""
         c = self.config
         kda, latent = self.blocks("K"), self.blocks("L")
@@ -241,8 +248,10 @@ class KimiLinearForCausalLM(HybridBlock):
                 "value_width": c.kv_lora_rank,
                 "recurrent": kda[0].mixer.state_leaves(c.dtype)
                 if kda else {},
-                "counters": {"moe": ((len(experts), len(MOE_COUNTERS)),
-                                     "int32")} if experts else {},
+                "counters": {
+                    **({"moe": ((len(experts), len(MOE_COUNTERS)), "int32")}
+                       if experts else {}),
+                    **(LIVE_ROWS_COUNTER if c.dense_layers else {})},
                 "expert_weight_bytes": int(held)}
 
     def make_cache(self, batch, max_length, page_size=64, dtype=None,
@@ -270,19 +279,22 @@ class KimiLinearForCausalLM(HybridBlock):
         ids = inputs._data if isinstance(inputs, NDArray) else inputs
         b, t = ids.shape
         steps = jnp.arange(t)[None, :]
+        pick = None
         if cache is None:
             fresh, live = None, jnp.ones((b, t), bool)
         else:
-            require_recurrent_cache(
-                self, cache, recurrent="K" in c.pattern
-                or c.dense_layers < c.num_layers)
+            # (every layer's feed-forward keeps a counter in the state)
+            require_recurrent_cache(self, cache)
             # a slot with no context yet starts from zero state, whoever
             # held the slot before
             fresh = cache.length == 0
             live = steps < cache.spans[:, None]
+            if c.dense_layers:      # their feed-forwards
+                pick = pick_live_rows(live)
+                cache = count_live_rows(cache, pick)
         h = jnp.take(raw(self.embed.weight), ids, axis=0)
         for block in self.blocks():
-            h, cache = block.forward(h, cache, fresh, live)
+            h, cache = block.forward(h, cache, fresh, live, pick)
         h = rms_norm(h, raw(self.final_norm.weight), c.rms_norm_eps)
         return NDArray(h), None if cache is None else cache.advance(t)
 

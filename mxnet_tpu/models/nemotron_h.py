@@ -32,8 +32,9 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..gluon.nn import MOE_COUNTERS, Dense, DroplessMoE, Embedding
 from ..ndarray.ndarray import NDArray
-from .hybrid import (Attention, Mixer, RMSNorm, kernel_impl, linear, raw,
-                     require_recurrent_cache, rms_norm)
+from .hybrid import (LIVE_ROWS_COUNTER, Attention, Mixer, RMSNorm,
+                     count_live_rows, kernel_impl, linear, over_live_rows,
+                     pick_live_rows, raw, require_recurrent_cache, rms_norm)
 from .kv_cache import PagedKVCache
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM",
@@ -127,9 +128,10 @@ class LatentMoE(HybridBlock):
         self.shared_down = Dense(c.units, in_units=c.shared_hidden_size,
                                  **kw)
 
-    def forward(self, u, live, **impl):
-        """(B, T, C) normalised rows, (B, T) which of them are live ->
-        (the layer's f, the expert counters of this call)."""
+    def forward(self, u, live, pick=None, **impl):
+        """(B, T, C) normalised rows, (B, T) which of them are live, what
+        pick_live_rows made of that (None: whole sequences) -> (the
+        layer's f, the expert counters of this call)."""
         from ..ops.moe import relu2     # loads Pallas: not with the package
         b, t, c = u.shape
         rows = u.reshape(b * t, c)
@@ -137,9 +139,10 @@ class LatentMoE(HybridBlock):
             linear(rows, self.latent_in), live.reshape(-1), route_on=rows,
             **impl)
         with jax.named_scope("moe.shared"):
-            shared = linear(relu2(linear(rows, self.shared_up)),
-                            self.shared_down)
-        return (linear(y, self.latent_out) + shared).reshape(b, t, c), counts
+            shared = over_live_rows(
+                lambda r: linear(relu2(linear(r, self.shared_up)),
+                                 self.shared_down), u, live, pick)
+        return linear(y, self.latent_out).reshape(b, t, c) + shared, counts
 
 
 class NemotronHBlock(HybridBlock):
@@ -164,16 +167,17 @@ class NemotronHBlock(HybridBlock):
         else:
             self.mixer = LatentMoE(c)
 
-    def forward(self, h, cache, positions, fresh, live):
+    def forward(self, h, cache, positions, fresh, live, pick):
         u = rms_norm(h, raw(self.norm.weight), self._c.rms_norm_eps)
         if self.kind == "M":
             f, cache = self.mixer.forward(u, cache, self.index, fresh)
         elif self.kind == "*":
             f, cache = self.mixer.forward(u, cache, self.index, positions)
         elif cache is None:
-            f, _ = self.mixer.forward(u, live)
+            f, _ = self.mixer.forward(u, live, pick)
         else:
-            f, counts = self.mixer.forward(u, live, **kernel_impl(cache))
+            f, counts = self.mixer.forward(u, live, pick,
+                                           **kernel_impl(cache))
             rec = cache.recurrent
             cache = cache.with_recurrent(dict(
                 rec, moe=rec["moe"].at[self.index].add(counts)))
@@ -212,8 +216,10 @@ class NemotronHForCausalLM(HybridBlock):
         attention layers, the mixers' fixed-size leaves for the
         `recurrent_layers` mixers, nothing for an expert layer. `counters`
         are not a slot's: whole cumulative leaves kept with the state (a
-        row an expert layer, MOE_COUNTERS), which the engine zeroes and
-        fetches. `expert_weight_bytes`: the held routed experts'."""
+        row an expert layer, MOE_COUNTERS; the dispatches and how many of
+        them ran the shared experts over their live rows alone,
+        LIVE_ROWS_COUNTER), which the engine zeroes and fetches.
+        `expert_weight_bytes`: the held routed experts'."""
         c = self.config
         mixers, experts = self.blocks("M"), self.blocks("E")
         held = sum(raw(p).size * jnp.dtype(raw(p).dtype).itemsize
@@ -226,7 +232,8 @@ class NemotronHForCausalLM(HybridBlock):
                 "recurrent": mixers[0].mixer.state_leaves(c.dtype)
                 if mixers else {},
                 "counters": {"moe": ((len(experts), len(MOE_COUNTERS)),
-                                     "int32")} if experts else {},
+                                     "int32"), **LIVE_ROWS_COUNTER}
+                if experts else {},
                 "expert_weight_bytes": int(held)}
 
     def make_cache(self, batch, max_length, page_size=64, dtype=None,
@@ -253,6 +260,7 @@ class NemotronHForCausalLM(HybridBlock):
         ids = inputs._data if isinstance(inputs, NDArray) else inputs
         b, t = ids.shape
         steps = jnp.arange(t)[None, :]
+        pick = None
         if cache is None:
             positions = jnp.broadcast_to(steps, (b, t))
             fresh, live = None, jnp.ones((b, t), bool)
@@ -263,9 +271,12 @@ class NemotronHForCausalLM(HybridBlock):
             # held the slot before
             fresh = cache.length == 0
             live = steps < cache.spans[:, None]
+            if self.blocks("E"):    # the shared experts' feed-forwards
+                pick = pick_live_rows(live)
+                cache = count_live_rows(cache, pick)
         h = jnp.take(raw(self.embed.weight), ids, axis=0)
         for block in self.blocks():
-            h, cache = block.forward(h, cache, positions, fresh, live)
+            h, cache = block.forward(h, cache, positions, fresh, live, pick)
         h = rms_norm(h, raw(self.final_norm.weight), c.rms_norm_eps)
         return NDArray(h), None if cache is None else cache.advance(t)
 

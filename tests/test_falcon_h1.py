@@ -196,6 +196,12 @@ def test_serving_engine_logits_match_the_reference(impl):
             == list(r.output_tokens), r.id
     st = eng.stats
     assert st["state_resets"] == 5
+    # a whole prompt chunk does not fit an eighth of the 2 x 16 grid: those
+    # ticks took the full feed-forward, decode ticks and a prompt's short
+    # last chunk the compact one, and every logit above matched
+    dispatches, compact = st["model_counters"]["live_rows"]
+    assert dispatches == st["decode_dispatches"]
+    assert 0 < compact < dispatches
     assert st["recurrent_state_bytes"] == 2 * 2 * (
         4 * 32 * 16 * 4 + 3 * (4 * 32 + 2 * 2 * 16) * 4)
     path = "xla" if impl == "xla" else "pallas"

@@ -128,6 +128,12 @@ def test_serving_engine_logits_match_the_reference(impl):
         assert layer["dispatches"] == st["decode_dispatches"]
         assert layer["rows"] == 111 + 30
         assert 0 < layer["pairs"] <= 4 * layer["rows"]
+    # a whole prompt chunk does not fit an eighth of the 2 x 16 grid: those
+    # ticks took the full feed-forward, decode ticks and a prompt's short
+    # last chunk the compact one, and every logit above matched
+    dispatches, compact = st["model_counters"]["live_rows"]
+    assert dispatches == st["decode_dispatches"]
+    assert 0 < compact < dispatches
 
 
 def _kda_rows(rng, Bt, W, H, D, strongest=True):
@@ -454,7 +460,8 @@ def test_the_engine_allocates_one_pool_and_refuses_by_name():
     assert sorted(state) == ["k", "rec"]
     assert state["k"].shape == (2, 8, 16, 128)
     assert {k: v.shape for k, v in state["rec"].items()} == {
-        "conv": (3, 2, 3, 3 * 64), "kda": (3, 2, 4, 16, 16), "moe": (4, 5)}
+        "conv": (3, 2, 3, 3 * 64), "kda": (3, 2, 4, 16, 16), "moe": (4, 5),
+        "live_rows": (2,)}
     st = eng.stats
     assert st["kv_pool_bytes"] == 2 * 8 * 16 * 128 * 4
     assert st["kv_page_bytes"] == 2 * 16 * 128 * 4         # one pool

@@ -164,6 +164,12 @@ def test_serving_engine_logits_match_the_reference(impl):
         assert 0 < layer["pairs"] <= 3 * layer["rows"]
         assert layer["largest_group"] * 4 >= layer["pairs"]
         assert layer["experts_touched"] <= 4 * layer["dispatches"]
+    # a whole prompt chunk does not fit an eighth of the 2 x 16 grid: those
+    # ticks took the full feed-forward, decode ticks and a prompt's short
+    # last chunk the compact one, and every logit above matched
+    dispatches, compact = st["model_counters"]["live_rows"]
+    assert dispatches == st["decode_dispatches"]
+    assert 0 < compact < dispatches
     eng.reset_stats()
     assert np.asarray(eng.stats["model_counters"]["moe"]).sum() == 0
 
@@ -382,7 +388,8 @@ def test_the_engine_allocates_by_layer_kind():
     spec = net.state_spec()
     assert (spec["num_layers"], spec["kv_layers"],
             spec["recurrent_layers"]) == (8, 2, 3)
-    assert spec["counters"] == {"moe": ((3, 5), "int32")}
+    assert spec["counters"] == {"moe": ((3, 5), "int32"),
+                                "live_rows": ((2,), "int32")}
     assert spec["expert_weight_bytes"] == 3 * 4 * 2 * 64 * 128 * 4
     eng = ServingEngine(net, **base)
     pages = 2 * (64 // 16)
@@ -390,7 +397,7 @@ def test_the_engine_allocates_by_layer_kind():
     conv, ssm = 3 * (8 * 16 + 2 * 2 * 16) * 4, 8 * 16 * 16 * 4
     assert _pool_bytes(eng) == {
         "k": 2 * pages * 16 * 64 * 4, "v": 2 * pages * 16 * 64 * 4,
-        "rec": 3 * 2 * (conv + ssm) + 3 * 5 * 4}
+        "rec": 3 * 2 * (conv + ssm) + 3 * 5 * 4 + 2 * 4}
     st = eng.stats
     assert st["recurrent_state_bytes"] == 3 * 2 * (conv + ssm)
     assert st["kv_page_bytes"] == 2 * 2 * 16 * 64 * 4
@@ -405,11 +412,11 @@ def test_the_engine_allocates_by_layer_kind():
     fconv, fssm = 3 * (4 * 32 + 2 * 2 * 16) * 4, 4 * 32 * 16 * 4
     assert _pool_bytes(feng) == {
         "k": 2 * pages * 16 * 64 * 4, "v": 2 * pages * 16 * 64 * 4,
-        "rec": 2 * 2 * (fconv + fssm)}
+        "rec": 2 * 2 * (fconv + fssm) + 2 * 4}
     fst = feng.stats
     assert (fst["kv_layers"], fst["recurrent_layers"],
             fst["expert_weight_bytes"], fst["model_counters"]) \
-        == (2, 2, 0, {})
+        == (2, 2, 0, {"live_rows": [0, 0]})
 
     gnet = models.GPT2ForCausalLM(models.GPT2Config(
         vocab_size=128, units=64, num_layers=3, num_heads=4, max_length=64,

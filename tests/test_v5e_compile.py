@@ -195,9 +195,11 @@ def test_falcon_h1_unified_program_donates_pages_and_state(one_chip):
                  if d != "1") in pools]
     assert moved == []
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == sum(a.nbytes for a in leaves)
+    # every leaf aliased; the 8 bytes of the live-rows counter pad to a tile
+    assert 0 <= mem.alias_size_in_bytes - sum(a.nbytes for a in leaves) \
+        < 4096
     assert eng.stats["recurrent_state_bytes"] == sum(
-        a.nbytes for a in jax.tree_util.tree_leaves(state["rec"]))
+        a.nbytes for k, a in state["rec"].items() if k != "live_rows")
     print(f"falcon_h1_34b unified greedy program, {slots} slots, {layers} "
           f"layers: {mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
@@ -240,7 +242,7 @@ def test_nemotron_h_unified_program_holds_state_by_layer_kind(one_chip):
     assert state["k"].shape == (1, pages, 64, 2 * 128)
     assert {k: v.shape for k, v in state["rec"].items()} == {
         "conv": (5, slots, 3, 8192 + 2 * 8 * 128),
-        "ssm": (5, slots, 128, 64, 128), "moe": (5, 5)}
+        "ssm": (5, slots, 128, 64, 128), "moe": (5, 5), "live_rows": (2,)}
     st = eng.stats
     assert st["recurrent_state_bytes"] == 5 * slots * (
         128 * 64 * 128 * 4 + 3 * 10240 * 2)
@@ -267,9 +269,10 @@ def test_nemotron_h_unified_program_holds_state_by_layer_kind(one_chip):
     assert f"[{slots},{kw['vocab_size']}]" in hlo
     mem = compiled.memory_analysis()
     leaves = jax.tree_util.tree_leaves(state)
-    # every leaf aliased; the 100 bytes of counters pad to a 4 KiB tile
+    # every leaf aliased; the 108 bytes of the two counters pad to a tile
+    # each, of 4 KiB at most
     assert 0 <= mem.alias_size_in_bytes - sum(a.nbytes for a in leaves) \
-        < 4096
+        < 2 * 4096
     print(f"nemotron3_super_120b unified greedy program, {slots} slots, "
           f"{len(kw['pattern'])} layers: "
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
@@ -314,7 +317,7 @@ def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
     assert state["k"].shape == (2, pages, 64, 640)
     assert {k: v.shape for k, v in state["rec"].items()} == {
         "conv": (7, slots, 3, 3 * 4096), "kda": (7, slots, 32, 128, 128),
-        "moe": (8, 5)}
+        "moe": (8, 5), "live_rows": (2,)}
     st = eng.stats
     assert st["recurrent_state_bytes"] == 7 * slots * (
         32 * 128 * 128 * 4 + 3 * 12288 * 2)
@@ -344,9 +347,10 @@ def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
     assert f"[{slots},{kw['vocab_size']}]" in hlo
     mem = compiled.memory_analysis()
     leaves = jax.tree_util.tree_leaves(state)
-    # every leaf aliased; the 160 bytes of counters pad to a 4 KiB tile
+    # every leaf aliased; the 168 bytes of the two counters pad to a tile
+    # each, of 4 KiB at most
     assert 0 <= mem.alias_size_in_bytes - sum(a.nbytes for a in leaves) \
-        < 4096
+        < 2 * 4096
     print(f"kimi_linear_48b unified greedy program, {slots} slots, "
           f"{len(kw['pattern'])} layers: "
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
