@@ -240,11 +240,14 @@ class Attention(HybridBlock):
             from ..ops.pallas_attention import ragged_span_attention
             cache = cache.write_decode(layer, k.transpose(0, 2, 1, 3),
                                        v.transpose(0, 2, 1, 3))
+            # int8 pages keep the query in its own type and hand the
+            # kernel the per-(page, head) scales (both None otherwise)
             out = ragged_span_attention(
-                q.astype(cache.k_pages.dtype), cache.k_pages,
-                cache.v_pages, cache.page_table, cache.length + 1,
-                q_counts=cache.spans, layer=layer, num_kv_heads=hkv,
-                **kernel_impl(cache)).astype(u.dtype)
+                q if cache.quantized else q.astype(cache.k_pages.dtype),
+                cache.k_pages, cache.v_pages, cache.page_table,
+                cache.length + 1, q_counts=cache.spans, layer=layer,
+                num_kv_heads=hkv, k_scale=cache.k_scale,
+                v_scale=cache.v_scale, **kernel_impl(cache)).astype(u.dtype)
         out = out.reshape(b, t, hq * d)
         return linear(out, self.proj) * self._out, cache
 

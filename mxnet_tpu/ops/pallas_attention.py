@@ -580,14 +580,16 @@ def _span_passes(H, D):
     return 1, False
 
 
-def _ragged_span_kernel(pages_ref, len_ref, qc_ref, *refs, scale, S, Sq, H,
-                        D, KB, group=1, quant=False):
+def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
+                        S, Sq, H, D, KB, group=1, quant=False):
     """H KV heads, each against a block of Sq rows: `group` query heads
     stacked, Sq // group positions each (plain multi-head: group 1), and
-    KB pages of keys a step. pages_ref: _span_block_table's. refs:
+    KB pages of keys a step. layer_ref: the pools' layer, which only the
+    page index maps read. pages_ref: _span_block_table's. refs:
     [k_scale, v_scale] (int8 pages: the per-(page, head) float32 scales,
     in SMEM beside it), q, KB pages of K, KB pages of V, out, and the
     running max, denominator and numerator."""
+    del layer_ref
     kscale_ref = vscale_ref = None
     if quant:
         kscale_ref, vscale_ref, *refs = refs
@@ -759,8 +761,14 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     lengths-1 .. lengths+q_counts-2.
     k_pages/v_pages:(L, num_pages, S, H_kv*D) — the WHOLE page pools as
                     PagedKVCache stores them (heads packed, column
-                    h*D + d); `layer` (a static int) picks the layer,
-                    inside the kernel's page BlockSpec.
+                    h*D + d); `layer` picks the layer inside the
+                    kernel's page BlockSpec: an int32 scalar, a Python
+                    int or TRACED (a model that runs its stack several
+                    times over cache layers of its own hands
+                    `step * layers + l` from inside its loop), read by
+                    the page index maps from a scalar-prefetch operand
+                    as kv_page_write reads its own. One form for both,
+                    so every model's layers share the lowered kernel.
     num_kv_heads:   H_kv, where fewer KV heads than the H query heads
                     (None: as many). KV head h serves the G = H / H_kv
                     query heads h*G .. h*G+G-1; their rows are STACKED
@@ -821,10 +829,11 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                               q_counts, S, KB)
 
     # the index_maps take the grid position and then every scalar-prefetch
-    # operand: the block table, the lengths, the counts and, over int8
-    # pages, the two scale leaves
+    # operand: the layer, the block table, the lengths, the counts and,
+    # over int8 pages, the two scale leaves
     def page_index(i):
-        return lambda b, p, pages, *_: (layer, pages[b, p * KB + i], 0, 0)
+        return lambda b, p, layer, pages, *_: (
+            layer[0], pages[b, p * KB + i], 0, 0)
 
     def q_index(b, p, *_prefetched):
         return (b, 0, 0)
@@ -836,7 +845,7 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     scales = (k_scale[layer].astype(jnp.float32),
               v_scale[layer].astype(jnp.float32)) if quant else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3 + len(scales),
+        num_scalar_prefetch=4 + len(scales),
         grid=(B, pl.cdiv(P, KB)),
         in_specs=[pl.BlockSpec((1, Sr, H * D), q_index)] + 2 * page_specs,
         out_specs=pl.BlockSpec((1, Sr, H * D), q_index),
@@ -848,13 +857,14 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     )
     kernel = functools.partial(_ragged_span_kernel, scale=s, S=S, Sq=Sr,
                                H=H, D=D, KB=KB, group=G, quant=quant)
-    operands = (pages, lengths, q_counts, *scales, qp,
-                *([k_pages] * KB), *([v_pages] * KB))
+    operands = (jnp.asarray(layer, jnp.int32).reshape(1), pages, lengths,
+                q_counts, *scales, qp, *([k_pages] * KB), *([v_pages] * KB))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sr, H * D), q.dtype),
         interpret=interpret,
+        name="ragged_span_attention",
         compiler_params=_compiler_params(
             interpret, dimension_semantics=("parallel", "arbitrary")),
     )(*operands)

@@ -353,13 +353,21 @@ def _engine_metrics(eid):
             _E),
         "kv_layers": g(
             "serving_kv_layers",
-            "layers that hold KV pages (state_spec()['kv_layers']; every "
-            "layer for a model that says nothing)", _E),
+            "cache layers that hold KV pages (state_spec()['kv_layers']; "
+            "every layer for a model that says nothing, more than the "
+            "model has layers where every pass of a looped stack keeps "
+            "its own)", _E),
         "recurrent_layers": g(
             "serving_recurrent_layers",
             "layers that hold the slots' recurrent leaves "
             "(state_spec()['recurrent_layers']; 0 for a model with pages "
             "only)", _E),
+        "loop_steps": g(
+            "serving_loop_steps",
+            "passes a dispatch makes over the model's stack "
+            "(state_spec()['loop_steps']; 1 for a model that runs each "
+            "layer once): serving_kv_layers counts every pass's cache "
+            "layers", _E),
         "expert_weight_bytes": g(
             "serving_expert_weight_bytes",
             "device bytes of the routed experts this engine's model holds "
@@ -678,15 +686,19 @@ class ServingEngine:
         # attention) says `row_width`: one page pool that wide, no V pool
         row_width = slot_state.get("row_width")
         self._one_pool = row_width is not None
+        # the features a model's state may refuse, and whether each is on
+        asked = {"tp": self._tp > 1, "speculative": speculative,
+                 "prefix_cache": prefix_cache,
+                 "host_kv_bytes": host_kv_bytes is not None,
+                 "kv_dtype": kv_dtype is not None,
+                 "weight_dtype": weight_dtype is not None,
+                 "adapter_pool": adapter_pool is not None}
         if self._one_pool:
             # what splits a page by head, scales it by head or ships it as
             # a K and a V page has nothing to hold on to
             # (docs/SERVING.md "A pool with no head axis")
-            for feature, on in (
-                    ("tp", self._tp > 1),
-                    ("kv_dtype", kv_dtype is not None),
-                    ("host_kv_bytes", host_kv_bytes is not None)):
-                if on:
+            for feature in ("tp", "kv_dtype", "host_kv_bytes"):
+                if asked[feature]:
                     raise MXNetError(
                         f"{feature} is not supported for a model whose "
                         f"page row has no head axis (row_width {row_width})"
@@ -697,15 +709,10 @@ class ServingEngine:
             # page by page, split over heads or rebuilt from pages: what
             # leases, copies or ships pages knows nothing of it yet
             # (docs/SERVING.md "Recurrent state")
-            for feature, on in (
-                    ("prefix_cache", prefix_cache),
-                    ("speculative", speculative),
-                    ("host_kv_bytes", host_kv_bytes is not None),
-                    ("tp", self._tp > 1),
-                    ("kv_dtype", kv_dtype is not None),
-                    ("weight_dtype", weight_dtype is not None),
-                    ("adapter_pool", adapter_pool is not None)):
-                if on:
+            for feature in ("prefix_cache", "speculative", "host_kv_bytes",
+                            "tp", "kv_dtype", "weight_dtype",
+                            "adapter_pool"):
+                if asked[feature]:
                     raise MXNetError(
                         f"{feature} is not supported for a model that "
                         "declares recurrent state "
@@ -713,6 +720,15 @@ class ServingEngine:
                         "beside its KV pages cannot be shared by prefix, "
                         "verified and rolled back, spilled, sharded, "
                         "quantized or adapted yet")
+        # what the model itself says its blocks do not carry
+        # (state_spec()["refuses"]: feature -> why)
+        for feature, why in (slot_state.get("refuses") or {}).items():
+            if asked[feature]:
+                raise MXNetError(f"{feature} is not supported for "
+                                 f"{type(model).__name__}: {why}")
+        # a model that runs its stack several times a dispatch says how
+        # often (`loop_steps`): its `kv_layers` then count every pass's
+        self._loop_steps = int(slot_state.get("loop_steps", 1))
         if self._tp > 1:
             if cfg.num_heads % self._tp:
                 raise MXNetError(
@@ -954,8 +970,10 @@ class ServingEngine:
             name: jnp.zeros(tuple(shape), jnp.dtype(cdt))
             for name, (shape, cdt) in
             (slot_state.get("counters") or {}).items()}
-        self._model_totals = {name: np.zeros(a.shape, np.int64)
-                              for name, a in self._model_counts.items()}
+        self._model_totals = {
+            name: np.zeros(a.shape, np.float64 if jnp.issubdtype(
+                a.dtype, jnp.floating) else np.int64)
+            for name, a in self._model_counts.items()}
         if self._mesh is not None:
             # the pools LIVE sharded (global shape above, the packed
             # axis split over the mesh in whole-head blocks): every
@@ -1300,6 +1318,7 @@ class ServingEngine:
             "kv_pool_bytes": self._kv_pool_bytes,
             "kv_layers": self._kv_layers,
             "recurrent_layers": self._rec_layers,
+            "loop_steps": self._loop_steps,
             "expert_weight_bytes": self._expert_weight_bytes,
             "model_counters": {name: total.tolist() for name, total
                                in self._model_totals.items()},
@@ -1336,6 +1355,7 @@ class ServingEngine:
         self._metrics["kv_pool_bytes"].set(self._kv_pool_bytes)
         self._metrics["kv_layers"].set(self._kv_layers)
         self._metrics["recurrent_layers"].set(self._rec_layers)
+        self._metrics["loop_steps"].set(self._loop_steps)
         self._metrics["expert_weight_bytes"].set(self._expert_weight_bytes)
         for wd, nb in self._weight_bytes.items():
             self._wbytes_fam.labels(self._eid, wd).set(nb)

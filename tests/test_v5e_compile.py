@@ -371,3 +371,83 @@ def test_kimi_linear_unified_program_holds_one_pool_and_kda_state(one_chip):
         one((1, width, 32), "float32"), one((7, 1, 32, 128, 128), "float32"),
         one((1,), "int32")).compile()
     assert alone.as_text().count("tpu_custom_call") == 1
+
+
+def test_ouro_unified_program_carries_its_pools_in_place_through_the_loop(
+        one_chip):
+    """ouro_2_6b.fewshot_backlog's whole unified greedy program at the
+    cell's own engine block and model kwargs, weights described: all 48
+    blocks traced ONCE as the body of a `while` of four passes (96 Mosaic
+    calls: a page write and a span kernel a block, not 384), K and V pools
+    of 192 cache layers as one donated pytree aliased to the outputs and
+    carried through the loop IN PLACE: no copy, slice or update as large as
+    a cache layer, and temporaries a hundredth of a pool. Prints the
+    arguments and temporaries that `PERF.md` cites. The engine is BUILT
+    with one page a slot (its pools would be 8 GB of this host's memory)
+    and the program lowered at the cell's 16: a program's shapes are its
+    arguments'."""
+    import json
+    from mxnet_tpu import models
+    from mxnet_tpu.serving import ServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "ouro_2_6b.json")) as f:
+        cfg = json.load(f)
+    kw, ekw = cfg["model"]["kwargs"], cfg["engine"]
+    net = models.OuroForCausalLM(models.ouro_2_6b_config(**kw))
+    for p in net.collect_params().values():
+        p._data = _described(p.shape, kw["dtype"])
+    slots, width, page = (ekw["num_slots"], ekw["chunk_tokens"],
+                          ekw["page_size"])
+    per_slot = ekw["max_length"] // page
+    eng = ServingEngine(net, attn_impl="pallas",
+                        **{**ekw, "max_length": page})
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=one_chip)
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=one_chip)
+    row = lambda dtype: one((slots,), dtype)
+    state = eng._device_state()
+    assert sorted(state) == ["k", "rec", "v"]
+    assert state["k"].shape == (192, slots, page, 16 * 128)
+    pool = one((192, slots * per_slot, page, 16 * 128), kw["dtype"])
+    described = {"k": pool, "v": pool,
+                 "rec": jax.tree_util.tree_map(sds, state["rec"])}
+    st = eng.stats
+    assert (st["kv_layers"], st["loop_steps"]) == (192, 4)
+    assert st["kv_bytes_per_token"] == 1_572_864
+    before = (dict(kernel_paths.PATHS), dict(kernel_paths.TILES))
+    compiled = eng._build_unified(greedy_only=True).lower(
+        tuple(sds(p.data()._data) for p in eng._params), described,
+        one((slots, per_slot), "int32"), one((slots * per_slot,), "bool"),
+        *[sds(a) for a in eng._dstate[:11]], one((slots, width), "int32"),
+        row("int32"), row("bool"), row("bool")).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2 * 48
+    assert len(re.findall(r" while\(", hlo)) == 1
+    assert _since(kernel_paths.PATHS, before[0]) == {
+        ("kv_page_write", "pallas"): 48,
+        ("ragged_span_attention", "pallas"): 48}
+    # GPT-2 774M's geometry: every head its own KV head, all 16 pages a step
+    assert _since(kernel_paths.TILES, before[1]) == {
+        ("ragged_span_attention", "pages=16,keys=1024,rows=64"): 48}
+    assert f"[{slots},{kw['vocab_size']}]" in hlo
+    layer_elems = slots * per_slot * page * 16 * 128
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice|transpose|"
+        r"dynamic-update-slice)\(", hlo)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= layer_elems]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    pools = 2 * 192 * layer_elems * 2
+    # both pools aliased; the 20 bytes of the exit counter pad to a tile
+    assert 0 <= mem.alias_size_in_bytes - pools < 4096
+    assert mem.temp_size_in_bytes < pools // 50
+    print(f"ouro_2_6b unified greedy program, {slots} slots, 48 layers x 4 "
+          f"passes: {mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB aliased")
+    assert mem.argument_size_in_bytes > 13e9
+    # the check's own one-slot cache (1.611 GB) beside it still fits
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + pools // slots + 1e9 < 16.91e9
