@@ -24,6 +24,8 @@ from .kimi_linear import (  # noqa: F401,E402
     KimiLinearConfig, KimiLinearForCausalLM, kimi_linear_48b_config)
 from .ouro import (  # noqa: F401,E402
     OuroConfig, OuroForCausalLM, ouro_2_6b_config)
+from .trinity import (  # noqa: F401,E402
+    TrinityConfig, TrinityForCausalLM, trinity_mini_config)
 from .kv_cache import KVCache, PagedKVCache  # noqa: F401,E402
 from .nmt import NMTConfig, TransformerNMT, nmt_base_config  # noqa: F401,E402
 from . import vision  # noqa: F401,E402

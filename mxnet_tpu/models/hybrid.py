@@ -189,24 +189,45 @@ class Attention(HybridBlock):
     `num_kv_heads` KV heads of `head_dim`, no bias. With `rotary` the
     queries and keys are rotated at the token's own position and the keys
     go to the pages already rotated; without, nothing here reads a
-    position (the order is then some other layer's to carry)."""
+    position (the order is then some other layer's to carry).
+
+    Off unless asked, each adding its own parameters: `qk_norm`, the
+    queries and keys RMS-normalised over each head's `head_dim` columns
+    with a learned weight of `head_dim` (q_norm, k_norm; eps `norm_eps`),
+    before the rotation; `output_gate`, the heads' concatenated output
+    times sigmoid(x W_gate) before the projection; `window` W, a token
+    sees the W keys up to and including its own. A window layer keeps its
+    keys in the slot's RING of pages (PagedKVCache.ring: the recurrent
+    leaves `ring_k` and `ring_v`, its `layer` a ring layer) and attends
+    through the window kernel, which visits no page before the window;
+    every other layer keeps whole-depth pages (its `layer` a page layer)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rotary=True, rope_theta=1e4, in_multiplier=1.0,
-                 key_multiplier=1.0, out_multiplier=1.0, **kwargs):
+                 key_multiplier=1.0, out_multiplier=1.0, qk_norm=False,
+                 output_gate=False, window=None, norm_eps=1e-5, **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise MXNetError(f"{num_heads} query heads over "
                              f"{num_kv_heads} KV heads")
+        if window is not None and window < 1:
+            raise MXNetError(f"window {window} < 1")
         self._heads, self._kv_heads, self._dim = (num_heads, num_kv_heads,
                                                   head_dim)
         self._rotary, self._theta = bool(rotary), rope_theta
         self._in, self._key, self._out = (in_multiplier, key_multiplier,
                                           out_multiplier)
+        self._qk_norm, self._gated = bool(qk_norm), bool(output_gate)
+        self.window, self._eps = window, norm_eps
         kw = dict(use_bias=False, flatten=False, in_units=units)
         self.query = Dense(num_heads * head_dim, **kw)
         self.key = Dense(num_kv_heads * head_dim, **kw)
         self.value = Dense(num_kv_heads * head_dim, **kw)
+        if self._qk_norm:
+            self.q_norm = RMSNorm(head_dim)
+            self.k_norm = RMSNorm(head_dim)
+        if self._gated:
+            self.gate = Dense(num_heads * head_dim, **kw)
         self.proj = Dense(units, use_bias=False, flatten=False,
                           in_units=num_heads * head_dim)
 
@@ -220,6 +241,9 @@ class Attention(HybridBlock):
         q = linear(u, self.query).reshape(b, t, hq, d)
         k = (linear(u, self.key) * self._key).reshape(b, t, hkv, d)
         v = linear(u, self.value).reshape(b, t, hkv, d)
+        if self._qk_norm:
+            q = rms_norm(q, raw(self.q_norm.weight), self._eps)
+            k = rms_norm(k, raw(self.k_norm.weight), self._eps)
         if self._rotary:
             q = rope(q, positions, self._theta)
             k = rope(k, positions, self._theta)
@@ -230,6 +254,9 @@ class Attention(HybridBlock):
                            preferred_element_type=jnp.float32) \
                 / math.sqrt(d)
             causal = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+            if self.window is not None:
+                causal &= jnp.arange(t)[None, :] \
+                    > jnp.arange(t)[:, None] - self.window
             w = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
             out = jnp.einsum("bhgjt,bthd->bjhgd", w.astype(v.dtype), v)
         else:
@@ -238,17 +265,24 @@ class Attention(HybridBlock):
             # imported where they are first traced, as models/gpt2.py
             # does, not with the package)
             from ..ops.pallas_attention import ragged_span_attention
-            cache = cache.write_decode(layer, k.transpose(0, 2, 1, 3),
+            # a window layer's pages are the slot's ring of them
+            pages = cache if self.window is None else cache.ring()
+            pages = pages.write_decode(layer, k.transpose(0, 2, 1, 3),
                                        v.transpose(0, 2, 1, 3))
             # int8 pages keep the query in its own type and hand the
             # kernel the per-(page, head) scales (both None otherwise)
             out = ragged_span_attention(
-                q if cache.quantized else q.astype(cache.k_pages.dtype),
-                cache.k_pages, cache.v_pages, cache.page_table,
+                q if pages.quantized else q.astype(pages.k_pages.dtype),
+                pages.k_pages, pages.v_pages, pages.page_table,
                 cache.length + 1, q_counts=cache.spans, layer=layer,
-                num_kv_heads=hkv, k_scale=cache.k_scale,
-                v_scale=cache.v_scale, **kernel_impl(cache)).astype(u.dtype)
+                num_kv_heads=hkv, k_scale=pages.k_scale,
+                v_scale=pages.v_scale, window=self.window,
+                **kernel_impl(cache)).astype(u.dtype)
+            cache = pages if self.window is None else cache.with_ring(pages)
         out = out.reshape(b, t, hq * d)
+        if self._gated:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                linear(u, self.gate).astype(jnp.float32))).astype(u.dtype)
         return linear(out, self.proj) * self._out, cache
 
 

@@ -50,7 +50,11 @@ from ..base import MXNetError
 from ..ops.kernel_paths import note_path
 
 __all__ = ["KVCache", "PagedKVCache", "gather_kv_pages",
-           "scatter_kv_pages"]
+           "scatter_kv_pages", "RING_LEAVES"]
+
+# the recurrent leaves that hold the window layers' rings of pages, K and V
+# (PagedKVCache.ring)
+RING_LEAVES = ("ring_k", "ring_v")
 
 
 def gather_kv_pages(k_pages, v_pages, idx, k_scale=None, v_scale=None):
@@ -547,6 +551,37 @@ class PagedKVCache:
         new = self.advance(0)
         new.recurrent = recurrent
         return new
+
+    def ring(self):
+        """The window layers' keys and values as a cache of their own.
+        They ride as the recurrent leaves RING_LEAVES, (L_win, B, R, S,
+        width) each: a slot's RING of R pages of S rows, fixed in size
+        whatever the sequence's length. Seen here as pools (L_win, B * R,
+        S, width) (the leading axes merged: no byte moves) behind an
+        arithmetic page table that puts logical page n of slot b at ring
+        page b * R + n mod R, as far as this cache's max_length reaches:
+        write_decode and the span kernels then work on a ring as on any
+        pool. A ring of R pages holds a window while R pages cover the
+        window and a dispatch's rows (ops/pallas_attention.window_pages):
+        the oldest page a window still reads is overwritten only after it
+        left every window."""
+        k, v = (self.recurrent[name] for name in RING_LEAVES)
+        L, B, R, S, width = k.shape
+        pages = -(-self.max_length // S)
+        table = jnp.arange(B, dtype=jnp.int32)[:, None] * R \
+            + jnp.arange(pages, dtype=jnp.int32)[None, :] % R
+        pool = lambda a: a.reshape(L, B * R, S, width)
+        return PagedKVCache(pool(k), pool(v), table, self.length,
+                            spans=self.spans, attn_impl=self.attn_impl)
+
+    def with_ring(self, ring):
+        """This cache with the rings of `ring` (a cache made by `ring()`)
+        back in its recurrent leaves."""
+        rec = self.recurrent
+        shape = rec[RING_LEAVES[0]].shape
+        return self.with_recurrent(dict(rec, **{
+            name: pool.reshape(shape) for name, pool
+            in zip(RING_LEAVES, (ring.k_pages, ring.v_pages))}))
 
     def tree_flatten(self):
         return (self.k_pages, self.v_pages, self.page_table,

@@ -542,7 +542,8 @@ def _span_live_pages(lengths, q_counts, S):
                      (lengths + q_counts - 1 + S - 1) // S)
 
 
-def _span_block_table(page_table, lengths, q_counts, S, KB):
+def _span_block_table(page_table, lengths, q_counts, S, KB, first=None,
+                      blocks=None):
     """(B, ceil(P / KB) * KB) int32: at [b, p*KB + i] the physical page
     that operand i of the span kernel holds at grid step (b, p). That is
     logical page p*KB + i of slot b while it is live (the extent
@@ -554,15 +555,40 @@ def _span_block_table(page_table, lengths, q_counts, S, KB):
     their first live page: the first slot's pages). Computed in XLA, a
     few small integer operations, identical in every layer of a
     program; the index_maps and the int8 kernel's scale lookup only
-    read it, so a scale always belongs to the page the DMA fetched."""
+    read it, so a scale always belongs to the page the DMA fetched.
+    With `first` (B,) and `blocks` (the window kernel's grid): logical
+    page first[b] + p*KB + i, over `blocks` steps a slot."""
     B, P = page_table.shape
-    steps = B * -(-P // KB)
+    steps = B * (-(-P // KB) if blocks is None else blocks)
     n_live = _span_live_pages(lengths, q_counts, S)
     j = jnp.arange(steps // B * KB)
-    live = (j[None, :] < n_live[:, None]).reshape(steps, KB)
-    phys = page_table[:, jnp.minimum(j, P - 1)].reshape(steps, KB)
+    if first is None:
+        live = (j[None, :] < n_live[:, None]).reshape(steps, KB)
+        phys = page_table[:, jnp.minimum(j, P - 1)].reshape(steps, KB)
+    else:
+        logical = first[:, None] + j[None, :]
+        live = (logical < n_live[:, None]).reshape(steps, KB)
+        phys = jnp.take_along_axis(page_table, jnp.minimum(logical, P - 1),
+                                   axis=1).reshape(steps, KB)
     held = lax.cummax(jnp.where(live, jnp.arange(steps)[:, None], 0), axis=0)
     return jnp.take_along_axis(phys, held, axis=0).reshape(B, -1)
+
+
+def window_pages(S, Sq, window):
+    """Pages the keys of Sq consecutive queries' windows can fall in: the
+    `window` keys up to the first query's and the Sq - 1 after it, W +
+    Sq - 1 positions starting anywhere in a page. A window layer's ring
+    of pages needs as many, and the window kernel's grid visits no
+    more."""
+    return (S - 1 + window + Sq - 2) // S + 1
+
+
+def _window_first_page(lengths, q_counts, S, window):
+    """(B,) the logical page of each slot's first key in a window: the
+    first query (at lengths - 1) sees back to lengths - window; 0 for an
+    idle slot."""
+    return jnp.where(q_counts == 0, 0,
+                     jnp.maximum(lengths - window, 0) // S)
 
 
 def _span_passes(H, D):
@@ -581,15 +607,20 @@ def _span_passes(H, D):
 
 
 def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
-                        S, Sq, H, D, KB, group=1, quant=False):
+                        S, Sq, H, D, KB, group=1, quant=False, window=None):
     """H KV heads, each against a block of Sq rows: `group` query heads
     stacked, Sq // group positions each (plain multi-head: group 1), and
     KB pages of keys a step. layer_ref: the pools' layer, which only the
-    page index maps read. pages_ref: _span_block_table's. refs:
-    [k_scale, v_scale] (int8 pages: the per-(page, head) float32 scales,
-    in SMEM beside it), q, KB pages of K, KB pages of V, out, and the
-    running max, denominator and numerator."""
+    page index maps read. pages_ref: _span_block_table's. refs: [first]
+    (with `window`: each slot's first logical page, that of grid step
+    0), [k_scale, v_scale] (int8
+    pages: the per-(page, head) float32 scales, in SMEM beside it), q, KB
+    pages of K, KB pages of V, out, and the running max, denominator and
+    numerator."""
     del layer_ref
+    first_ref = None
+    if window is not None:
+        first_ref, *refs = refs
     kscale_ref = vscale_ref = None
     if quant:
         kscale_ref, vscale_ref, *refs = refs
@@ -620,7 +651,8 @@ def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(p * KB < n_live)
+    @pl.when(p * KB < n_live if window is None
+             else first_ref[b] + p * KB < n_live)
     def _accumulate():
         # rows are query positions, columns token positions in this
         # block; row j's causal window is pos < length + j, and rows past
@@ -632,9 +664,13 @@ def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
         # within its own head's stack, built from compares alone
         for _ in range(1, group):
             rows = rows - jnp.where(rows >= Sq // group, Sq // group, 0)
-        cols = p * (KB * S) \
+        cols = (p * (KB * S) if window is None
+                else (first_ref[b] + p * KB) * S) \
             + lax.broadcasted_iota(jnp.int32, (Sq, KB * S), 1)
         valid = (cols < length + rows) & (rows < qn)
+        if window is not None:
+            # the `window` keys up to and including the row's own
+            valid &= cols >= length + rows - window
         if quant:
             phys = [pages_ref[b, p * KB + i] for i in range(KB)]
             head_of_lane = lax.broadcasted_iota(
@@ -697,13 +733,14 @@ def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
 
 
 def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
-                         k_scale=None, v_scale=None, layer=0):
+                         k_scale=None, v_scale=None, layer=0, window=None):
     """Dense XLA fallback/oracle for the multi-query kernel: full gather
     of `layer`'s pages out of the packed pools, unpacked to (H, D) here,
     per-position causal-offset mask — query j of slot b attends key
-    positions < lengths[b] + j. int8 pools dequant on the gathered view
-    with the per-(page, head) scales — the same math the fused kernel
-    epilogue applies in VMEM."""
+    positions < lengths[b] + j (and with `window`, >= lengths[b] + j -
+    window). int8 pools dequant on the gathered view with the per-(page,
+    head) scales — the same math the fused kernel epilogue applies in
+    VMEM."""
     B, Sq, H, D = q.shape
     P, S = page_table.shape[1], k_pages.shape[2]
     Hkv = k_pages.shape[3] // D
@@ -723,8 +760,11 @@ def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
     s = jnp.einsum("bjhd,bthd->bjht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     limit = lengths[:, None] + jnp.arange(Sq)[None, :]     # (B, Sq)
-    mask = (jnp.arange(P * S)[None, None, :]
-            < limit[:, :, None])[:, :, None, :]            # (B, Sq, 1, T)
+    pos = jnp.arange(P * S)[None, None, :]
+    mask = pos < limit[:, :, None]
+    if window is not None:
+        mask &= pos >= limit[:, :, None] - window
+    mask = mask[:, :, None, :]                             # (B, Sq, 1, T)
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(s - m))
@@ -736,14 +776,14 @@ def _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths, scale,
 
 def _ragged_span_reference(q, k_pages, v_pages, page_table, lengths,
                            q_counts, scale, k_scale=None, v_scale=None,
-                           layer=0):
+                           layer=0, window=None):
     """Dense XLA fallback/oracle for the span kernel: the multi-query
     causal-offset math, with query rows >= q_counts[b] dead — they emit
     exact zeros (the row-mask contract the unified dispatch relies on:
     garbage rows of a mixed batch can never leak into live output)."""
     out = _ragged_mq_reference(q, k_pages, v_pages, page_table, lengths,
                                scale, k_scale=k_scale, v_scale=v_scale,
-                               layer=layer)
+                               layer=layer, window=window)
     rows = jnp.arange(q.shape[1])[None, :] < q_counts[:, None]  # (B, Sq)
     return jnp.where(rows[:, :, None, None], out,
                      jnp.zeros_like(out))
@@ -752,7 +792,7 @@ def _ragged_span_reference(q, k_pages, v_pages, page_table, lengths,
 def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                           q_counts=None, scale=None, impl="auto",
                           interpret=False, k_scale=None, v_scale=None,
-                          layer=0, num_kv_heads=None):
+                          layer=0, num_kv_heads=None, window=None):
     """Span ragged paged-attention: ONE fixed-shape program for mixed
     prefill-chunk / decode / speculative-verify / idle work.
 
@@ -790,6 +830,18 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
                     pools; both set or both None. The
                     Pallas path fuses the dequant into the page DMA
                     epilogue; the XLA path dequants the gathered view.
+    window:         None, or W: query j sees only the W keys up to and
+                    including its own position (positions >= lengths[b]
+                    + j - W as well). The call is then a kernel of its
+                    own, `window_span_attention`: its grid spans the
+                    window_pages(S, Sq, W) pages a slot's windows can
+                    fall in, from the slot's first windowed page (a
+                    scalar-prefetch operand), and no page before it is
+                    visited. page_table maps LOGICAL pages as ever, so a
+                    ring of pages (logical page n at ring page n mod R,
+                    R >= window_pages) is read through an arithmetic
+                    table. With None the kernel, its grid and its
+                    operands are the plain span kernel's.
     impl: 'auto' (kernel on TPU; dense XLA elsewhere, or on TPU with a
     warning when ragged_supported says no), 'pallas' (force the kernel;
     interpret=True runs it on CPU), 'xla'.
@@ -808,29 +860,37 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
             f"{k_pages.dtype} pages {k_pages.shape[3]} wide")
     if q_counts is None:
         q_counts = jnp.full((B,), Sq, jnp.int32)
+    name = "ragged_span_attention" if window is None \
+        else "window_span_attention"
     impl = _resolve_ragged_impl(impl, interpret, q, k_pages)
-    note_path("ragged_span_attention", impl)
+    note_path(name, impl)
     if impl == "xla":
         return _ragged_span_reference(q, k_pages, v_pages, page_table,
                                       lengths, q_counts, s,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      layer=layer)
+                                      layer=layer, window=window)
     if impl != "pallas":
         raise ValueError(f"unknown ragged attention impl {impl!r}")
     Sr = G * Sq
-    KB = _span_block_pages(S, Sr, P)
-    note_tile("ragged_span_attention", pages=KB, keys=KB * S, rows=Sr)
+    # the pages a slot's grid steps may visit
+    reach = P if window is None else min(P, window_pages(S, Sq, window))
+    KB = _span_block_pages(S, Sr, reach)
+    note_tile(name, pages=KB, keys=KB * S, rows=Sr)
     qp = q.reshape(B, Sq, H * D) if G == 1 else \
         q.reshape(B, Sq, H, G, D).transpose(0, 3, 1, 2, 4) \
         .reshape(B, Sr, H * D)
     lengths = lengths.astype(jnp.int32)
     q_counts = q_counts.astype(jnp.int32)
+    first = () if window is None else (
+        _window_first_page(lengths, q_counts, S, window),)
     pages = _span_block_table(page_table.astype(jnp.int32), lengths,
-                              q_counts, S, KB)
+                              q_counts, S, KB, *first,
+                              blocks=pl.cdiv(reach, KB))
 
     # the index_maps take the grid position and then every scalar-prefetch
-    # operand: the layer, the block table, the lengths, the counts and,
-    # over int8 pages, the two scale leaves
+    # operand: the layer, the block table, the lengths, the counts, with a
+    # window each slot's first page and, over int8 pages, the two scale
+    # leaves
     def page_index(i):
         return lambda b, p, layer, pages, *_: (
             layer[0], pages[b, p * KB + i], 0, 0)
@@ -845,8 +905,8 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
     scales = (k_scale[layer].astype(jnp.float32),
               v_scale[layer].astype(jnp.float32)) if quant else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 + len(scales),
-        grid=(B, pl.cdiv(P, KB)),
+        num_scalar_prefetch=4 + len(first) + len(scales),
+        grid=(B, pl.cdiv(reach, KB)),
         in_specs=[pl.BlockSpec((1, Sr, H * D), q_index)] + 2 * page_specs,
         out_specs=pl.BlockSpec((1, Sr, H * D), q_index),
         scratch_shapes=[
@@ -856,15 +916,17 @@ def ragged_span_attention(q, k_pages, v_pages, page_table, lengths,
         ],
     )
     kernel = functools.partial(_ragged_span_kernel, scale=s, S=S, Sq=Sr,
-                               H=H, D=D, KB=KB, group=G, quant=quant)
+                               H=H, D=D, KB=KB, group=G, quant=quant,
+                               window=window)
     operands = (jnp.asarray(layer, jnp.int32).reshape(1), pages, lengths,
-                q_counts, *scales, qp, *([k_pages] * KB), *([v_pages] * KB))
+                q_counts, *first, *scales, qp, *([k_pages] * KB),
+                *([v_pages] * KB))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sr, H * D), q.dtype),
         interpret=interpret,
-        name="ragged_span_attention",
+        name=name,
         compiler_params=_compiler_params(
             interpret, dimension_semantics=("parallel", "arbitrary")),
     )(*operands)

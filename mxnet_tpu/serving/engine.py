@@ -91,7 +91,7 @@ from ..base import MXNetError
 from ..gluon.block import _trace_channel
 from ..ops.kernel_paths import PATHS as _KERNEL_PATHS, \
     TILES as _KERNEL_TILES
-from ..models.kv_cache import (PagedKVCache, gather_kv_pages,
+from ..models.kv_cache import (RING_LEAVES, PagedKVCache, gather_kv_pages,
                                scatter_kv_pages)
 from ..ndarray.ndarray import NDArray
 from ..telemetry import server as _tserver
@@ -368,6 +368,16 @@ def _engine_metrics(eid):
             "(state_spec()['loop_steps']; 1 for a model that runs each "
             "layer once): serving_kv_layers counts every pass's cache "
             "layers", _E),
+        "kv_window_layers": g(
+            "serving_kv_window_layers",
+            "layers whose attention sees a window of keys and keeps them "
+            "in a per-slot ring of pages among the recurrent leaves "
+            "(state_spec()['window_layers']; 0 for a model without)", _E),
+        "kv_ring_pages": g(
+            "serving_kv_ring_pages",
+            "pages a window layer's ring holds a slot "
+            "(state_spec()['ring_pages']: the window and one dispatch's "
+            "rows; 0 for a model without window layers)", _E),
         "expert_weight_bytes": g(
             "serving_expert_weight_bytes",
             "device bytes of the routed experts this engine's model holds "
@@ -693,6 +703,13 @@ class ServingEngine:
                  "kv_dtype": kv_dtype is not None,
                  "weight_dtype": weight_dtype is not None,
                  "adapter_pool": adapter_pool is not None}
+        # what the model itself says its state or its blocks do not carry
+        # (state_spec()["refuses"]: feature -> why), before what the kinds
+        # of state below refuse on their own
+        for feature, why in (slot_state.get("refuses") or {}).items():
+            if asked[feature]:
+                raise MXNetError(f"{feature} is not supported for "
+                                 f"{type(model).__name__}: {why}")
         if self._one_pool:
             # what splits a page by head, scales it by head or ships it as
             # a K and a V page has nothing to hold on to
@@ -720,12 +737,6 @@ class ServingEngine:
                         "beside its KV pages cannot be shared by prefix, "
                         "verified and rolled back, spilled, sharded, "
                         "quantized or adapted yet")
-        # what the model itself says its blocks do not carry
-        # (state_spec()["refuses"]: feature -> why)
-        for feature, why in (slot_state.get("refuses") or {}).items():
-            if asked[feature]:
-                raise MXNetError(f"{feature} is not supported for "
-                                 f"{type(model).__name__}: {why}")
         # a model that runs its stack several times a dispatch says how
         # often (`loop_steps`): its `kv_layers` then count every pass's
         self._loop_steps = int(slot_state.get("loop_steps", 1))
@@ -921,6 +932,15 @@ class ServingEngine:
             for name, (shape, rdt) in slot_state["recurrent"].items()}
         self._rec_bytes = sum(int(np.prod(shape)) * rdt.itemsize
                               for shape, rdt in rec_shapes.values())
+        # window layers keep their keys in rings of pages among the
+        # recurrent leaves (models/kv_cache.py RING_LEAVES), counted in
+        # _rec_bytes as well: `window_layers` of them, `ring_pages` a slot
+        self._window_layers = int(slot_state.get("window_layers", 0))
+        self._ring_pages = int(slot_state.get("ring_pages", 0))
+        self._ring_bytes = sum(
+            int(np.prod(shape)) * rdt.itemsize
+            for name, (shape, rdt) in rec_shapes.items()
+            if name in RING_LEAVES)
         if self._hbm_budget is not None:
             # under tp each CHIP holds 1/tp of every page (the head
             # axis shards), so the budget — the quantity that actually
@@ -1319,6 +1339,9 @@ class ServingEngine:
             "kv_layers": self._kv_layers,
             "recurrent_layers": self._rec_layers,
             "loop_steps": self._loop_steps,
+            "kv_window_layers": self._window_layers,
+            "kv_ring_pages": self._ring_pages,
+            "kv_ring_bytes": self._ring_bytes,
             "expert_weight_bytes": self._expert_weight_bytes,
             "model_counters": {name: total.tolist() for name, total
                                in self._model_totals.items()},
@@ -1356,6 +1379,8 @@ class ServingEngine:
         self._metrics["kv_layers"].set(self._kv_layers)
         self._metrics["recurrent_layers"].set(self._rec_layers)
         self._metrics["loop_steps"].set(self._loop_steps)
+        self._metrics["kv_window_layers"].set(self._window_layers)
+        self._metrics["kv_ring_pages"].set(self._ring_pages)
         self._metrics["expert_weight_bytes"].set(self._expert_weight_bytes)
         for wd, nb in self._weight_bytes.items():
             self._wbytes_fam.labels(self._eid, wd).set(nb)

@@ -487,3 +487,167 @@ def test_ouro_unified_program_carries_its_pools_in_place_through_the_loop(
     # the check's own one-slot cache (1.611 GB) beside it still fits
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + pools // slots + 1e9 < 16.91e9
+
+
+def test_trinity_unified_program_keeps_pool_and_rings_in_place(one_chip):
+    """trinity_mini.longdoc32k_backlog's whole unified greedy program at
+    the cell's own engine block and model kwargs, weights described: ONE
+    full layer's whole-depth K and V pools and the four window layers'
+    rings of 34 pages a slot, all one donated pytree aliased to the outputs
+    with the counters; fourteen Mosaic calls (a page write and a span
+    kernel for the full layer, a page write into its ring and a
+    `window_span_attention` for each window layer, four grouped expert
+    feed-forwards), none refused by the chip's compiler; no copy, slice or
+    update as large as a ring or a layer of the pool; temporaries under 2%
+    of the chip; and it leaves more than 1 GB spare. Prints the arguments
+    and temporaries that `PERF.md` cites. The engine is BUILT with one page
+    a slot (its pool would be 2 GB of this host's memory) and the program
+    lowered at the cell's 513: a program's shapes are its arguments'."""
+    import json
+    from mxnet_tpu import models
+    from mxnet_tpu.serving import ServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "trinity_mini.json")) as f:
+        cfg = json.load(f)
+    kw, ekw = cfg["model"]["kwargs"], cfg["engine"]
+    net = models.TrinityForCausalLM(models.trinity_mini_config(**kw))
+    for p in net.collect_params().values():
+        p._data = _described(p.shape, kw["dtype"])
+    slots, width, page = (ekw["num_slots"], ekw["chunk_tokens"],
+                          ekw["page_size"])
+    per_slot = ekw["max_length"] // page
+    eng = ServingEngine(net, attn_impl="pallas",
+                        **{**ekw, "max_length": page})
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=one_chip)
+    one = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=one_chip)
+    row = lambda dtype: one((slots,), dtype)
+    state = eng._device_state()
+    assert sorted(state) == ["k", "rec", "v"]
+    ring = (4, slots, 34, page, 4 * 128)
+    assert {k: v.shape for k, v in state["rec"].items()} == {
+        "ring_k": ring, "ring_v": ring, "moe": (4, 5), "live_rows": (2,),
+        "window_keys": (2,)}
+    pool = one((1, slots * per_slot, page, 4 * 128), kw["dtype"])
+    described = {"k": pool, "v": pool,
+                 "rec": jax.tree_util.tree_map(sds, state["rec"])}
+    st = eng.stats
+    ring_bytes = 2 * int(np.prod(ring)) * 2
+    assert st["kv_ring_bytes"] == st["recurrent_state_bytes"] == ring_bytes
+    assert round(ring_bytes / 1e9, 3) == 0.570
+    assert (st["kv_layers"], st["kv_window_layers"], st["kv_ring_pages"]) \
+        == (1, 4, 34)
+    before = (dict(kernel_paths.PATHS), dict(kernel_paths.TILES))
+    compiled = eng._build_unified(greedy_only=True).lower(
+        tuple(sds(p.data()._data) for p in eng._params), described,
+        one((slots, per_slot), "int32"), one((slots * per_slot,), "bool"),
+        *[sds(a) for a in eng._dstate[:11]], one((slots, width), "int32"),
+        row("int32"), row("bool"), row("bool")).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 14
+    assert _since(kernel_paths.PATHS, before[0]) == {
+        ("kv_page_write", "pallas"): 5, ("expert_ffn", "pallas"): 4,
+        ("ragged_span_attention", "pallas"): 1,
+        ("window_span_attention", "pallas"): 4}
+    # 8 query heads a KV head stack 512 rows: 512 KiB of float32 scores a
+    # head leave room for four pages a grid step; the window kernel's grid
+    # spans the ring's 34 pages, 9 steps a slot, and not the 513 a slot has
+    assert _since(kernel_paths.TILES, before[1]) == {
+        ("expert_ffn", "rows=128,hidden=1024"): 4,
+        ("ragged_span_attention", "pages=4,keys=256,rows=512"): 1,
+        ("window_span_attention", "pages=4,keys=256,rows=512"): 4}
+    assert len(re.findall(r"%window_span_attention[\w.\-]* = ", hlo)) == 4
+    assert _expert_ffn_in_vmem(hlo) == []
+    assert f"[{slots},{kw['vocab_size']}]" in hlo
+    ring_elems = int(np.prod(ring[1:]))
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|slice|dynamic-slice|transpose|"
+        r"dynamic-update-slice)\(", hlo)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= ring_elems]
+    assert moved == []
+    mem = compiled.memory_analysis()
+    leaves = jax.tree_util.tree_leaves(described)
+    # every leaf aliased; the three counters pad to a tile each
+    assert 0 <= mem.alias_size_in_bytes - sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves) < 3 * 4096
+    assert mem.temp_size_in_bytes < 0.02 * 16e9
+    print(f"trinity_mini unified greedy program, {slots} slots, 5 layers: "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB of arguments, "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB of temporaries, "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB aliased")
+    assert mem.argument_size_in_bytes > 11e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + 1e9 \
+        < 16.91e9
+
+
+# The span kernel with no window, lowered for a described v5e at the cells'
+# geometries: sha256 of its Mosaic module printed WITHOUT debug information
+# (a location names the source file and line, which move with any edit) and
+# of the program around it with the module's bytes taken out, as the commit
+# before the window kernel lowered them. A window is a kernel of its own
+# (`window_span_attention`); these are every other model's, and must not
+# move. A change to the span kernel itself updates them.
+SPAN_LOWERED = {
+    # (slots, rows, query heads, KV heads, head size, pages a slot, pages)
+    (16, 64, 20, 20, 64, 16, "bfloat16"): (
+        "0e65351ec13e9c6256285a5db3c1c77f0085b82a18cc7eae6822cf6c99d402b9",
+        "02f695fefaccb1d5f9849dcb9857c02081eeb6aea595ca73191a9427ac18bf4d"),
+    (5, 64, 16, 16, 128, 16, "bfloat16"): (
+        "505949d0c2c29ff61fb89be287079fb5e60a6a757e453a9f8ecb74d3a471e8d7",
+        "99517d3e4b5965f9e14398206e04d546ee4188ea35fa6e2e32dbf4db82175fa9"),
+    (32, 64, 20, 4, 128, 10, "bfloat16"): (
+        "919607d535cc0b60d62d499f5fd635589bd6f7c8d8dd36129b3a8d2806fee4a8",
+        "b4ad310367d9e08fa6bb4b1bf2d0b0a7079fa4f9971144cbb8706d71dc6eb05a"),
+    (32, 64, 32, 4, 128, 513, "bfloat16"): (
+        "62fc228340ddf4875902d7064d2ddffd7b9541967d7b1edad27d5235fe90f656",
+        "58c322003ca73b2d13950c84770dd3b3bcd4bc2716f6e807ce5820d48dbe60a3"),
+    (16, 64, 20, 20, 64, 16, "int8"): (
+        "227f7fd33dbea46b5a9bc3b0e009c4da3ee3d00cc38d3e5df7e966cf7df3be3c",
+        "f959c8c6088ea9bbbee5d0db74d468ae82c95f474fd2704a8c686ee1ea4caeb1"),
+}
+
+
+def _mosaic_modules(text):
+    """(the Mosaic modules of a lowered program's custom calls, printed
+    without debug information; the program's text with their bytes out)."""
+    import base64
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    body = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)'
+    modules = []
+    for blob in re.findall(body, text):
+        ctx = jmlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        tpu.register_dialect(ctx)
+        with ctx:
+            modules.append(ir.Module.parse(base64.b64decode(blob))
+                           .operation.get_asm(enable_debug_info=False))
+    return modules, re.sub(body, "", text)
+
+
+@pytest.mark.parametrize("geometry", sorted(SPAN_LOWERED, key=str),
+                         ids=lambda g: "x".join(map(str, g)))
+def test_the_span_kernel_without_a_window_lowers_as_before(one_chip,
+                                                           geometry):
+    import hashlib
+    B, Sq, Hq, Hkv, D, P, dtype = geometry
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                                 sharding=one_chip)
+    quant = dtype == "int8"
+    pool = sds((2, B * P, 64, Hkv * D), dtype)
+    scale = sds((2, B * P, Hkv), "float32") if quant else None
+    text = jax.jit(lambda q, kp, vp, t, l, c, ks, vs:
+                   pa.ragged_span_attention(
+                       q, kp, vp, t, l, q_counts=c, impl="pallas", layer=1,
+                       num_kv_heads=Hkv, k_scale=ks, v_scale=vs)).lower(
+        sds((B, Sq, Hq, D), "float32" if quant else "bfloat16"), pool, pool,
+        sds((B, P), "int32"), sds((B,), "int32"), sds((B,), "int32"),
+        scale, scale).as_text()
+    modules, around = _mosaic_modules(text)
+    assert len(modules) == 1 and "module @ragged_span_attention" in \
+        modules[0]
+    digest = lambda s: hashlib.sha256(s.encode()).hexdigest()
+    assert (digest(modules[0]), digest(around)) == SPAN_LOWERED[geometry]
