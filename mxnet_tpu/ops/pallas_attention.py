@@ -418,10 +418,15 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # the position mask below drops the keys.
 #
 # For each KV head the step runs ONCE over the block: (Sr, D) x (D, KB*S)
-# scores in float32, one mask, one row max / exp / row sum over
-# (Sr, KB*S), (Sr, KB*S) x (KB*S, D) with the probabilities cast to the
-# page dtype, one rescale of the (Sr, D) accumulator. The block's keys of
-# a head are the KB pages' column slices set one under another
+# scores in float32, one row max / exp / row sum over (Sr, KB*S),
+# (Sr, KB*S) x (KB*S, D) with the probabilities cast to the page dtype,
+# one rescale of the (Sr, D) accumulator. The step's mask compares a
+# (1, KB*S) vector of key positions with a (Sr, 1) vector of query
+# positions, once for all the heads: no whole tile of positions is built.
+# A row's running max and denominator stay as the scratch holds them, the
+# row's value in all 128 lanes, so that no step moves a lane to widen
+# them (_lanes). The block's keys of a head are the KB pages' column
+# slices set one under another
 # (sublane-aligned, so the concatenation moves no lane); int8 pages are
 # widened and multiplied by their own (page, head) scale first, read at
 # the block table's page, so HBM traffic stays one byte an element.
@@ -442,9 +447,11 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 # query tokens: a decode slot 1, a speculative verify S, a prefill chunk
 # C, an idle slot 0. Query row j of slot b sits at absolute position
 # lengths[b]-1+j, so it may attend key positions < lengths[b]+j — the
-# per-position CAUSAL OFFSET — and rows >= q_counts[b] are dead: they
-# accumulate nothing and emit exact zeros. The grid skips dead rows AND
-# dead blocks (a slot's page extent stretches only to
+# per-position CAUSAL OFFSET — and rows >= q_counts[b] are dead: the
+# last step writes them exact zeros, whatever they accumulated (rows meet
+# nowhere in the softmax or the products, so nothing of a dead row, not
+# even a non-finite query, reaches a live one). The grid skips dead
+# blocks (a slot's page extent stretches only to
 # lengths[b] + q_counts[b] - 1; an idle slot visits no block at all);
 # inside the last live block, keys past the extent are masked by position.
 #
@@ -591,6 +598,22 @@ def _window_first_page(lengths, q_counts, S, window):
                      jnp.maximum(lengths - window, 0) // S)
 
 
+def _query_positions(rows, group):
+    """(rows, 1) int32: each stacked row's query position, its index within
+    its own head's stack of rows // group."""
+    return lax.rem(lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
+                   rows // group)
+
+
+def _lanes(x, n):
+    """(rows, 128) values, each row's in all its lanes, as (rows, n): a
+    lane slice, or whole copies side by side. Bit for bit the row's value
+    broadcast, with no lane moved."""
+    if n <= x.shape[1]:
+        return x[:, :n]
+    return jnp.concatenate([x] * -(-n // x.shape[1]), axis=1)[:, :n]
+
+
 def _span_passes(H, D):
     """(heads, rolled): how the span kernel walks its H packed heads. A
     pass takes `heads` of them, up to _SPAN_PASS_HEADS, whose columns are
@@ -655,22 +678,17 @@ def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
              else first_ref[b] + p * KB < n_live)
     def _accumulate():
         # rows are query positions, columns token positions in this
-        # block; row j's causal window is pos < length + j, and rows past
-        # the slot's span are fully masked (they emit zeros). A page of
-        # the block past the live extent holds another page's keys: its
+        # block; row j's causal window is pos < length + j. A page of the
+        # block past the live extent holds another page's keys: its
         # positions are >= length + qn - 1, so the same mask drops them
-        rows = lax.broadcasted_iota(jnp.int32, (Sq, KB * S), 0)
-        # under grouped KV heads a row's query position is its index
-        # within its own head's stack, built from compares alone
-        for _ in range(1, group):
-            rows = rows - jnp.where(rows >= Sq // group, Sq // group, 0)
-        cols = (p * (KB * S) if window is None
-                else (first_ref[b] + p * KB) * S) \
-            + lax.broadcasted_iota(jnp.int32, (Sq, KB * S), 1)
-        valid = (cols < length + rows) & (rows < qn)
+        # for every live row (dead rows are zeroed when the slot emits)
+        pos = _query_positions(Sq, group)                    # (Sq, 1)
+        cols = (p * KB if window is None else first_ref[b] + p * KB) * S \
+            + lax.broadcasted_iota(jnp.int32, (1, KB * S), 1)
+        valid = cols < length + pos
         if window is not None:
             # the `window` keys up to and including the row's own
-            valid &= cols >= length + rows - window
+            valid &= cols >= length + pos - window
         if quant:
             phys = [pages_ref[b, p * KB + i] for i in range(KB)]
             head_of_lane = lax.broadcasted_iota(
@@ -701,29 +719,33 @@ def _ragged_span_kernel(layer_ref, pages_ref, len_ref, qc_ref, *refs, scale,
                                     (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
                 s = jnp.where(valid, s * scale, NEG_INF)   # (Sq, KB*S)
-                m_prev = m_ref[h][:, :1]                   # (Sq, 1)
-                l_prev = l_ref[h][:, :1]
+                m_prev = m_ref[h]                          # (Sq, 128)
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s, axis=-1, keepdims=True))
-                e = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+                m_keys = _lanes(m_new, KB * S)
+                e = jnp.where(m_keys <= NEG_INF / 2, 0.0, jnp.exp(s - m_keys))
                 alpha = jnp.where(m_new <= NEG_INF / 2, 1.0,
                                   jnp.exp(m_prev - m_new))
                 v = vs[:, c0:c1]
                 pv = lax.dot_general(e.astype(v.dtype), v,
                                      (((1,), (0,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-                acc_ref[h] = acc_ref[h] * alpha + pv
-                l_new = l_prev * alpha + jnp.sum(e, axis=-1, keepdims=True)
-                l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-                m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                acc_ref[h] = acc_ref[h] * _lanes(alpha, D) + pv
+                l_ref[h] = l_ref[h] * alpha + jnp.sum(e, axis=-1,
+                                                      keepdims=True)
+                m_ref[h] = m_new
 
         each_pass(attend)
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _emit():
+        # a dead row (past the slot's count) emits exact zeros, whatever
+        # it accumulated
+        dead = _query_positions(Sq, group) >= qn              # (Sq, 1)
+
         def emit(h0, columns):
-            outs = [acc_ref[h0 + t]
-                    / jnp.maximum(l_ref[h0 + t][:, :1], 1e-30)
+            outs = [jnp.where(dead, 0.0, acc_ref[h0 + t] / _lanes(
+                jnp.maximum(l_ref[h0 + t], 1e-30), D))
                     for t in range(heads)]
             o_ref[0, :, columns] = (
                 outs[0] if heads == 1

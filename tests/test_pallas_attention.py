@@ -6,6 +6,8 @@ Parity target: dot_product_attention semantics (ops/nn.py) — the fused
 kernel must be a drop-in for the XLA path including key-padding masks,
 causal masking, and fully-masked-row zeros.
 """
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -264,3 +266,171 @@ def test_packed_unsupported_head_dim_gated():
     q, k, v = _qkv(B=2, H=3, Tq=64, Tk=64, D=32)
     assert not pa.supported(_to_bthd(q), _to_bthd(k), None, layout="BTHD")
     assert pa.supported(q, k, None)  # BHTD path unaffected
+
+
+# ---------------------------------------------------------------------------
+# The span kernel's step builds its mask from a row vector of query
+# positions and a column vector of key positions, and dead rows are zeroed
+# when the slot emits. Eight query heads a KV head of 128 (64 stacked
+# rows), pages of 8, seven pages a slot: at the block the rule picks (4
+# pages) and at a page a step (7 steps a slot), so that slots hold blocks
+# every live row sees whole, blocks at the causal edge or a window's start,
+# and both.
+# ---------------------------------------------------------------------------
+
+SPAN_LENGTHS = [1, 45, 30, 20, 41, 49]     # query 0's position + 1
+# a first chunk, a decode, a short chunk, an idle slot, two full chunks
+SPAN_COUNTS = [8, 1, 3, 0, 8, 8]
+
+
+def _span_case(pages="float32", seed=0, Hq=16, Hkv=2, D=128, P=7, S=8,
+               Sq=8):
+    """(q, k pool, v pool, table, the scales kwargs) for len(SPAN_LENGTHS)
+    slots, two layers a pool, the table a permutation."""
+    rng = np.random.default_rng(seed)
+    B = len(SPAN_LENGTHS)
+    q = jnp.asarray(rng.standard_normal((B, Sq, Hq, D)), jnp.float32)
+    kv = [rng.standard_normal((2, B * P, S, Hkv * D)) for _ in range(2)]
+    table = jnp.asarray(rng.permutation(B * P).reshape(B, P), jnp.int32)
+    if pages == "float32":
+        return q, *(jnp.asarray(x, jnp.float32) for x in kv), table, {}
+    codes, scales = [], []
+    for x in kv:                    # a scale a (page, head), as stored
+        x = x.reshape(2, B * P, S, Hkv, D)
+        sc = np.abs(x).max(axis=(2, 4)) / 127.0
+        codes.append(jnp.asarray(np.round(x / sc[:, :, None, :, None])
+                                 .reshape(2, B * P, S, Hkv * D), jnp.int8))
+        scales.append(jnp.asarray(sc, jnp.float32))
+    return q, *codes, table, dict(k_scale=scales[0], v_scale=scales[1])
+
+
+def _span_call(q, kp, vp, table, kw, window=None, **extra):
+    return np.asarray(pa.ragged_span_attention(
+        q, kp, vp, table, jnp.asarray(SPAN_LENGTHS, jnp.int32),
+        q_counts=jnp.asarray(SPAN_COUNTS, jnp.int32), impl="pallas",
+        interpret=True, layer=1, num_kv_heads=kp.shape[-1] // q.shape[-1],
+        window=window, **kw, **extra))
+
+
+def _dense_span(q, kp, vp, table, kw, window=None):
+    """Query j of slot b (position L - 1 + j) over the keys at positions
+    max(0, L + j - window) .. L - 1 + j, each read from its page, by a
+    plain float64 softmax; dead rows zero."""
+    B, Sq, Hq, D = q.shape
+    S, Hkv = kp.shape[2], kp.shape[3] // D
+    k, v = (np.asarray(x[1], np.float64).reshape(-1, S, Hkv, D)
+            for x in (kp, vp))
+    if kw:
+        k = k * np.asarray(kw["k_scale"][1])[:, None, :, None]
+        v = v * np.asarray(kw["v_scale"][1])[:, None, :, None]
+    t = np.asarray(table)
+    out = np.zeros(q.shape)
+    for b, (L, n) in enumerate(zip(SPAN_LENGTHS, SPAN_COUNTS)):
+        for j in range(n):
+            lo = 0 if window is None else max(0, L + j - window)
+            pos = np.arange(lo, L + j)
+            K, V = (x[t[b, pos // S], pos % S] for x in (k, v))
+            for h in range(Hq):
+                s = K[:, h // (Hq // Hkv)] @ np.asarray(q[b, j, h]) \
+                    / np.sqrt(D)
+                w = np.exp(s - s.max())
+                out[b, j, h] = (w / w.sum()) @ V[:, h // (Hq // Hkv)]
+    return out
+
+
+def _dead():
+    return np.arange(8)[None, :] >= np.asarray(SPAN_COUNTS)[:, None]
+
+
+@pytest.mark.parametrize("kb", [None, 1])
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("pages", ["float32", "int8"])
+def test_span_kernel_grouped_and_window_calls(monkeypatch, pages, window,
+                                              kb):
+    """Grouped (8 query heads a KV head of 128) and window calls over
+    slots whose blocks are seen whole, cut and both, a decode slot, a
+    short chunk and an idle one, float and int8 pages: the kernel against
+    its XLA form and a dense float64 softmax, dead rows exact zeros."""
+    if kb is not None:
+        monkeypatch.setattr(pa, "_span_block_pages", lambda S, Sr, P: kb)
+    q, kp, vp, table, kw = _span_case(pages)
+    out = _span_call(q, kp, vp, table, kw, window)
+    ref = pa._ragged_span_reference(
+        q, kp, vp, table, jnp.asarray(SPAN_LENGTHS, jnp.int32),
+        jnp.asarray(SPAN_COUNTS, jnp.int32), 1 / np.sqrt(128), layer=1,
+        window=window, **kw)
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out, _dense_span(q, kp, vp, table, kw,
+                                                window), atol=5e-5)
+    dead = _dead()
+    assert (out[dead] == 0).all() and not np.signbit(out[dead]).any()
+    assert (np.abs(out[~dead]).max(axis=-1) > 0).all()
+
+
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("bad", [np.inf, -np.inf, np.nan])
+def test_span_kernel_dead_rows_are_zeros_whatever_their_queries(bad,
+                                                                window):
+    """Dead rows accumulate whatever they meet and are zeroed when the
+    slot emits: a non-finite query in every dead row (the idle slot's
+    too) leaves them exact zeros and the live rows the same bits."""
+    q, kp, vp, table, kw = _span_case()
+    clean = _span_call(q, kp, vp, table, kw, window)
+    dirty = _span_call(jnp.where(jnp.asarray(_dead())[:, :, None, None],
+                                 bad, q), kp, vp, table, kw, window)
+    dead = _dead()
+    assert (dirty[dead] == 0).all() and not np.signbit(dirty[dead]).any()
+    assert (dirty.view(np.uint32) == clean.view(np.uint32)).all()
+
+
+@pytest.mark.parametrize("kb", [None, 1])
+def test_span_kernel_a_window_as_wide_as_every_position_changes_nothing(
+        monkeypatch, kb):
+    """A window of 64 keys over 56 positions: the same blocks, a mask
+    that drops nothing more; the same bits."""
+    if kb is not None:
+        monkeypatch.setattr(pa, "_span_block_pages", lambda S, Sr, P: kb)
+    q, kp, vp, table, kw = _span_case()
+    plain = _span_call(q, kp, vp, table, kw)
+    wide = _span_call(q, kp, vp, table, kw, window=64)
+    assert (wide.view(np.uint32) == plain.view(np.uint32)).all()
+
+
+# sha256 of the whole output of _span_call on _span_case(pages, seed=5) at
+# the kernel's own scale, as the kernel that built a whole (rows, keys) tile
+# of positions a step computed it (the commit before the mask was built
+# from vectors): the live rows' numbers and the dead rows' zeros did not
+# move by a bit. Keyed by (pages, window, pages a step; None: the rule's)
+PARENT_BITS = {
+    ("float32", None, None):
+        "ff988d9bae381711d5e73ea0b419c16632341755093eeeaaa6e9b1c3738d50a3",
+    ("float32", None, 1):
+        "0862a64aeb7311be33dcf91196c819645ce57603fa072ac066c10d830fe16ee0",
+    ("float32", 24, None):
+        "33ca53d7ace7a4e21a3b2594f053c5c2f658c7d7c35ccbd12c80c099a7c94885",
+    ("float32", 24, 1):
+        "ad48604cffa4a04ddc93e0e03c2e654d6bcbb8ab32f971aa5293f8ad77c185dd",
+    ("int8", None, None):
+        "9997bf891240ad7af6db4e60ba4f95760f9dbdd914637535cb44ef8eda368c40",
+    ("int8", None, 1):
+        "9fd859e2fed248ae376d0732abdf26ad296c117bd24958701ed686b3e5ba60bb",
+    ("int8", 24, None):
+        "1bcb478c56480c333b6511f14b1cb5f69f5fcadf1ba2bec5db62e070cb98d189",
+    ("int8", 24, 1):
+        "e6bd3a7bb9d638589865dee07e3036e4a7d29f1d1eab64fc92461e8c4c4945e9",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_BITS, key=str),
+                         ids=lambda c: "-".join(map(str, c)))
+def test_span_kernel_output_is_the_tile_masked_kernels_bits(monkeypatch,
+                                                            case):
+    """The vector mask, the row statistics kept in their lanes and the
+    dead rows zeroed at the emit give every row the bits of the kernel
+    that masked a whole tile of positions: a select that keeps what the
+    tile's mask kept, the same exp, the same sums."""
+    pages, window, kb = case
+    if kb is not None:
+        monkeypatch.setattr(pa, "_span_block_pages", lambda S, Sr, P: kb)
+    out = _span_call(*_span_case(pages, seed=5), window)
+    assert hashlib.sha256(out.tobytes()).hexdigest() == PARENT_BITS[case]

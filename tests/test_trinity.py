@@ -214,14 +214,16 @@ def test_the_window_kernel_visits_no_page_before_the_window():
     assert (got[2, 1] == got[2, 0]).all() and (got[3] == got[2, 0]).all()
 
 
-# jax.make_jaxpr of the span kernel with no window (impl 'pallas') as the
-# commit before the window kernel traced it, sha256 of its text: a window
-# is a kernel of its own, and without one the kernel is what it was
+# jax.make_jaxpr of the span kernel with no window (impl 'pallas'), sha256
+# of its text, as the kernel whose mask is built from a row vector and a
+# column vector traced it: a window is a kernel of its own, and without one
+# the kernel is the span kernel every other model runs. A change to the
+# span kernel itself updates them
 SPAN_JAXPR = {
     (3, 16, 4, 2, 64, 8, 16):
-        "ed5c482778bbc488994f9c36e870154bea8675482a710601bf209bb6d08d8d24",
+        "521a7c7078bbf410c29962ff287217a10548134c4456eaa5386561dfa1ef658d",
     (2, 8, 4, 4, 32, 6, 8):
-        "1c88ccf84b6b0e4b685608c2fde7056a3a67240498a92b803ed9deee7e398a75",
+        "82d1c0de4b52bbadb900473ece3917601413d707d3ffa6b6449ca17113f68d63",
 }
 
 

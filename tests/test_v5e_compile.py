@@ -585,26 +585,28 @@ def test_trinity_unified_program_keeps_pool_and_rings_in_place(one_chip):
 # The span kernel with no window, lowered for a described v5e at the cells'
 # geometries: sha256 of its Mosaic module printed WITHOUT debug information
 # (a location names the source file and line, which move with any edit) and
-# of the program around it with the module's bytes taken out, as the commit
-# before the window kernel lowered them. A window is a kernel of its own
-# (`window_span_attention`); these are every other model's, and must not
-# move. A change to the span kernel itself updates them.
+# of the program around it with the module's bytes taken out. The program
+# around the module is as the commit before the window kernel lowered it;
+# the module is the kernel whose mask is built from a row vector and a
+# column vector. A window is a kernel of its own (`window_span_attention`);
+# these are every other model's, and must not move. A change to the span
+# kernel itself updates the module's digests.
 SPAN_LOWERED = {
     # (slots, rows, query heads, KV heads, head size, pages a slot, pages)
     (16, 64, 20, 20, 64, 16, "bfloat16"): (
-        "0e65351ec13e9c6256285a5db3c1c77f0085b82a18cc7eae6822cf6c99d402b9",
+        "0f47a0aaa977ea3989f0b804282902e0a6e46cf272016f820ea0d7cb4bccfd42",
         "02f695fefaccb1d5f9849dcb9857c02081eeb6aea595ca73191a9427ac18bf4d"),
     (5, 64, 16, 16, 128, 16, "bfloat16"): (
-        "505949d0c2c29ff61fb89be287079fb5e60a6a757e453a9f8ecb74d3a471e8d7",
+        "be31ba437fd7061851e6544c816b40ea8d0b1a53f045f58d0db42a29a55a8590",
         "99517d3e4b5965f9e14398206e04d546ee4188ea35fa6e2e32dbf4db82175fa9"),
     (32, 64, 20, 4, 128, 10, "bfloat16"): (
-        "919607d535cc0b60d62d499f5fd635589bd6f7c8d8dd36129b3a8d2806fee4a8",
+        "67741249950a925d40ea6217445357727cc7fda656dbdf5bd29ed65b52608d1f",
         "b4ad310367d9e08fa6bb4b1bf2d0b0a7079fa4f9971144cbb8706d71dc6eb05a"),
     (32, 64, 32, 4, 128, 513, "bfloat16"): (
-        "62fc228340ddf4875902d7064d2ddffd7b9541967d7b1edad27d5235fe90f656",
+        "333dde6173931d756b1bdd608cbc4bb1525fa256c80bd4b8f208b1fa0de63018",
         "58c322003ca73b2d13950c84770dd3b3bcd4bc2716f6e807ce5820d48dbe60a3"),
     (16, 64, 20, 20, 64, 16, "int8"): (
-        "227f7fd33dbea46b5a9bc3b0e009c4da3ee3d00cc38d3e5df7e966cf7df3be3c",
+        "fe836a3921bf2e296555d62bd0aa1d189c549dc71c5626c351ac6c6720ecd849",
         "f959c8c6088ea9bbbee5d0db74d468ae82c95f474fd2704a8c686ee1ea4caeb1"),
 }
 
